@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.state import FingerState
+from repro.distributed.sharding import auto_mesh
 from repro.graphs.layout import NodeLayout
 from repro.graphs.types import GraphDelta
 from repro.launch import hlo_analysis
@@ -109,8 +110,8 @@ def mesh_for_placement(placement: str):
     if placement == "local":
         return None
     if placement == "sharded":
-        return jax.make_mesh((jax.device_count(),), ("data",))
-    return jax.make_mesh((1, jax.device_count()), ("pod", "data"))
+        return auto_mesh((jax.device_count(),), ("data",))
+    return auto_mesh((1, jax.device_count()), ("pod", "data"))
 
 
 def _dummy_tick_args(config: ServiceConfig,

@@ -55,14 +55,6 @@ class CompileCount:
             self.count += 1
 
 
-def _unregister_duration_listener(fn) -> None:
-    # jax.monitoring (0.4.x) has no public unregister; the private
-    # helper is stable across the pinned version.
-    from jax._src import monitoring as _m
-
-    _m._unregister_event_duration_listener_by_callback(fn)
-
-
 @contextlib.contextmanager
 def compile_budget(max_compiles: Optional[int],
                    what: str = "") -> Iterator[CompileCount]:
@@ -84,7 +76,7 @@ def compile_budget(max_compiles: Optional[int],
     try:
         yield counter
     finally:
-        _unregister_duration_listener(_listener)
+        jax.monitoring.unregister_event_duration_listener(_listener)
     if max_compiles is not None and counter.count > max_compiles:
         label = f" ({what})" if what else ""
         raise CompileBudgetExceeded(
@@ -129,7 +121,9 @@ def transfer_budget(max_transfers: Optional[int],
     buffer-protocol shortcut — a zero-copy view that really is not a
     transfer, and is not counted. `float(...)` of a fresh device value
     and `jax.device_get` funnel through `_value` on every backend, so
-    per-item-sync regressions still trip the budget on CPU CI.
+    per-item-sync regressions still trip the budget on CPU CI; the
+    zero-copy view `_value` returns there is cached like a copied value,
+    so each array counts once on every backend.
 
     Implementation: temporarily swaps the `_value` property on
     ``jax._src.array.ArrayImpl`` for a counting wrapper and restores
@@ -146,9 +140,16 @@ def transfer_budget(max_transfers: Optional[int],
     prev_fget = prev.fget
 
     def _counting_value(self):
+        if self._npy_value is not None:
+            return prev_fget(self)
+        counter._bump()
+        out = prev_fget(self)
         if self._npy_value is None:
-            counter._bump()
-        return prev_fget(self)
+            # A zero-copy host view (the CPU backend) is not cached by
+            # the runtime; cache it so a re-read stays free, exactly as
+            # a copied device value is.
+            self._npy_value = out
+        return out
 
     impl._value = property(_counting_value)
     try:

@@ -21,7 +21,7 @@ from the kernels they guard. This module closes that gap mechanically:
 Block-level demand is a *lower* bound on true VMEM use (the compiler
 adds its own temporaries — which is exactly why the hand estimates
 model the big intermediates explicitly and why the budget is half the
-physical ~16 MB).
+16 MiB scoped-VMEM limit of one v5e kernel; see `kernels.dispatch`).
 """
 from __future__ import annotations
 
@@ -215,12 +215,12 @@ def _check_stream_tick_estimate(
     for launch, fp in zip(launches, footprints):
         if launch.package != "stream_tick":
             continue
-        # operand order fixed by prepare_stream_tick: q, s, smax,
-        # strengths(b,n), mask(b,n), ep_ids(b,2k), 3×payload, nid(b,j),
-        # nflag(b,j)
-        n_al = launch.operand_shapes[3][-1]
-        two_k = launch.operand_shapes[5][-1]
-        j_al = launch.operand_shapes[9][-1]
+        # operand order fixed by prepare_stream_tick: scalar slab,
+        # strengths(b,1,n), mask(b,1,n), ep_ids(b,1,2k), 3×payload,
+        # nid(b,1,j), nflag(b,1,j)
+        n_al = launch.operand_shapes[1][-1]
+        two_k = launch.operand_shapes[3][-1]
+        j_al = launch.operand_shapes[7][-1]
         est = fused_tick_vmem_bytes(n_al, two_k // 2, j_al)
         if est < fp.step_bytes:
             out.append(VmemViolation(

@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.core.jsdist import _js_from_entropies
 from repro.core.incremental import update_state
-from repro.core.state import FingerState, finger_state
+from repro.core.state import FingerState, host_finger_state
 from repro.graphs.types import (
     DenseGraph,
     EdgeList,
@@ -524,18 +524,15 @@ class SlotMap:
         else:
             freed_nodes = []
 
+        # Host (numpy) leaves: the service stacks the B per-stream
+        # deltas on the host and moves the tick to the device once.
         slot_delta = GraphDelta(
-            senders=jnp.asarray(out_snd),
-            receivers=jnp.asarray(out_rcv),
-            dw=jnp.asarray(out_dw),
-            w_old=jnp.asarray(out_wold),
-            mask=jnp.asarray(out_mask),
+            senders=out_snd, receivers=out_rcv, dw=out_dw,
+            w_old=out_wold, mask=out_mask,
             n_nodes=self.layout.n_slots,
-            node_ids=None if out_nid is None else jnp.asarray(out_nid),
-            node_flag=(None if out_nflag is None
-                       else jnp.asarray(out_nflag)),
+            node_ids=out_nid, node_flag=out_nflag,
             layout_generation=None,
-            edge_slots=jnp.asarray(out_slot),
+            edge_slots=out_slot,
         )
         return _StagedTranslation(
             delta=slot_delta, staged_nodes=staged_nodes,
@@ -617,9 +614,6 @@ def sparse_state_from_graph(
         raise ValueError(
             f"sparse_state_from_graph: graph n_nodes={g.n_nodes} "
             f"exceeds the virtual bound n_virtual={n_virtual}")
-    if isinstance(g, EdgeList):
-        g = g.to_dense()
-    w = np.asarray(g.masked_weights(), np.float32)
     if g.node_mask is None:
         active = np.arange(g.n_nodes, dtype=np.int64)
     else:
@@ -628,10 +622,22 @@ def sparse_state_from_graph(
         raise SparseCapacityError(
             f"sparse_state_from_graph: {active.size} active node(s) "
             f"exceed n_slots={layout.n_slots}; use a larger capacity")
-    iu, ju = np.triu_indices(g.n_nodes, k=1)
-    vals = w[iu, ju]
-    nz = vals != 0.0
-    iu, ju, vals = iu[nz], ju[nz], vals[nz]
+    if isinstance(g, EdgeList):
+        # Straight from the edge arrays (one entry per undirected edge,
+        # senders < receivers): no n x n densification, so a wide
+        # virtual id space costs O(n + m) host work.
+        vals = np.asarray(g.masked_weights(), np.float32)
+        nz = vals != 0.0
+        iu = np.asarray(g.senders, np.int64)[nz]
+        ju = np.asarray(g.receivers, np.int64)[nz]
+        order = np.lexsort((ju, iu))
+        iu, ju, vals = iu[order], ju[order], vals[nz][order]
+    else:
+        w = np.asarray(g.masked_weights(), np.float32)
+        iu, ju = np.triu_indices(g.n_nodes, k=1)
+        vals = w[iu, ju]
+        nz = vals != 0.0
+        iu, ju, vals = iu[nz], ju[nz], vals[nz]
     if iu.size > layout.m_pad:
         raise SparseCapacityError(
             f"sparse_state_from_graph: {iu.size} edge(s) exceed "
@@ -660,12 +666,13 @@ def sparse_state_from_graph(
     el = EdgeList.from_arrays(
         snd, rcv, vals, n_nodes=layout.n_slots,
         m_pad=max(int(iu.size), 1), n_pad=layout.n_slots,
-        node_mask=jnp.asarray(slot_mask))
-    fs = finger_state(el)
+        node_mask=slot_mask)
+    fs = host_finger_state(el)
     state = SparseStreamState(
-        q=fs.q, s_total=fs.s_total, s_max=fs.s_max,
-        strengths=fs.strengths, node_mask=jnp.asarray(slot_mask),
-        edge_weights=jnp.asarray(ew), layout=layout)
+        q=jnp.asarray(fs.q), s_total=jnp.asarray(fs.s_total),
+        s_max=jnp.asarray(fs.s_max), strengths=jnp.asarray(fs.strengths),
+        node_mask=jnp.asarray(slot_mask), edge_weights=jnp.asarray(ew),
+        layout=layout)
     return state, slot_map
 
 

@@ -27,6 +27,7 @@ from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.vnge import c_from_s_total, strength_stats
 from repro.graphs.layout import NodeLayout
@@ -91,3 +92,25 @@ def finger_state(g: Graph,
     return FingerState(q=q, s_total=s_total, s_max=s_max,
                        strengths=g.strengths(), node_mask=g.node_mask,
                        layout=layout)
+
+
+def host_device() -> Optional[jax.Device]:
+    """The host CPU device, or None (the default device) where JAX
+    exposes no CPU backend."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
+def host_finger_state(g: Graph) -> FingerState:
+    """`finger_state` computed on the host CPU device, leaves as numpy.
+
+    For one-off host bookkeeping — admitting a tenant, seeding a slot
+    map. On an accelerator each eager op of a graph of a new width
+    would compile first (about a second apiece on a TPU v5e) only for
+    the values to come straight back to the host.
+    """
+    with jax.default_device(host_device()):
+        st = finger_state(jax.tree_util.tree_map(np.asarray, g))
+    return jax.tree_util.tree_map(np.asarray, st)

@@ -20,7 +20,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.state import FingerState
 from repro.core.vnge import c_from_s_total
-from repro.distributed.sharding import shard_map
 from repro.graphs.types import EdgeList
 
 
@@ -59,7 +58,7 @@ def distributed_finger_state(g: EdgeList, mesh: Mesh,
     shard = P(axis)
     # P() for the node-mask slot is correct whether it is an (n,)
     # replicated array or None (an empty pytree matches any leaf spec).
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(shard, shard, shard, shard, P()),
         out_specs=(P(), P(), P(), P()),
@@ -117,9 +116,9 @@ def distributed_power_iteration(
         return jnp.maximum(lam, 0.0)
 
     shard = P(axis)
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(shard, shard, shard, shard),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(shard, shard, shard, shard),
+                       out_specs=P(), check_vma=False)
     return fn(g.senders, g.receivers, g.weights, g.mask)
 
 

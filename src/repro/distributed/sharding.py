@@ -12,30 +12,25 @@ Parallelism encoded here (DESIGN.md §6):
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 re-exports shard_map at the top level
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # jax 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
 
+def auto_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis `AxisType.Auto`.
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = True):
-    """Version-portable `shard_map` (the `check_rep` kwarg moved around).
-
-    0.4.x needs `check_rep=False` for bodies containing `while_loop` (no
-    replication rule); newer jax dropped the kwarg entirely.
+    `jax.make_mesh` makes Explicit axes by default, which type-check
+    every sharded array op: the serving top-k merge and the sharded
+    ticks rely on the compiler choosing shardings (a (p·k,) candidate
+    row does not split evenly over p shards), so their meshes are Auto.
     """
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_rep)
-    except TypeError:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 @dataclasses.dataclass(frozen=True)

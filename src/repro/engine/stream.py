@@ -47,12 +47,12 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.jsdist import jsdist_incremental
 from repro.core.state import FingerState, finger_state
-from repro.distributed.sharding import shard_map
 from repro.graphs.layout import NodeLayout
 from repro.graphs.types import GraphDelta
 from repro.train.checkpoint import (
@@ -176,6 +176,8 @@ def stack_deltas(deltas: Sequence[GraphDelta]) -> GraphDelta:
     Streams must share every static/layout dimension — k_pad, n_pad
     (the static `n_nodes`), node-slot presence and j_pad. Each is
     validated up front with an error naming the offending streams.
+    Host (numpy) deltas stack on the host: no device op and no compile,
+    and the ingestion path moves the stacked tick to the device once.
     """
     _check_consistent("stack_deltas", "k_pad",
                       (d.dw.shape[-1] for d in deltas))
@@ -190,7 +192,10 @@ def stack_deltas(deltas: Sequence[GraphDelta]) -> GraphDelta:
     if deltas[0].node_ids is not None:
         _check_consistent("stack_deltas", "j_pad",
                           (d.node_ids.shape[-1] for d in deltas))
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *deltas)
+    on_host = all(isinstance(x, np.ndarray)
+                  for x in jax.tree_util.tree_leaves(list(deltas)))
+    stack = np.stack if on_host else jnp.stack
+    return jax.tree_util.tree_map(lambda *xs: stack(xs), *deltas)
 
 
 class StreamEngine:
@@ -422,7 +427,7 @@ class StreamEngine:
         contract as `tick`.
         """
         spec = P(axis)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             self._tick_body, mesh=mesh,
             in_specs=(spec, spec), out_specs=(spec, spec),
         )

@@ -24,7 +24,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from repro.core.sparse import SparseCapacityError, sparse_state_from_graph
-from repro.core.state import FingerState, finger_state
+from repro.core.state import FingerState, host_finger_state
 from repro.fleet import pooltick
 from repro.fleet.config import FleetConfig
 from repro.fleet.directory import TenantDirectory, TenantEntry
@@ -168,11 +168,10 @@ class FingerFleet:
         pool = self._config.pools[pool_i]
         svc = self.shard_service(pool_i, shard_i)
         # Same O(n + m) init pass `StreamEngine.init_states` runs on
-        # the unpadded graph, so a fleet tenant's starting state is
-        # bit-identical to a single service opened on the same graph
-        # (zero-padding into the shard layout commutes with every
-        # FINGER statistic).
-        st = finger_state(graph)
+        # the unpadded graph (zero-padding into the shard layout
+        # commutes with every FINGER statistic), computed on the host:
+        # the values are host bookkeeping.
+        st = host_finger_state(graph)
         base = {
             "q": float(st.q), "s_total": float(st.s_total),
             "s_max": float(st.s_max),

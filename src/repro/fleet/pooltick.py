@@ -192,9 +192,9 @@ def warm_pool_tick(entries: Sequence[Tuple[object, object]]) -> None:
     ONE body, so a mixed-method entry list cannot be a real group —
     it raises `PoolGroupError` by name instead of silently warming the
     first entry's program for shards that will never run it. A group
-    failing `group_fits` is skipped (the fleet will tick it through
-    the already-compiled sequential per-shard path, so there is no
-    stacked program to warm).
+    failing `group_fits` is skipped: the fleet ticks it shard by shard,
+    so there is no stacked program to warm (the rebalancer warms those
+    shards' own plans).
     """
     entries = list(entries)
     if not entries:

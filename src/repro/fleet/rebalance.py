@@ -247,7 +247,9 @@ class Rebalancer:
         bound. Sparse shards have no compaction/repad surface (their
         virtual bound grows for free and slot capacities only change
         through explicit `grow_capacity`), so only their current
-        capacity grouping is warmed."""
+        capacity grouping is warmed. A current group that fails
+        `pooltick.group_fits` ticks shard by shard in `poll()`, so its
+        shards' own per-shard ticks are warmed instead."""
         fleet = self._fleet
         warmed = []
         if not fleet.config.stacked_ticks:
@@ -267,6 +269,12 @@ class Rebalancer:
                 groups.setdefault(key, []).append(svc)
             plans = []
             for members in groups.values():
+                if not pooltick.group_fits([s.config for s in members]):
+                    # poll() ticks this group shard by shard: warm each
+                    # shard's own plan at its live layout instead.
+                    for s in members:
+                        s.plan.warm_tick(s.capacity or s.layout)
+                    continue
                 if pool.method == "sparse_tick":
                     # Warm entries carry the SparseLayout capacity —
                     # the layout `dummy_tick_args` sizes slot-space

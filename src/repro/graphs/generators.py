@@ -6,7 +6,7 @@ are `DenseGraph`/`EdgeList` pytrees.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -32,25 +32,34 @@ def erdos_renyi(n: int, p: float, seed: int = 0,
     return _to_graphs(w)
 
 
-def barabasi_albert(n: int, m_attach: int, seed: int = 0) -> DenseGraph:
-    """BA(n, m): preferential attachment; power-law degree distribution."""
+def barabasi_albert_edges(n: int, m_attach: int,
+                          seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:
+    """BA(n, m) as an edge list: (lo, hi) int64 arrays with lo < hi,
+    each undirected edge once. O(n·m) host work and memory — no n×n
+    matrix — so it scales to AS-level widths."""
     rng = np.random.default_rng(seed)
     m_attach = max(1, min(m_attach, n - 1))
-    w = np.zeros((n, n))
     # seed clique of m_attach + 1 nodes
-    w[: m_attach + 1, : m_attach + 1] = 1.0
-    np.fill_diagonal(w, 0.0)
-    deg = w.sum(1)
+    lo, hi = np.triu_indices(m_attach + 1, k=1)
+    lo, hi = list(lo), list(hi)
     repeated = list(np.repeat(np.arange(m_attach + 1), m_attach))
     for v in range(m_attach + 1, n):
         targets: set = set()
         while len(targets) < m_attach:
             targets.add(int(repeated[rng.integers(0, len(repeated))]))
         for t in targets:
-            w[v, t] = w[t, v] = 1.0
+            lo.append(t)
+            hi.append(v)
             repeated.append(t)
             repeated.append(v)
-        deg[v] = m_attach
+    return np.asarray(lo, np.int64), np.asarray(hi, np.int64)
+
+
+def barabasi_albert(n: int, m_attach: int, seed: int = 0) -> DenseGraph:
+    """BA(n, m): preferential attachment; power-law degree distribution."""
+    lo, hi = barabasi_albert_edges(n, m_attach, seed)
+    w = np.zeros((n, n))
+    w[lo, hi] = w[hi, lo] = 1.0
     return _to_graphs(w)
 
 

@@ -14,8 +14,10 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from repro.graphs.generators import erdos_renyi, barabasi_albert
-from repro.graphs.types import DenseGraph, GraphDelta, apply_delta_dense
+from repro.graphs.generators import (barabasi_albert, barabasi_albert_edges,
+                                     erdos_renyi)
+from repro.graphs.types import (DenseGraph, EdgeList, GraphDelta,
+                                apply_delta_dense)
 
 
 @dataclass
@@ -94,14 +96,15 @@ def dos_attack_sequence(
     attack_frac: float = 0.05,
     seed: int = 0,
     k_pad: Optional[int] = None,
-) -> Tuple[GraphSequence, int]:
+) -> Tuple[GraphSequence, Optional[int]]:
     """Oregon-AS-like peering sequence with one planted DoS event.
 
     Each snapshot is a BA graph (AS-level router topologies are
     scale-free) with mild natural churn; in one randomly chosen snapshot
     among the first `n_graphs - 1`, X% of nodes all connect to a single
     random target — the paper's synthesized DoS pattern. Returns the
-    sequence and the attacked transition index.
+    sequence and the attacked transition index. ``attack_frac=0``
+    plants no attack (churn only) and returns None for the index.
     """
     rng = np.random.default_rng(seed)
     base = barabasi_albert(n, 3, seed=seed)
@@ -125,7 +128,7 @@ def dos_attack_sequence(
         ii, jj = iu[pick], ju[pick]
         w_new[ii, jj] = np.where(w_new[ii, jj] > 0, 0.0, 1.0)
         w_new[jj, ii] = w_new[ii, jj]
-        if t == attack_at:
+        if attack_frac > 0 and t == attack_at:
             target = int(rng.integers(0, n))
             botnet = rng.choice(np.setdiff1d(np.arange(n), [target]),
                                 size=max(1, int(attack_frac * n)),
@@ -136,7 +139,73 @@ def dos_attack_sequence(
         deltas.append(_delta_between(graphs[-1], g_new, k_pad=k_pad))
         graphs.append(g_new)
         w = w_new
-    return GraphSequence(graphs, deltas), attack_at
+    return GraphSequence(graphs, deltas), \
+        (attack_at if attack_frac > 0 else None)
+
+
+def dos_attack_edge_sequence(
+    n: int = 11_174,
+    n_graphs: int = 9,
+    attack_frac: float = 0.05,
+    churn_frac: float = 0.005,
+    ba_m: int = 2,
+    seed: int = 0,
+    k_pad: Optional[int] = None,
+) -> Tuple[EdgeList, List[GraphDelta], Optional[int]]:
+    """`dos_attack_sequence` at AS-level width, as edge lists.
+
+    No n×n snapshot is ever built, so it scales to the paper's Oregon-1
+    width (11,174 ASes): the base is BA(n, ``ba_m``) from
+    `barabasi_albert_edges` (``ba_m=2`` gives about 2n edges, Oregon-1's
+    ~22k at its width). Each snapshot removes ``churn_frac`` of the
+    current edges and adds as many absent pairs, so the edge count stays
+    stable, as between successive AS peering snapshots. The planted DoS
+    event is `dos_attack_sequence`'s: in one randomly chosen snapshot
+    among the first ``n_graphs - 1``, X% of the nodes all connect to a
+    single random target. ``attack_frac=0`` plants nothing.
+
+    Returns (the first snapshot as an `EdgeList`, the per-transition
+    deltas, the attacked transition index or None).
+    """
+    rng = np.random.default_rng(seed)
+    lo, hi = barabasi_albert_edges(n, ba_m, seed=seed)
+    edges = set(zip(lo.tolist(), hi.tolist()))
+    attack_at = int(rng.integers(0, n_graphs - 1))
+    churn = max(1, int(churn_frac * len(edges)))
+    n_bot = max(1, int(attack_frac * n))
+    if k_pad is None:
+        # one padded shape for the whole sequence: removals + additions
+        # plus the worst-case attack fan-in
+        k_pad = 2 * churn + n_bot + 1
+    first = EdgeList.from_arrays(lo, hi, np.ones(len(lo)), n_nodes=n)
+    deltas = []
+    for t in range(n_graphs - 1):
+        current = sorted(edges)
+        drop = rng.choice(len(current), size=churn, replace=False)
+        new = edges.difference(current[i] for i in drop.tolist())
+        added = 0
+        while added < churn:
+            i, j = (int(x) for x in rng.integers(0, n, 2))
+            pair = (min(i, j), max(i, j))
+            if i == j or pair in edges or pair in new:
+                continue
+            new.add(pair)
+            added += 1
+        if attack_frac > 0 and t == attack_at:
+            target = int(rng.integers(0, n))
+            botnet = rng.choice(np.setdiff1d(np.arange(n), [target]),
+                                size=n_bot, replace=False)
+            new.update((min(b, target), max(b, target))
+                       for b in botnet.tolist())
+        gone, born = sorted(edges - new), sorted(new - edges)
+        pairs = np.asarray(gone + born, np.int64).reshape(-1, 2)
+        dw = np.concatenate([-np.ones(len(gone)), np.ones(len(born))])
+        order = np.lexsort((pairs[:, 1], pairs[:, 0]))
+        deltas.append(GraphDelta.from_arrays(
+            pairs[order, 0], pairs[order, 1], dw[order],
+            (dw[order] < 0).astype(np.float64), n_nodes=n, k_pad=k_pad))
+        edges = new
+    return first, deltas, (attack_at if attack_frac > 0 else None)
 
 
 def hic_bifurcation_sequence(

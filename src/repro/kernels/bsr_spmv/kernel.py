@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 def _kernel(col_ids_ref, x_ref, values_ref, y_ref, *, max_bpr: int, b: int):
     def body(k, acc):
         col = col_ids_ref[0, k]
-        xb = pl.load(x_ref, (pl.ds(col * b, b), slice(None)))  # (b, 1)
+        xb = x_ref[pl.ds(col * b, b), :]  # (b, 1)
         blk = values_ref[0, k]  # (b, b)
         return acc + jnp.dot(blk, xb, preferred_element_type=jnp.float32)
 

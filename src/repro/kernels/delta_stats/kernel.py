@@ -19,6 +19,11 @@ strictly-lower-triangular occurrence count marks segment heads. The
 VMEM — HBM traffic stays O(Δm), which is what the pass is bound by for
 streaming deltas.
 
+The four statistics leave as lanes 0-3 of one ``(1, 128)`` row: Mosaic
+cannot store a scalar to VMEM. The segment-sum contraction carries Δw
+values and runs at ``Precision.HIGHEST`` (see `stream_tick.kernel`:
+the default is about bf16 accurate).
+
 Adaptation note: the CUDA analogue would be a sort + segmented-reduce
 (CUB) pair of kernels; on TPU one fused kernel with an MXU segment
 contraction replaces both.
@@ -32,45 +37,53 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import SCALAR_LANES, pack_lanes
+
 
 def _kernel(sn_ref, sv_ref, ss_ref, ev_ref, dw_ref, wo_ref, mask_ref,
             out_ref):
-    sn = sn_ref[0, :]          # (2k,) int32 sorted node ids, sentinel last
-    sv = sv_ref[0, :]          # (2k,) f32 masked Δw per endpoint
-    ss = ss_ref[0, :]          # (2k,) f32 gathered strengths
-    ev = ev_ref[0, :]          # (2k,) f32 endpoint validity
-    two_k = sn.shape[0]
+    # Every per-endpoint vector stays a (1, 2k) lane row, so no value
+    # ever changes between sublane and lane orientation.
+    sn = sn_ref[...]           # (1, 2k) int32 sorted node ids, sentinel last
+    sv = sv_ref[...]           # (1, 2k) f32 masked Δw per endpoint
+    ss = ss_ref[...]           # (1, 2k) f32 gathered strengths
+    ev = ev_ref[...]           # (1, 2k) f32 endpoint validity
+    two_k = sn.shape[1]
 
     # Same-node matrix M[p, q] = [sn[p] == sn[q]] over the sorted run.
-    sn_row = jax.lax.broadcast_in_dim(sn, (two_k, two_k), (0,))
-    sn_col = jax.lax.broadcast_in_dim(sn, (two_k, two_k), (1,))
+    sn_row = jax.lax.broadcast_in_dim(sn[0], (two_k, two_k), (0,))
+    sn_col = jax.lax.broadcast_in_dim(sn[0], (two_k, two_k), (1,))
     same = (sn_row == sn_col).astype(jnp.float32)
 
-    # Δs of each slot's segment: contract the segment indicator against
-    # the endpoint values (MXU; values are zero on masked slots).
-    ds_pos = jnp.dot(same, sv.reshape(two_k, 1),
-                     preferred_element_type=jnp.float32)[:, 0]
+    # Δs of each slot's segment: contract the endpoint values against
+    # the (symmetric) segment indicator on the MXU; values are zero on
+    # masked slots.
+    ds_pos = jnp.dot(sv, same, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
 
-    # Segment head = first occurrence: no equal node id strictly before.
+    # Segment head = first occurrence: no equal node id strictly before
+    # (column sums of M over the rows above, M being symmetric).
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (two_k, two_k), 0)
     col_ids = jax.lax.broadcasted_iota(jnp.int32, (two_k, two_k), 1)
-    before = (col_ids < row_ids).astype(jnp.float32)
-    cnt_before = jnp.sum(same * before, axis=1)
+    before = (row_ids < col_ids).astype(jnp.float32)
+    cnt_before = jnp.sum(same * before, axis=0, keepdims=True)
     head = jnp.logical_and(cnt_before == 0.0, ev > 0.0)
 
-    node_term = jnp.sum(jnp.where(
+    def total(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    node_term = total(jnp.where(
         head, 2.0 * ss * ds_pos + ds_pos * ds_pos, 0.0))
-    max_new = jnp.max(jnp.where(head, ss + ds_pos, -jnp.inf))
-    n_touched = jnp.sum(head.astype(jnp.float32))
+    max_new = jnp.max(jnp.where(head, ss + ds_pos, -jnp.inf), axis=1,
+                      keepdims=True)
+    n_touched = total(head.astype(jnp.float32))
 
-    dwm = dw_ref[0, :] * mask_ref[0, :]
-    edge_term = jnp.sum(4.0 * wo_ref[0, :] * dwm + 2.0 * dwm * dwm)
-    delta_s = 2.0 * jnp.sum(dwm)
+    dwm = dw_ref[...] * mask_ref[...]
+    edge_term = total(4.0 * wo_ref[...] * dwm + 2.0 * dwm * dwm)
+    delta_s = 2.0 * total(dwm)
 
-    out_ref[0] = delta_s
-    out_ref[1] = node_term + edge_term
-    out_ref[2] = max_new
-    out_ref[3] = n_touched
+    out_ref[...] = pack_lanes(out_ref.shape, delta_s, node_term + edge_term,
+                              max_new, n_touched)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -84,7 +97,8 @@ def delta_stats_sorted_pallas(
     mask: jax.Array,              # (1, k) f32
     interpret: bool = False,
 ) -> jax.Array:
-    """Sorted-endpoint delta arrays → (4,) [ΔS, ΔQ, max s', |ΔV|]."""
+    """Sorted-endpoint delta arrays → (1, 128) row whose lanes 0-3 are
+    [ΔS, ΔQ, max s', |ΔV|]."""
     two_k = sorted_nodes.shape[1]
     assert two_k % 128 == 0, (
         f"2·k_pad={two_k} must be lane-aligned (multiple of 128); "
@@ -98,7 +112,7 @@ def delta_stats_sorted_pallas(
         _kernel,
         in_specs=[vspec] * 7,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((4,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, SCALAR_LANES), jnp.float32),
         interpret=interpret,
     )(sorted_nodes, sorted_vals, sorted_strengths, endpoint_valid,
       dw, w_old, mask)

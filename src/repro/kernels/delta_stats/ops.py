@@ -25,8 +25,9 @@ from repro.kernels.delta_stats.ref import delta_stats_sorted_ref
 
 _LANE = dispatch.LANE
 # The fused kernel builds (2k, 2k) segment-indicator temporaries in VMEM
-# (~3 × (2k)² × 4 B); past this endpoint count they would blow the ~16 MB
-# per-core budget, so larger deltas take the XLA ref path instead.
+# (~3 × (2k)² × 4 B, 12 MB at 1024 endpoints); past this endpoint count
+# they would outgrow the scoped VMEM a Mosaic kernel gets by default
+# (16 MiB on v5e), so larger deltas take the XLA ref path instead.
 _MAX_FUSED_ENDPOINTS = 1024
 
 
@@ -85,5 +86,5 @@ def delta_stats_fused(
     else:
         interpret = dispatch.default_interpret(interpret)
         stats = delta_stats_sorted_pallas(
-            *(x.reshape(1, -1) for x in prep), interpret=interpret)
+            *(x.reshape(1, -1) for x in prep), interpret=interpret)[0]
     return stats[0], stats[1], stats[2]

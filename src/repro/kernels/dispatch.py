@@ -1,5 +1,6 @@
 """Shared kernel dispatch policy: backend detection, interpret-mode
-fallback, lane geometry, and the configurable per-grid-step VMEM budget.
+fallback, lane geometry and the one-row block layout every per-stream
+kernel uses, and the configurable per-grid-step VMEM budget.
 
 Every kernel package's ``ops.py`` dispatches the same way — Pallas on
 TPU, interpret mode elsewhere (CPU CI), and a size guard that routes
@@ -8,9 +9,17 @@ home for that policy, and `repro.analysis.vmem` consumes the same
 budget so the static checker and the runtime guard can never disagree
 on what "fits" means.
 
-The VMEM budget defaults to a conservative 8 MB (half the ~16 MB/core
-TPU VMEM, leaving headroom for the compiler's own temporaries).  It can
-be overridden three ways, in increasing precedence:
+The VMEM budget defaults to a conservative 8 MB: half the 16 MiB of
+scoped VMEM a Mosaic kernel gets by default on TPU v5e. That limit is
+per kernel, not the core's whole VMEM: compiling for a described v5e
+chip refuses an oversized kernel with "scoped allocation ... limit
+16.00M exceeded". The kernels' hand estimates are about twice what
+Mosaic actually allocates — the fused tick at (2k, n) = (2048, 512),
+B = 8, needs 36.84 MB against an estimated 71 MB, and (1024, 512)
+compiles within the limit although estimated at 19 MB — so the halved
+budget leaves headroom for the compiler's own temporaries on top of an
+already generous estimate. It can be overridden three ways, in
+increasing precedence:
 
 - the ``REPRO_VMEM_BUDGET_BYTES`` environment variable (read once at
   import);
@@ -36,11 +45,71 @@ import threading
 from typing import Iterator, Optional
 
 import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # TPU vector-memory lane geometry: the last axis tiles to 128 lanes,
 # the second-to-last to 8 sublanes (f32).
 LANE = 128
 SUBLANE = 8
+
+# Lane width of the packed per-stream scalar slab.
+SCALAR_LANES = LANE
+
+# One-row block layout. Mosaic refuses a ``(1, width)`` block of a
+# ``(B, width)`` array (its sublane dim is neither 8-aligned nor the
+# full axis) and refuses scalar stores to VMEM. So per-stream operands
+# travel as ``(B, 1, width)`` with ``(None, 1, width)`` blocks — the
+# trailing block dims equal the array's — and per-stream scalars as one
+# ``(B, 1, SCALAR_LANES)`` lane slab, stored as one vector row.
+
+
+def pad_last(x: jax.Array, width: int, value=0) -> jax.Array:
+    """Pad the last axis of ``x`` to ``width`` with ``value``."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    cfg = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
+    return jnp.pad(x, cfg, constant_values=value)
+
+
+def pack_scalar_slab(*scalars: jax.Array) -> jax.Array:
+    """(…,) per-stream scalars → the ``(…, 1, SCALAR_LANES)`` lane slab
+    (scalar i in lane i)."""
+    slab = jnp.stack(scalars, axis=-1).astype(jnp.float32)
+    return pad_last(slab, SCALAR_LANES)[..., None, :]
+
+
+def as_rows(*xs: jax.Array):
+    """(…, w) operands → one-row ``(…, 1, w)`` blocks."""
+    return tuple(x[..., None, :] for x in xs)
+
+
+def pack_lanes(shape, *values):
+    """Inside a kernel: a ``shape`` row holding ``values[i]`` in lane i,
+    zero elsewhere — the vector store Mosaic accepts in place of
+    per-scalar stores."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    out = jnp.zeros(shape, jnp.float32)
+    for i, v in enumerate(values):
+        out = jnp.where(lane == i, v, out)
+    return out
+
+
+def row_spec(width):
+    """Per-stream block of a ``(B, 1, width)`` operand, grid ``(B,)``."""
+    return pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0),
+                        memory_space=pltpu.VMEM)
+
+
+def stacked_row_spec(width):
+    """Per-stream block of an ``(S, B, 1, width)`` operand, grid
+    ``(S, B)``: the shard and stream axes are both squeezed, so the
+    kernel sees the same ``(1, width)`` ref as under `row_spec`."""
+    return pl.BlockSpec((None, None, 1, width),
+                        lambda si, bi: (si, bi, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 DEFAULT_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 

@@ -26,6 +26,11 @@ Per grid step, on one stream's row:
      (padding/gated lanes ride the `EDGE_SLOT_SENTINEL` and match no
      slot).
 
+Block layout, scalar slab and contraction precision are
+`stream_tick`'s (see its module docstring): ``(B, 1, width)`` operands
+with ``(None, 1, width)`` blocks, (q, S, s_max) in and (dist, q', S',
+s_max') out through one ``(B, 1, 128)`` lane slab.
+
 ops.py routes oversized (k_pad, n_slots, m_pad) tiles to the vmapped
 XLA oracle (`ref.sparse_tick_ref`) before reaching this kernel's
 asserts, and runs interpret mode off-TPU like every kernel package.
@@ -37,11 +42,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dispatch import (
+    SCALAR_LANES,
+    pack_lanes,
+    row_spec,
+    stacked_row_spec,
+)
 
 # Same endpoint-axis ceiling as stream_tick: the (2k, 2k) indicator
 # temporaries dominate and are layout-independent.
 MAX_ENDPOINTS = 2048
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _h_tilde(q, s_total, s_max):
@@ -51,11 +64,10 @@ def _h_tilde(q, s_total, s_max):
     return jnp.where(s_total > 0, -q * jnp.log(arg), 0.0)
 
 
-def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
+def _kernel(sc_ref, str_ref, mask_ref, ew_ref,
             ep_ids_ref, ep_dw_ref, ep_wold_ref, ep_mask_ref,
             eslot_ref, nid_ref, nflag_ref,
-            dist_ref, qo_ref, so_ref, smaxo_ref, stro_ref, masko_ref,
-            ewo_ref, *, exact_smax: bool):
+            sco_ref, stro_ref, masko_ref, ewo_ref, *, exact_smax: bool):
     f32 = jnp.float32
     strengths = str_ref[0, :]          # (n,) slot-space strengths
     node_mask = mask_ref[0, :]         # (n,) 0/1 allocated-and-active
@@ -89,7 +101,7 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
     onehot = (ep_b == node_col).astype(f32)          # (2k, n_slots)
     gate_ep = jnp.dot(onehot, mask_joined.reshape(n, 1),
                       preferred_element_type=f32)[:, 0]
-    s_ep = jnp.dot(onehot, strengths.reshape(n, 1),
+    s_ep = jnp.dot(onehot, strengths.reshape(n, 1), precision=_HIGHEST,
                    preferred_element_type=f32)[:, 0]
     row2 = jax.lax.broadcasted_iota(jnp.int32, (two_k, two_k), 0)
     col2 = jax.lax.broadcasted_iota(jnp.int32, (two_k, two_k), 1)
@@ -105,7 +117,7 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
     v_r = jax.lax.broadcast_in_dim(valid, (two_k, two_k), (0,))
     v_c = jax.lax.broadcast_in_dim(valid, (two_k, two_k), (1,))
     same = (ids_r == ids_c).astype(f32) * v_r * v_c
-    ds_here = jnp.dot(same, vals.reshape(two_k, 1),
+    ds_here = jnp.dot(same, vals.reshape(two_k, 1), precision=_HIGHEST,
                       preferred_element_type=f32)[:, 0]
     cnt_before = jnp.sum(same * (col2 < row2).astype(f32), axis=1)
     head = jnp.logical_and(valid > 0.0, cnt_before == 0.0)
@@ -122,13 +134,13 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
     max_new_half = jnp.max(jnp.where(head, s_ep + 0.5 * ds_here,
                                      -jnp.inf))
 
-    ds_dense = jnp.dot(vals.reshape(1, two_k), onehot,
+    ds_dense = jnp.dot(vals.reshape(1, two_k), onehot, precision=_HIGHEST,
                        preferred_element_type=f32)[0, :]
 
     # -- 4. Theorem-2 scalar updates (ΔG/2 and ΔG) ----------------------
-    q0 = q_ref[0, 0]
-    s0 = s_ref[0, 0]
-    smax0 = smax_ref[0, 0]
+    q0 = sc_ref[0, 0]
+    s0 = sc_ref[0, 1]
+    smax0 = sc_ref[0, 2]
     c0 = jnp.where(s0 > 0, 1.0 / s0, 0.0)
 
     def theorem2(f, node_term, edge_term):
@@ -172,7 +184,7 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
     gate_b = jax.lax.broadcast_in_dim(gate_edge, (k, m), (0,))
     oh_store = (eslot_b == store_col).astype(f32) * gate_b  # (k, m)
     touched = jnp.max(oh_store, axis=0)              # (m,) 0/1
-    scattered = jnp.dot(new_w.reshape(1, k), oh_store,
+    scattered = jnp.dot(new_w.reshape(1, k), oh_store, precision=_HIGHEST,
                         preferred_element_type=f32)[0, :]
     ew_full = edge_w * (1.0 - touched) + scattered
     ew_full = jnp.where(s_full > 0, ew_full, 0.0)
@@ -182,10 +194,8 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
     h_full = _h_tilde(q_full, s_full, smax_full)
     div = h_half - 0.5 * (h_pre + h_full)
 
-    dist_ref[0, 0] = jnp.sqrt(jnp.maximum(div, 0.0))
-    qo_ref[0, 0] = q_full
-    so_ref[0, 0] = s_full
-    smaxo_ref[0, 0] = smax_full
+    sco_ref[...] = pack_lanes(sco_ref.shape, jnp.sqrt(jnp.maximum(div, 0.0)),
+                              q_full, s_full, smax_full)
     stro_ref[0, :] = str_full
     masko_ref[0, :] = mask_after
     ewo_ref[0, :] = ew_full
@@ -193,75 +203,64 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref, ew_ref,
 
 @functools.partial(jax.jit, static_argnames=("exact_smax", "interpret"))
 def sparse_tick_pallas(
-    q: jax.Array,           # (B, 1) f32
-    s_total: jax.Array,     # (B, 1) f32
-    s_max: jax.Array,       # (B, 1) f32
-    strengths: jax.Array,   # (B, n_slots) f32
-    node_mask: jax.Array,   # (B, n_slots) f32
-    edge_weights: jax.Array,  # (B, m_pad) f32
-    ep_ids: jax.Array,      # (B, 2k) int32, [senders | receivers]
-    ep_dw: jax.Array,       # (B, 2k) f32
-    ep_wold: jax.Array,     # (B, 2k) f32
-    ep_mask: jax.Array,     # (B, 2k) f32
-    eslot: jax.Array,       # (B, k) int32 edge-store slots
-    nid: jax.Array,         # (B, j_pad) int32 node slot ids
-    nflag: jax.Array,       # (B, j_pad) f32 +1/-1/0
+    scalars: jax.Array,     # (B, 1, 128) f32 lanes [q, S, s_max, 0...]
+    strengths: jax.Array,   # (B, 1, n_slots) f32
+    node_mask: jax.Array,   # (B, 1, n_slots) f32
+    edge_weights: jax.Array,  # (B, 1, m_pad) f32
+    ep_ids: jax.Array,      # (B, 1, 2k) int32, [senders | receivers]
+    ep_dw: jax.Array,       # (B, 1, 2k) f32
+    ep_wold: jax.Array,     # (B, 1, 2k) f32
+    ep_mask: jax.Array,     # (B, 1, 2k) f32
+    eslot: jax.Array,       # (B, 1, k) int32 edge-store slots
+    nid: jax.Array,         # (B, 1, j_pad) int32 node slot ids
+    nflag: jax.Array,       # (B, 1, j_pad) f32 +1/-1/0
     exact_smax: bool = False,
     interpret: bool = False,
 ):
-    """Batched fused sparse tick → (dist, q', S', s_max', strengths',
-    mask', edge_weights')."""
-    b, n = strengths.shape
-    m = edge_weights.shape[1]
-    two_k = ep_ids.shape[1]
+    """Batched fused sparse tick → (scalars', strengths', mask',
+    edge_weights'), where ``scalars'`` holds lanes [dist, q', S',
+    s_max']."""
+    b, _, n = strengths.shape
+    m = edge_weights.shape[-1]
+    two_k = ep_ids.shape[-1]
     assert two_k % 256 == 0 and n % 128 == 0 and m % 128 == 0, (
         f"endpoint axis 2k={two_k}, slot axis n={n} and store axis "
         f"m={m} must be lane-aligned (ops.prepare pads them)")
-    assert eslot.shape[1] == two_k // 2, (
-        f"eslot axis {eslot.shape[1]} must equal k={two_k // 2}")
+    assert eslot.shape[-1] == two_k // 2, (
+        f"eslot axis {eslot.shape[-1]} must equal k={two_k // 2}")
     assert two_k <= MAX_ENDPOINTS, (
         f"2k={two_k} endpoints exceed the sparse-tick VMEM ceiling; "
         "ops.py routes such tiles to the vmapped path")
 
-    def row(width):
-        return pl.BlockSpec((1, width), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-
-    j = nid.shape[1]
-    in_specs = [row(1), row(1), row(1), row(n), row(n), row(m),
-                row(two_k), row(two_k), row(two_k), row(two_k),
-                row(two_k // 2), row(j), row(j)]
-    out_specs = [row(1), row(1), row(1), row(1), row(n), row(n),
-                 row(m)]
-    out_shape = tuple(
-        jax.ShapeDtypeStruct((b, w), jnp.float32)
-        for w in (1, 1, 1, 1, n, n, m))
+    j = nid.shape[-1]
+    widths_in = (SCALAR_LANES, n, n, m, two_k, two_k, two_k, two_k,
+                 two_k // 2, j, j)
+    widths_out = (SCALAR_LANES, n, n, m)
     return pl.pallas_call(
         functools.partial(_kernel, exact_smax=exact_smax),
         grid=(b,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        in_specs=[row_spec(w) for w in widths_in],
+        out_specs=[row_spec(w) for w in widths_out],
+        out_shape=tuple(jax.ShapeDtypeStruct((b, 1, w), jnp.float32)
+                        for w in widths_out),
         interpret=interpret,
-    )(q, s_total, s_max, strengths, node_mask, edge_weights,
+    )(scalars, strengths, node_mask, edge_weights,
       ep_ids, ep_dw, ep_wold, ep_mask, eslot, nid, nflag)
 
 
 @functools.partial(jax.jit, static_argnames=("exact_smax", "interpret"))
 def sparse_tick_pallas_stacked(
-    q: jax.Array,           # (S, B, 1) f32
-    s_total: jax.Array,     # (S, B, 1) f32
-    s_max: jax.Array,       # (S, B, 1) f32
-    strengths: jax.Array,   # (S, B, n_slots) f32
-    node_mask: jax.Array,   # (S, B, n_slots) f32
-    edge_weights: jax.Array,  # (S, B, m_pad) f32
-    ep_ids: jax.Array,      # (S, B, 2k) int32, [senders | receivers]
-    ep_dw: jax.Array,       # (S, B, 2k) f32
-    ep_wold: jax.Array,     # (S, B, 2k) f32
-    ep_mask: jax.Array,     # (S, B, 2k) f32
-    eslot: jax.Array,       # (S, B, k) int32 edge-store slots
-    nid: jax.Array,         # (S, B, j_pad) int32
-    nflag: jax.Array,       # (S, B, j_pad) f32
+    scalars: jax.Array,     # (S, B, 1, 128) f32 lanes [q, S, s_max, 0...]
+    strengths: jax.Array,   # (S, B, 1, n_slots) f32
+    node_mask: jax.Array,   # (S, B, 1, n_slots) f32
+    edge_weights: jax.Array,  # (S, B, 1, m_pad) f32
+    ep_ids: jax.Array,      # (S, B, 1, 2k) int32, [senders | receivers]
+    ep_dw: jax.Array,       # (S, B, 1, 2k) f32
+    ep_wold: jax.Array,     # (S, B, 1, 2k) f32
+    ep_mask: jax.Array,     # (S, B, 1, 2k) f32
+    eslot: jax.Array,       # (S, B, 1, k) int32 edge-store slots
+    nid: jax.Array,         # (S, B, 1, j_pad) int32
+    nflag: jax.Array,       # (S, B, 1, j_pad) f32
     exact_smax: bool = False,
     interpret: bool = False,
 ):
@@ -269,44 +268,34 @@ def sparse_tick_pallas_stacked(
     ONE `pallas_call`.
 
     Same spelling as `stream_tick.stream_tick_pallas_stacked`: the grid
-    extends to ``(S, B)`` and every BlockSpec squeezes the leading shard
-    axis (block shape ``(None, 1, width)``, index map ``(si, bi, 0)``),
-    so each grid step sees the per-batch entry point's ``(1, w)`` refs
-    and the per-step kernel body — and its VMEM footprint — is reused
-    verbatim.
+    extends to ``(S, B)`` and every BlockSpec squeezes the shard and
+    stream axes (`stacked_row_spec`), so each grid step sees the
+    per-batch entry point's ``(1, w)`` refs and the per-step kernel
+    body — and its VMEM footprint — is reused verbatim.
     """
-    s, b, n = strengths.shape
-    m = edge_weights.shape[2]
-    two_k = ep_ids.shape[2]
+    s, b, _, n = strengths.shape
+    m = edge_weights.shape[-1]
+    two_k = ep_ids.shape[-1]
     assert two_k % 256 == 0 and n % 128 == 0 and m % 128 == 0, (
         f"endpoint axis 2k={two_k}, slot axis n={n} and store axis "
         f"m={m} must be lane-aligned (ops.prepare pads them)")
-    assert eslot.shape[2] == two_k // 2, (
-        f"eslot axis {eslot.shape[2]} must equal k={two_k // 2}")
+    assert eslot.shape[-1] == two_k // 2, (
+        f"eslot axis {eslot.shape[-1]} must equal k={two_k // 2}")
     assert two_k <= MAX_ENDPOINTS, (
         f"2k={two_k} endpoints exceed the sparse-tick VMEM ceiling; "
         "ops.py routes such tiles to the vmapped path")
 
-    def tile(width):
-        return pl.BlockSpec((None, 1, width),
-                            lambda si, bi: (si, bi, 0),
-                            memory_space=pltpu.VMEM)
-
-    j = nid.shape[2]
-    in_specs = [tile(1), tile(1), tile(1), tile(n), tile(n), tile(m),
-                tile(two_k), tile(two_k), tile(two_k), tile(two_k),
-                tile(two_k // 2), tile(j), tile(j)]
-    out_specs = [tile(1), tile(1), tile(1), tile(1), tile(n), tile(n),
-                 tile(m)]
-    out_shape = tuple(
-        jax.ShapeDtypeStruct((s, b, w), jnp.float32)
-        for w in (1, 1, 1, 1, n, n, m))
+    j = nid.shape[-1]
+    widths_in = (SCALAR_LANES, n, n, m, two_k, two_k, two_k, two_k,
+                 two_k // 2, j, j)
+    widths_out = (SCALAR_LANES, n, n, m)
     return pl.pallas_call(
         functools.partial(_kernel, exact_smax=exact_smax),
         grid=(s, b),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        in_specs=[stacked_row_spec(w) for w in widths_in],
+        out_specs=[stacked_row_spec(w) for w in widths_out],
+        out_shape=tuple(jax.ShapeDtypeStruct((s, b, 1, w), jnp.float32)
+                        for w in widths_out),
         interpret=interpret,
-    )(q, s_total, s_max, strengths, node_mask, edge_weights,
+    )(scalars, strengths, node_mask, edge_weights,
       ep_ids, ep_dw, ep_wold, ep_mask, eslot, nid, nflag)

@@ -30,7 +30,9 @@ import jax.numpy as jnp
 from repro.core.sparse import EDGE_SLOT_SENTINEL, SparseStreamState
 from repro.graphs.types import GraphDelta
 from repro.kernels import dispatch
+from repro.kernels.dispatch import as_rows, pack_scalar_slab
 from repro.kernels.dispatch import ceil_to as _ceil_to
+from repro.kernels.dispatch import pad_last as _pad_last
 from repro.kernels.sparse_tick.kernel import (
     MAX_ENDPOINTS,
     sparse_tick_pallas,
@@ -40,14 +42,6 @@ from repro.kernels.sparse_tick.ref import sparse_tick_ref
 
 _LANE = dispatch.LANE
 _SUBLANE = dispatch.SUBLANE
-
-
-def _pad_last(x: jax.Array, width: int, value=0) -> jax.Array:
-    pad = width - x.shape[-1]
-    if pad == 0:
-        return x
-    cfg = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
-    return jnp.pad(x, cfg, constant_values=value)
 
 
 def sparse_tick_vmem_bytes(n_slots: int, m_pad: int, k_pad: int,
@@ -123,7 +117,8 @@ def prepare_sparse_tick(states: SparseStreamState, deltas: GraphDelta):
     Pads the edge axis to the lane multiple (mask 0, sentinel slot),
     the slot and store axes to the lane multiple (inactive zero slots —
     exact by padding invariance), and the node-slot axis to the sublane
-    multiple (flag 0).
+    multiple (flag 0); scalars and rows take `stream_tick`'s slab and
+    ``(…, 1, w)`` block forms.
 
     Leading-dim agnostic: every op works on the last axis, so the same
     preparation serves the per-batch ``(B, ·)`` spelling and the
@@ -156,13 +151,21 @@ def prepare_sparse_tick(states: SparseStreamState, deltas: GraphDelta):
         nid = jnp.zeros((*lead, _SUBLANE), jnp.int32)
         nflag = jnp.zeros((*lead, _SUBLANE), jnp.float32)
 
-    return (states.q.reshape(*lead, 1),
-            states.s_total.reshape(*lead, 1),
-            states.s_max.reshape(*lead, 1),
-            _pad_last(states.strengths, n_al),
-            _pad_last(states.node_mask, n_al),
-            _pad_last(states.edge_weights, m_al),
-            ep_ids, ep_dw, ep_wold, ep_mask, eslot, nid, nflag)
+    return (pack_scalar_slab(states.q, states.s_total, states.s_max),
+            *as_rows(_pad_last(states.strengths, n_al),
+                     _pad_last(states.node_mask, n_al),
+                     _pad_last(states.edge_weights, m_al),
+                     ep_ids, ep_dw, ep_wold, ep_mask, eslot, nid, nflag))
+
+
+def _unpack(sc2, str2, mask2, ew2, n, m, layout
+            ) -> Tuple[jax.Array, SparseStreamState]:
+    """Kernel outputs → (scores, SparseStreamState), any leading dims."""
+    new_states = SparseStreamState(
+        q=sc2[..., 0, 1], s_total=sc2[..., 0, 2], s_max=sc2[..., 0, 3],
+        strengths=str2[..., 0, :n], node_mask=mask2[..., 0, :n],
+        edge_weights=ew2[..., 0, :m], layout=layout)
+    return sc2[..., 0, 0], new_states
 
 
 def sparse_tick_fused(
@@ -188,13 +191,9 @@ def sparse_tick_fused(
         return sparse_tick_ref(states, deltas, exact_smax=exact_smax)
     interpret = dispatch.default_interpret(interpret)
     prep = prepare_sparse_tick(states, deltas)
-    dist, q2, s2, smax2, str2, mask2, ew2 = sparse_tick_pallas(
+    outs = sparse_tick_pallas(
         *prep, exact_smax=exact_smax, interpret=interpret)
-    new_states = SparseStreamState(
-        q=q2[:, 0], s_total=s2[:, 0], s_max=smax2[:, 0],
-        strengths=str2[..., :n], node_mask=mask2[..., :n],
-        edge_weights=ew2[..., :m], layout=states.layout)
-    return dist[:, 0], new_states
+    return _unpack(*outs, n, m, states.layout)
 
 
 def sparse_tick_fused_stacked(
@@ -231,10 +230,6 @@ def sparse_tick_fused_stacked(
             states, deltas)
     interpret = dispatch.default_interpret(interpret)
     prep = prepare_sparse_tick(states, deltas)
-    dist, q2, s2, smax2, str2, mask2, ew2 = sparse_tick_pallas_stacked(
+    outs = sparse_tick_pallas_stacked(
         *prep, exact_smax=exact_smax, interpret=interpret)
-    new_states = SparseStreamState(
-        q=q2[..., 0], s_total=s2[..., 0], s_max=smax2[..., 0],
-        strengths=str2[..., :n], node_mask=mask2[..., :n],
-        edge_weights=ew2[..., :m], layout=states.layout)
-    return dist[..., 0], new_states
+    return _unpack(*outs, n, m, states.layout)

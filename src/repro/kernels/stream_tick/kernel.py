@@ -1,10 +1,11 @@
 """Pallas TPU megakernel: one full serving tick per stream, in VMEM.
 
 Grid: (B,) over the stream slots of a stacked batch. Each grid step
-loads ONE stream's FingerState row — (q, S, s_max) scalars plus the
-(n_pad,) strengths and node mask — and one tick's delta in *tiled
-endpoint* form (ops.py concatenates the k_pad senders and receivers into
-2k_pad endpoint slots, duplicating the per-edge Δw/w_old/mask payloads),
+loads ONE stream's FingerState row — the (q, S, s_max) scalars packed
+into one lane slab plus the (n_pad,) strengths and node mask — and one
+tick's delta in *tiled endpoint* form (ops.py concatenates the k_pad
+senders and receivers into 2k_pad endpoint slots, duplicating the
+per-edge Δw/w_old/mask payloads),
 then fuses the whole Algorithm-2 step without writing any intermediate
 back to HBM:
 
@@ -34,6 +35,22 @@ contraction, which is order-independent — sortedness only matters for
 indicator temporaries bound VMEM; ops.py routes oversized (k_pad, n_pad)
 tiles to the vmapped XLA path before reaching this kernel's asserts.
 
+Block layout: every per-stream operand is passed as ``(B, 1, width)``
+with block ``(None, 1, width)``, so each grid step sees a ``(1, width)``
+ref whose trailing two block dims equal the array's — the form Mosaic
+accepts for one-row blocks (a ``(1, width)`` block of a ``(B, width)``
+array is refused: its sublane dim is neither 8-aligned nor the full
+axis). The scalars travel as a ``(B, 1, 128)`` slab (lanes 0-2 in:
+q, S, s_max; lanes 0-3 out: dist, q', S', s_max') because Mosaic
+cannot store a scalar to VMEM; `dispatch.pack_lanes` builds the
+outgoing row (the layout helpers live in `kernels.dispatch`).
+The contractions that carry values (strength gather, segment sums,
+Δs carry-forward) run at ``Precision.HIGHEST``. At the default
+precision, Mosaic contracts f32 operands to about bf16 accuracy: on a
+TPU v5e the weighted parity checks of this kernel, `sparse_tick` and
+`delta_stats` then miss the XLA reference by 3e-3 to 6e-3 relative.
+The 0/1 gate contractions are exact at any precision.
+
 Adaptation note: the CUDA analogue would be a per-stream thread-block
 chaining gather → sort → segmented-reduce → scatter kernels through
 shared memory; on TPU the sequential grid plus MXU indicator
@@ -47,13 +64,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dispatch import (
+    SCALAR_LANES,
+    pack_lanes,
+    row_spec,
+    stacked_row_spec,
+)
 
 # VMEM ceiling on the endpoint axis: the (2k, 2k) indicator temporaries
-# are ~3 x (2k)^2 x 4 B, so 2048 endpoints stay well inside the ~16 MB
-# per-core budget (ops.py enforces the full-tile estimate incl. the
-# (2k, n) one-hot before dispatching here).
+# are ~4 x (2k)^2 x 4 B; ops.py enforces the full-tile estimate (incl.
+# the (2k, n) one-hot) against `dispatch.vmem_budget_bytes()` before
+# dispatching here, which binds long before this ceiling does.
 MAX_ENDPOINTS = 2048
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _h_tilde(q, s_total, s_max):
@@ -63,10 +88,10 @@ def _h_tilde(q, s_total, s_max):
     return jnp.where(s_total > 0, -q * jnp.log(arg), 0.0)
 
 
-def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref,
+def _kernel(sc_ref, str_ref, mask_ref,
             ep_ids_ref, ep_dw_ref, ep_wold_ref, ep_mask_ref,
             nid_ref, nflag_ref,
-            dist_ref, qo_ref, so_ref, smaxo_ref, stro_ref, masko_ref,
+            sco_ref, stro_ref, masko_ref,
             *, exact_smax: bool):
     f32 = jnp.float32
     strengths = str_ref[0, :]          # (n,) carried nodal strengths
@@ -97,7 +122,7 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref,
     onehot = (ep_b == node_col).astype(f32)          # (2k, n)
     gate_ep = jnp.dot(onehot, mask_joined.reshape(n, 1),
                       preferred_element_type=f32)[:, 0]
-    s_ep = jnp.dot(onehot, strengths.reshape(n, 1),
+    s_ep = jnp.dot(onehot, strengths.reshape(n, 1), precision=_HIGHEST,
                    preferred_element_type=f32)[:, 0]
     # An edge is live iff BOTH endpoints are: the partner of endpoint e
     # sits at e +/- k, a fixed permutation applied as one contraction.
@@ -115,7 +140,7 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref,
     v_r = jax.lax.broadcast_in_dim(valid, (two_k, two_k), (0,))
     v_c = jax.lax.broadcast_in_dim(valid, (two_k, two_k), (1,))
     same = (ids_r == ids_c).astype(f32) * v_r * v_c
-    ds_here = jnp.dot(same, vals.reshape(two_k, 1),
+    ds_here = jnp.dot(same, vals.reshape(two_k, 1), precision=_HIGHEST,
                       preferred_element_type=f32)[:, 0]
     cnt_before = jnp.sum(same * (col2 < row2).astype(f32), axis=1)
     head = jnp.logical_and(valid > 0.0, cnt_before == 0.0)
@@ -136,13 +161,13 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref,
 
     # Dense Δs carry-forward: transpose contraction against the one-hot
     # replaces the (n,) endpoint scatter.
-    ds_dense = jnp.dot(vals.reshape(1, two_k), onehot,
+    ds_dense = jnp.dot(vals.reshape(1, two_k), onehot, precision=_HIGHEST,
                        preferred_element_type=f32)[0, :]
 
     # -- 4. Theorem-2 scalar updates (ΔG/2 and ΔG from one reduction) ---
-    q0 = q_ref[0, 0]
-    s0 = s_ref[0, 0]
-    smax0 = smax_ref[0, 0]
+    q0 = sc_ref[0, 0]
+    s0 = sc_ref[0, 1]
+    smax0 = sc_ref[0, 2]
     c0 = jnp.where(s0 > 0, 1.0 / s0, 0.0)
 
     def theorem2(f, node_term, edge_term):
@@ -181,33 +206,30 @@ def _kernel(q_ref, s_ref, smax_ref, str_ref, mask_ref,
     h_full = _h_tilde(q_full, s_full, smax_full)
     div = h_half - 0.5 * (h_pre + h_full)
 
-    dist_ref[0, 0] = jnp.sqrt(jnp.maximum(div, 0.0))
-    qo_ref[0, 0] = q_full
-    so_ref[0, 0] = s_full
-    smaxo_ref[0, 0] = smax_full
+    sco_ref[...] = pack_lanes(sco_ref.shape, jnp.sqrt(jnp.maximum(div, 0.0)),
+                              q_full, s_full, smax_full)
     stro_ref[0, :] = str_full
     masko_ref[0, :] = mask_after
 
 
 @functools.partial(jax.jit, static_argnames=("exact_smax", "interpret"))
 def stream_tick_pallas(
-    q: jax.Array,          # (B, 1) f32
-    s_total: jax.Array,    # (B, 1) f32
-    s_max: jax.Array,      # (B, 1) f32
-    strengths: jax.Array,  # (B, n_pad) f32
-    node_mask: jax.Array,  # (B, n_pad) f32
-    ep_ids: jax.Array,     # (B, 2k) int32, [senders | receivers]
-    ep_dw: jax.Array,      # (B, 2k) f32, per-edge Δw tiled to endpoints
-    ep_wold: jax.Array,    # (B, 2k) f32, pre-change weights tiled
-    ep_mask: jax.Array,    # (B, 2k) f32, edge validity tiled
-    nid: jax.Array,        # (B, j_pad) int32 node slot ids
-    nflag: jax.Array,      # (B, j_pad) f32 +1/-1/0
+    scalars: jax.Array,    # (B, 1, 128) f32 lanes [q, S, s_max, 0...]
+    strengths: jax.Array,  # (B, 1, n_pad) f32
+    node_mask: jax.Array,  # (B, 1, n_pad) f32
+    ep_ids: jax.Array,     # (B, 1, 2k) int32, [senders | receivers]
+    ep_dw: jax.Array,      # (B, 1, 2k) f32, per-edge Δw tiled to endpoints
+    ep_wold: jax.Array,    # (B, 1, 2k) f32, pre-change weights tiled
+    ep_mask: jax.Array,    # (B, 1, 2k) f32, edge validity tiled
+    nid: jax.Array,        # (B, 1, j_pad) int32 node slot ids
+    nflag: jax.Array,      # (B, 1, j_pad) f32 +1/-1/0
     exact_smax: bool = False,
     interpret: bool = False,
 ):
-    """Batched fused tick → (dist, q', S', s_max', strengths', mask')."""
-    b, n = strengths.shape
-    two_k = ep_ids.shape[1]
+    """Batched fused tick → (scalars', strengths', mask'), where
+    ``scalars'`` holds lanes [dist, q', S', s_max']."""
+    b, _, n = strengths.shape
+    two_k = ep_ids.shape[-1]
     assert two_k % 256 == 0 and n % 128 == 0, (
         f"endpoint axis 2k={two_k} and node axis n={n} must be "
         "lane-aligned (ops.prepare pads them)")
@@ -215,42 +237,32 @@ def stream_tick_pallas(
         f"2k={two_k} endpoints exceed the fused-tick VMEM ceiling; "
         "ops.py routes such tiles to the vmapped path")
 
-    def row(width):
-        return pl.BlockSpec((1, width), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-
-    j = nid.shape[1]
-    in_specs = [row(1), row(1), row(1), row(n), row(n),
-                row(two_k), row(two_k), row(two_k), row(two_k),
-                row(j), row(j)]
-    out_specs = [row(1), row(1), row(1), row(1), row(n), row(n)]
-    out_shape = tuple(
-        jax.ShapeDtypeStruct((b, w), jnp.float32)
-        for w in (1, 1, 1, 1, n, n))
+    j = nid.shape[-1]
+    widths_in = (SCALAR_LANES, n, n, two_k, two_k, two_k, two_k, j, j)
+    widths_out = (SCALAR_LANES, n, n)
     return pl.pallas_call(
         functools.partial(_kernel, exact_smax=exact_smax),
         grid=(b,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        in_specs=[row_spec(w) for w in widths_in],
+        out_specs=[row_spec(w) for w in widths_out],
+        out_shape=tuple(jax.ShapeDtypeStruct((b, 1, w), jnp.float32)
+                        for w in widths_out),
         interpret=interpret,
-    )(q, s_total, s_max, strengths, node_mask,
+    )(scalars, strengths, node_mask,
       ep_ids, ep_dw, ep_wold, ep_mask, nid, nflag)
 
 
 @functools.partial(jax.jit, static_argnames=("exact_smax", "interpret"))
 def stream_tick_pallas_stacked(
-    q: jax.Array,          # (S, B, 1) f32
-    s_total: jax.Array,    # (S, B, 1) f32
-    s_max: jax.Array,      # (S, B, 1) f32
-    strengths: jax.Array,  # (S, B, n_pad) f32
-    node_mask: jax.Array,  # (S, B, n_pad) f32
-    ep_ids: jax.Array,     # (S, B, 2k) int32, [senders | receivers]
-    ep_dw: jax.Array,      # (S, B, 2k) f32
-    ep_wold: jax.Array,    # (S, B, 2k) f32
-    ep_mask: jax.Array,    # (S, B, 2k) f32
-    nid: jax.Array,        # (S, B, j_pad) int32
-    nflag: jax.Array,      # (S, B, j_pad) f32
+    scalars: jax.Array,    # (S, B, 1, 128) f32 lanes [q, S, s_max, 0...]
+    strengths: jax.Array,  # (S, B, 1, n_pad) f32
+    node_mask: jax.Array,  # (S, B, 1, n_pad) f32
+    ep_ids: jax.Array,     # (S, B, 1, 2k) int32, [senders | receivers]
+    ep_dw: jax.Array,      # (S, B, 1, 2k) f32
+    ep_wold: jax.Array,    # (S, B, 1, 2k) f32
+    ep_mask: jax.Array,    # (S, B, 1, 2k) f32
+    nid: jax.Array,        # (S, B, 1, j_pad) int32
+    nflag: jax.Array,      # (S, B, 1, j_pad) f32
     exact_smax: bool = False,
     interpret: bool = False,
 ):
@@ -258,15 +270,14 @@ def stream_tick_pallas_stacked(
     `pallas_call`.
 
     The grid is extended to ``(S, B)`` and every BlockSpec squeezes the
-    leading shard axis (block shape ``(None, 1, width)``, index map
-    ``(si, bi, 0)``), so each grid step sees the exact same ``(1, w)``
-    refs as the per-batch entry point and the per-step kernel body —
-    and its VMEM footprint — is reused verbatim. Semantically this is
+    shard and stream axes (`stacked_row_spec`), so each grid step sees
+    the exact same ``(1, w)`` refs as the per-batch entry point and the
+    per-step kernel body — and its VMEM footprint — is reused verbatim. Semantically this is
     ``vmap(stream_tick_pallas)`` over the shard axis, spelled as one
     launch instead of S.
     """
-    s, b, n = strengths.shape
-    two_k = ep_ids.shape[2]
+    s, b, _, n = strengths.shape
+    two_k = ep_ids.shape[-1]
     assert two_k % 256 == 0 and n % 128 == 0, (
         f"endpoint axis 2k={two_k} and node axis n={n} must be "
         "lane-aligned (ops.prepare pads them)")
@@ -274,25 +285,16 @@ def stream_tick_pallas_stacked(
         f"2k={two_k} endpoints exceed the fused-tick VMEM ceiling; "
         "ops.py routes such tiles to the vmapped path")
 
-    def tile(width):
-        return pl.BlockSpec((None, 1, width),
-                            lambda si, bi: (si, bi, 0),
-                            memory_space=pltpu.VMEM)
-
-    j = nid.shape[2]
-    in_specs = [tile(1), tile(1), tile(1), tile(n), tile(n),
-                tile(two_k), tile(two_k), tile(two_k), tile(two_k),
-                tile(j), tile(j)]
-    out_specs = [tile(1), tile(1), tile(1), tile(1), tile(n), tile(n)]
-    out_shape = tuple(
-        jax.ShapeDtypeStruct((s, b, w), jnp.float32)
-        for w in (1, 1, 1, 1, n, n))
+    j = nid.shape[-1]
+    widths_in = (SCALAR_LANES, n, n, two_k, two_k, two_k, two_k, j, j)
+    widths_out = (SCALAR_LANES, n, n)
     return pl.pallas_call(
         functools.partial(_kernel, exact_smax=exact_smax),
         grid=(s, b),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        in_specs=[stacked_row_spec(w) for w in widths_in],
+        out_specs=[stacked_row_spec(w) for w in widths_out],
+        out_shape=tuple(jax.ShapeDtypeStruct((s, b, 1, w), jnp.float32)
+                        for w in widths_out),
         interpret=interpret,
-    )(q, s_total, s_max, strengths, node_mask,
+    )(scalars, strengths, node_mask,
       ep_ids, ep_dw, ep_wold, ep_mask, nid, nflag)

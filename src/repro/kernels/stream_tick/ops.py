@@ -30,7 +30,9 @@ import jax.numpy as jnp
 from repro.core.state import FingerState
 from repro.graphs.types import GraphDelta
 from repro.kernels import dispatch
+from repro.kernels.dispatch import as_rows, pack_scalar_slab
 from repro.kernels.dispatch import ceil_to as _ceil_to
+from repro.kernels.dispatch import pad_last as _pad_last
 from repro.kernels.stream_tick.kernel import (
     MAX_ENDPOINTS,
     stream_tick_pallas,
@@ -40,14 +42,6 @@ from repro.kernels.stream_tick.ref import stream_tick_ref
 
 _LANE = dispatch.LANE
 _SUBLANE = dispatch.SUBLANE
-
-
-def _pad_last(x: jax.Array, width: int, value=0) -> jax.Array:
-    pad = width - x.shape[-1]
-    if pad == 0:
-        return x
-    cfg = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
-    return jnp.pad(x, cfg, constant_values=value)
 
 
 def fused_tick_vmem_bytes(n_pad: int, k_pad: int,
@@ -102,8 +96,10 @@ def prepare_stream_tick(states: FingerState, deltas: GraphDelta):
     Pads the edge axis to the lane multiple (mask 0), the node axis to
     the lane multiple (inactive, zero-strength slots — exact by padding
     invariance), the node-slot axis to the sublane multiple (flag 0),
-    and tiles the per-edge payloads onto the concatenated
-    [senders | receivers] endpoint slots.
+    tiles the per-edge payloads onto the concatenated
+    [senders | receivers] endpoint slots, packs (q, S, s_max) into the
+    scalar slab, and gives every row operand its ``(…, 1, w)`` block
+    axis.
 
     Leading-dim agnostic: every op works on the last axis, so the same
     preparation serves the per-batch ``(B, ·)`` spelling and the
@@ -132,12 +128,19 @@ def prepare_stream_tick(states: FingerState, deltas: GraphDelta):
         nid = jnp.zeros((*lead, _SUBLANE), jnp.int32)
         nflag = jnp.zeros((*lead, _SUBLANE), jnp.float32)
 
-    return (states.q.reshape(*lead, 1),
-            states.s_total.reshape(*lead, 1),
-            states.s_max.reshape(*lead, 1),
-            _pad_last(states.strengths, n_al),
-            _pad_last(states.node_mask, n_al),
-            ep_ids, ep_dw, ep_wold, ep_mask, nid, nflag)
+    return (pack_scalar_slab(states.q, states.s_total, states.s_max),
+            *as_rows(_pad_last(states.strengths, n_al),
+                     _pad_last(states.node_mask, n_al),
+                     ep_ids, ep_dw, ep_wold, ep_mask, nid, nflag))
+
+
+def _unpack(sc2, str2, mask2, n, layout) -> Tuple[jax.Array, FingerState]:
+    """Kernel outputs → (scores, FingerState), any leading dims."""
+    new_states = FingerState(
+        q=sc2[..., 0, 1], s_total=sc2[..., 0, 2], s_max=sc2[..., 0, 3],
+        strengths=str2[..., 0, :n], node_mask=mask2[..., 0, :n],
+        layout=layout)
+    return sc2[..., 0, 0], new_states
 
 
 def stream_tick_fused(
@@ -172,13 +175,9 @@ def stream_tick_fused(
                                method="dense")
     interpret = dispatch.default_interpret(interpret)
     prep = prepare_stream_tick(states, deltas)
-    dist, q2, s2, smax2, str2, mask2 = stream_tick_pallas(
+    sc2, str2, mask2 = stream_tick_pallas(
         *prep, exact_smax=exact_smax, interpret=interpret)
-    new_states = FingerState(
-        q=q2[:, 0], s_total=s2[:, 0], s_max=smax2[:, 0],
-        strengths=str2[..., :n], node_mask=mask2[..., :n],
-        layout=states.layout)
-    return dist[:, 0], new_states
+    return _unpack(sc2, str2, mask2, n, states.layout)
 
 
 def stream_tick_fused_stacked(
@@ -222,10 +221,6 @@ def stream_tick_fused_stacked(
                                                            deltas)
     interpret = dispatch.default_interpret(interpret)
     prep = prepare_stream_tick(states, deltas)
-    dist, q2, s2, smax2, str2, mask2 = stream_tick_pallas_stacked(
+    sc2, str2, mask2 = stream_tick_pallas_stacked(
         *prep, exact_smax=exact_smax, interpret=interpret)
-    new_states = FingerState(
-        q=q2[..., 0], s_total=s2[..., 0], s_max=smax2[..., 0],
-        strengths=str2[..., :n], node_mask=mask2[..., :n],
-        layout=states.layout)
-    return dist[..., 0], new_states
+    return _unpack(sc2, str2, mask2, n, states.layout)

@@ -182,7 +182,11 @@ class ServiceConfig:
         every jit in the process, not just this service's plans.
         Setting a *different* directory in a process that already
         enabled one raises a named error rather than silently
-        re-rooting unrelated caches.
+        re-rooting unrelated caches. A ``JAX_COMPILATION_CACHE_DIR``
+        environment variable takes precedence: the cache then stays
+        where JAX put it and this field is ignored. Entry points pass a
+        fixed path inside the checkout (``.jax_cache/``), never a
+        temporary or per-run one — the path is part of the cache key.
     data_axis / pod_axis : mesh axis names the sharded placements bind.
     """
 

@@ -45,7 +45,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.state import FingerState
-from repro.distributed.sharding import shard_map
+from repro.distributed.sharding import auto_mesh
 from repro.engine.stream import StreamEngine
 from repro.graphs.layout import NodeLayout
 from repro.graphs.types import GraphDelta
@@ -279,8 +279,8 @@ class _ShardedPlanBase(ExecutionPlan):
         # resident B/p streams) under method="fused_tick".
         body = self.engine._tick_body
         self._tick = jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                      out_specs=(spec, spec), check_rep=False),
+            jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                          out_specs=(spec, spec), check_vma=False),
             donate_argnums=(0,))
 
     def tick(self, states, deltas):
@@ -307,8 +307,8 @@ class _ShardedPlanBase(ExecutionPlan):
             vals, idx = jax.lax.top_k(scores, k)
             return vals, self._shard_offset_ids(idx)
 
-        cand = shard_map(body, mesh=self.mesh, in_specs=(spec,),
-                         out_specs=(spec, spec), check_rep=False)
+        cand = jax.shard_map(body, mesh=self.mesh, in_specs=(spec,),
+                             out_specs=(spec, spec), check_vma=False)
 
         def topk(scores):
             # (p·k,) candidates — the only cross-shard materialization.
@@ -369,8 +369,8 @@ class MultiPodPlan(_ShardedPlanBase):
             return pv[None], ci[pos][None]  # (1, k) per pod, data-repl.
 
         out_spec = P(pod_axis, None)
-        fn = shard_map(body, mesh=self.mesh, in_specs=(spec,),
-                       out_specs=(out_spec, out_spec), check_rep=False)
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=(spec,),
+                           out_specs=(out_spec, out_spec), check_vma=False)
         return jax.jit(fn)
 
 
@@ -464,12 +464,11 @@ def build_plan(config: ServiceConfig,
         return LocalPlan(config)
     if config.placement == "sharded":
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),),
-                                 (config.data_axis,))
+            mesh = auto_mesh((jax.device_count(),), (config.data_axis,))
         return ShardedPlan(config, mesh)
     if config.placement == "multipod":
         if mesh is None:
-            mesh = jax.make_mesh((1, jax.device_count()),
-                                 (config.pod_axis, config.data_axis))
+            mesh = auto_mesh((1, jax.device_count()),
+                             (config.pod_axis, config.data_axis))
         return MultiPodPlan(config, mesh)
     raise ServiceConfigError(f"unknown placement {config.placement!r}")

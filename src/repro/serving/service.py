@@ -45,6 +45,7 @@ underneath as the plan-internal executor.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 
@@ -52,6 +53,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh
 
 from repro.core.state import FingerState
@@ -92,23 +94,32 @@ def _apply_compilation_cache(config: ServiceConfig) -> None:
     """Enable JAX's persistent on-disk compilation cache at the
     config's directory (no-op when unset).
 
-    The cache is PROCESS-GLOBAL JAX state: every jit in the process —
-    not just this service's plans — reads/writes it once enabled, and
-    it cannot be re-rooted per service. Re-opening with the same
-    directory is an idempotent no-op; a *different* directory raises
-    rather than silently moving unrelated caches. The compile-time /
-    entry-size floors are lowered to zero so the small serving ticks
-    actually persist (the JAX defaults skip sub-second compiles)."""
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins: JAX roots
+    the cache there itself, and the configured directory is then
+    neither applied nor checked against it. Otherwise the cache is
+    PROCESS-GLOBAL JAX state: every jit in the process — not just this
+    service's plans — reads/writes it once enabled, and it cannot be
+    re-rooted per service. Re-opening with the same directory is an
+    idempotent no-op; a *different* directory raises rather than
+    silently moving unrelated caches. JAX decides whether the cache is
+    in use at its first compile, so rooting it later resets that
+    decision. The compile-time / entry-size floors are lowered to zero
+    so the small serving ticks actually persist (the JAX defaults skip
+    sub-second compiles)."""
     target = config.compilation_cache_dir
     if target is None:
         return
-    current = jax.config.jax_compilation_cache_dir
-    if current is not None and current != target:
-        raise ServiceConfigError(
-            f"compilation_cache_dir={target!r} conflicts with the "
-            f"process-global JAX compilation cache already rooted at "
-            f"{current!r}; one process serves one cache directory")
-    jax.config.update("jax_compilation_cache_dir", target)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        current = jax.config.jax_compilation_cache_dir
+        if current is not None and current != target:
+            raise ServiceConfigError(
+                f"compilation_cache_dir={target!r} conflicts with the "
+                f"process-global JAX compilation cache already rooted "
+                f"at {current!r}; one process serves one cache "
+                "directory")
+        if current is None:
+            jax.config.update("jax_compilation_cache_dir", target)
+            compilation_cache.reset_cache()
     for knob, value in (
             ("jax_persistent_cache_min_compile_time_secs", 0),
             ("jax_persistent_cache_min_entry_size_bytes", -1)):

@@ -27,8 +27,9 @@ from repro.distributed.finger_dist import (
 from repro.graphs import EdgeList
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.spectral import power_iteration_lmax
+from repro.distributed.sharding import auto_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 g = erdos_renyi(200, 0.05, seed=3, weighted=True)
 el = EdgeList.from_dense(g)
 el_sharded = shard_edge_list(el, mesh, "data")

@@ -222,6 +222,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.distributed.sharding import auto_mesh
 from repro.engine import StreamEngine, stack_deltas
 from repro.graphs import GraphDelta
 from repro.graphs.generators import erdos_renyi
@@ -243,7 +244,7 @@ stacked = stack_deltas(deltas)
 engine = StreamEngine()
 local_d, _ = engine.tick(StreamEngine.init_states(graphs), stacked)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 tick = engine.make_sharded_tick(mesh, "data")
 st = engine.shard_states(StreamEngine.init_states(graphs), mesh, "data")
 sharding = NamedSharding(mesh, P("data"))

@@ -679,6 +679,36 @@ class TestPoolTickGrouping:
         with pytest.raises(PoolGroupError, match="mixed"):
             pooltick.warm_pool_tick([(dense, lay), (fused, lay)])
 
+    def test_warm_covers_groups_over_the_vmem_guard(self):
+        # A sparse pool whose tile the VMEM guard refuses ticks shard by
+        # shard; warm() must have compiled those per-shard ticks too.
+        from repro.analysis.sanitize import compile_budget
+        from repro.kernels import dispatch
+
+        cfg = FleetConfig(pools=(
+            PoolSpec(name="slots", n_pad=24, shards=2,
+                     streams_per_shard=2, k_pad=4, j_pad=2,
+                     method="sparse_tick", n_slots=12, m_pad=24),))
+        rng = np.random.default_rng(5)
+        with dispatch.vmem_budget(1024), FingerFleet.open(cfg) as fleet:
+            for i, n in enumerate("abc"):
+                fleet.admit(n, _graph(8, i + 40))
+            fleet.warm()
+            with compile_budget(0, "polls of a guard-refused pool"):
+                for _ in range(2):
+                    ds = {}
+                    for n in "abc":
+                        i, j = sorted(rng.choice(8, 2, replace=False)
+                                      .tolist())
+                        ds[n] = GraphDelta.from_arrays(
+                            [i], [j], [1.5], [0.0], n_nodes=24, k_pad=4,
+                            j_pad=2)
+                    fleet.ingest(ds)
+                    fleet.poll()
+                    fleet.scores()
+                    fleet.top_anomalies(k=2)
+            assert fleet.last_poll_launches == 2  # one per shard
+
 
 class TestWalRetention:
     """`FleetConfig.wal_retention_ticks`: ingest prunes WAL entries

@@ -84,7 +84,8 @@ import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze
-mesh = jax.make_mesh((4,), ("d",))
+from repro.distributed.sharding import auto_mesh
+mesh = auto_mesh((4,), ("d",))
 sh = NamedSharding(mesh, P("d", None))
 def f(x):
     y = x @ x.T          # needs all-gather of the sharded operand

@@ -23,6 +23,7 @@ from repro.core import (
     update_state,
     vnge_tilde,
 )
+from repro.distributed.sharding import auto_mesh
 from repro.engine import StreamEngine, stack_deltas, stack_states
 from repro.graphs import DenseGraph, GraphDelta, apply_delta_dense
 from repro.graphs.generators import erdos_renyi
@@ -408,7 +409,7 @@ class TestCheckpointedServing:
         engine.save(str(tmp_path), st, step=1)
         ref_scores, _ = engine.tick(st, ticks[1])
 
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = auto_mesh((jax.device_count(),), ("data",))
         fresh = StreamEngine()
         st2, _ = fresh.restore(str(tmp_path), mesh=mesh)
         tick = fresh.make_sharded_tick(mesh, "data")

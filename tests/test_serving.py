@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.sanitize import compile_budget, no_transfers
+from repro.distributed.sharding import auto_mesh
 from repro.engine import StreamEngine, stack_deltas
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.layout import NodeLayout
@@ -90,12 +91,12 @@ class TestConfigValidation:
                 num_shards=4)
 
     def test_local_plan_rejects_mesh(self):
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         with pytest.raises(ServiceConfigError, match="takes no mesh"):
             build_plan(self._base(topk=TopKSpec(k=2)), mesh)
 
     def test_sharded_plan_rejects_missing_axis(self):
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = auto_mesh((1,), ("model",))
         with pytest.raises(ServiceConfigError, match="no 'data' axis"):
             build_plan(self._base(placement="sharded",
                                   topk=TopKSpec(k=2)), mesh)
@@ -923,6 +924,7 @@ import json
 import numpy as np
 import jax
 
+from repro.distributed.sharding import auto_mesh
 from repro.engine import StreamEngine, stack_deltas
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.types import GraphDelta
@@ -951,8 +953,8 @@ ref = np.asarray(ref)  # the full-gather oracle, host side only
 
 out = {"n_devices": jax.device_count(), "cases": []}
 meshes = {
-    "sharded": jax.make_mesh((8,), ("data",)),
-    "multipod": jax.make_mesh((2, 4), ("pod", "data")),
+    "sharded": auto_mesh((8,), ("data",)),
+    "multipod": auto_mesh((2, 4), ("pod", "data")),
 }
 for placement, mesh in meshes.items():
     cfg = ServiceConfig(batch_size=b, n_pad=n, k_pad=k_pad,
@@ -1013,3 +1015,41 @@ def test_sharded_topk_matches_full_gather_oracle():
         assert case["candidates"] < case["b"], case
     mp = out["cases"][1]
     assert mp["per_pod_match"], mp
+
+
+_CACHE_SCRIPT = r"""
+import json, sys
+import jax, jax.numpy as jnp
+from repro.serving.config import ServiceConfig
+from repro.serving.service import _apply_compilation_cache
+
+jnp.arange(3.0).block_until_ready()  # a compile before the cache is set
+_apply_compilation_cache(ServiceConfig(
+    batch_size=1, n_pad=8, k_pad=1, compilation_cache_dir=sys.argv[1]))
+jax.jit(lambda x: x * 2.0 + 1.0)(jnp.arange(5.0)).block_until_ready()
+print(json.dumps({"dir": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compilation_cache_placed_from_outside(tmp_path, env_set):
+    """``JAX_COMPILATION_CACHE_DIR`` wins over
+    `ServiceConfig.compilation_cache_dir` (neither overridden nor
+    raised against); without it the configured directory is rooted,
+    even after earlier compiles, and entries land there."""
+    cfg_dir, env_dir = tmp_path / "configured", tmp_path / "from_env"
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
+                                     "src")
+    proc = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT,
+                           str(cfg_dir)], env=env, capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    used, unused = (env_dir, cfg_dir) if env_set else (cfg_dir, env_dir)
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["dir"] == str(used)
+    assert used.is_dir() and any(used.iterdir())
+    assert not unused.exists()

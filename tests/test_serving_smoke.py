@@ -13,6 +13,7 @@ import pytest
 
 import jax
 
+from repro.distributed.sharding import auto_mesh
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.types import GraphDelta
 from repro.serving import (
@@ -51,11 +52,11 @@ def _mesh_for(placement):
     if placement == "local":
         return None
     if placement == "sharded":
-        return jax.make_mesh((jax.device_count(),), ("data",))
+        return auto_mesh((jax.device_count(),), ("data",))
     # multipod smoke runs on a 1×N host mesh — the pod axis is size 1,
     # which still exercises the ("pod", "data") shard_map + per-pod
     # top-k code path.
-    return jax.make_mesh((1, jax.device_count()), ("pod", "data"))
+    return auto_mesh((1, jax.device_count()), ("pod", "data"))
 
 
 @pytest.mark.parametrize("placement,ingestion", [

@@ -21,16 +21,18 @@ import numpy as np
 import pytest
 from _propcheck import given, settings, strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import SparseCapacityError, finger_state
 from repro.core.sparse import (
     SlotMap,
     SparseLayout,
+    sparse_state_from_graph,
     sparse_states_from_graphs,
 )
 from repro.engine import StreamEngine, stack_deltas
-from repro.graphs import DenseGraph, GraphDelta
+from repro.graphs import DenseGraph, EdgeList, GraphDelta
 from repro.graphs.generators import erdos_renyi
 from repro.kernels.sparse_tick.ops import (
     fits_sparse_tick,
@@ -288,6 +290,37 @@ class TestEdgeCases:
         with pytest.raises(ValueError, match="virtual space"):
             sm.translate(GraphDelta.from_arrays(
                 [0], [99], [0.5], [0.0], n_nodes=100, k_pad=4))
+
+    def test_edge_list_admission_matches_dense(self):
+        """An `EdgeList` in a wide id space builds the same slot-space
+        state and SlotMap as its dense form, without densifying."""
+        g = erdos_renyi(12, 0.3, seed=4, weighted=True)
+        ids = np.random.default_rng(4).choice(
+            self.N_VIRTUAL, 12, replace=False)
+        w = np.asarray(g.weights)
+        iu, ju = np.nonzero(np.triu(w, 1))
+        mask = np.zeros(self.N_VIRTUAL, np.float32)
+        mask[ids] = 1.0
+        el = EdgeList.from_arrays(ids[iu], ids[ju], w[iu, ju],
+                                  n_nodes=self.N_VIRTUAL, node_mask=mask)
+        layout = SparseLayout(n_slots=16, m_pad=32)
+        got, got_map = sparse_state_from_graph(el, layout)
+        want, want_map = sparse_state_from_graph(el.to_dense(), layout)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert got_map.node_slot == want_map.node_slot
+        assert got_map.edge_slot == want_map.edge_slot
+
+    def test_translated_deltas_stack_on_host(self):
+        """Slot-space deltas leave `SlotMap` as host arrays and stack
+        there, so ingesting a sparse tick runs no device op."""
+        _, maps = self._dead_live()
+        stacked = stack_deltas(
+            [sm.translate(self._empty_delta()) for sm in maps])
+        leaves = jax.tree_util.tree_leaves(stacked)
+        assert all(isinstance(x, np.ndarray) for x in leaves)
+        assert stacked.edge_slots.shape == (2, 4)
 
     def test_vmem_guard(self):
         assert fits_sparse_tick(64, 256, 8, 2)

@@ -8,8 +8,10 @@ import jax.numpy as jnp
 
 from repro.baselines import veo_score
 from repro.core import finger_state, jsdist_fast, jsdist_incremental
+from repro.graphs.generators import barabasi_albert, barabasi_albert_edges
 from repro.graphs.streams import (
     churn_stream,
+    dos_attack_edge_sequence,
     dos_attack_sequence,
     hic_bifurcation_sequence,
 )
@@ -39,6 +41,52 @@ class TestDosDetection:
             dist, st = jsdist_incremental(st, d, exact_smax=True)
             inc_scores.append(float(dist))
         assert int(np.argmax(inc_scores)) == attack_at
+
+    @pytest.mark.parametrize("n,m,seed", [(40, 3, 0), (120, 2, 5)])
+    def test_ba_edge_list_is_the_dense_generator(self, n, m, seed):
+        lo, hi = barabasi_albert_edges(n, m, seed)
+        w = np.zeros((n, n))
+        w[lo, hi] = w[hi, lo] = 1.0
+        assert np.all(lo < hi)
+        np.testing.assert_array_equal(
+            w, np.asarray(barabasi_albert(n, m, seed).weights))
+
+    def test_edge_sequence_replays_and_plants_one_fan_in(self):
+        n, frac = 400, 0.05
+        first, deltas, attack_at = dos_attack_edge_sequence(
+            n=n, n_graphs=8, attack_frac=frac, seed=2)
+        valid = np.asarray(first.mask) > 0
+        edges = set(zip(np.asarray(first.senders)[valid].tolist(),
+                        np.asarray(first.receivers)[valid].tolist()))
+        m0 = len(edges)
+        for t, d in enumerate(deltas):
+            live = np.asarray(d.mask) > 0
+            pairs = list(zip(np.asarray(d.senders)[live].tolist(),
+                             np.asarray(d.receivers)[live].tolist()))
+            dw = np.asarray(d.dw)[live]
+            w_old = np.asarray(d.w_old)[live]
+            assert len(set(pairs)) == len(pairs)
+            for pair, step, old in zip(pairs, dw, w_old):
+                assert old == float(pair in edges)
+                assert step == (-1.0 if old else 1.0)
+                (edges.discard if old else edges.add)(pair)
+            born = [p for p, step in zip(pairs, dw) if step > 0]
+            hub = np.bincount(np.asarray(born).ravel(), minlength=n).max()
+            if t == attack_at:
+                assert hub >= int(frac * n) - 3  # some fan-in pre-exists
+            else:
+                assert hub < int(frac * n) // 2
+        assert abs(len(edges) - m0) <= int(frac * n)
+
+    def test_edge_sequence_planted_attack_scores_highest(self):
+        first, deltas, attack_at = dos_attack_edge_sequence(
+            n=600, n_graphs=7, seed=4)
+        st = finger_state(first)
+        scores = []
+        for d in deltas:
+            dist, st = jsdist_incremental(st, d)
+            scores.append(float(dist))
+        assert int(np.argmax(scores)) == attack_at
 
 
 class TestBifurcationDetection:
