@@ -19,7 +19,6 @@ from benchmarks import (
     kernels_bench,
     kernels_interpret,
     roofline,
-    streams_bench,
     table2_wiki,
     table3_dos,
 )
@@ -36,21 +35,10 @@ SUITES = {
     # drift without a TPU; a mismatch fails the harness.
     "kernels-interpret": kernels_interpret.run,
     "roofline": roofline.run,
-    # Serving-path suite; also writes the machine-readable
-    # BENCH_streams.json tracked across PRs.
-    "streams": streams_bench.run,
     # Static-analysis gate (lint / HLO audit / VMEM / compile-budget
     # sentinel); any unsuppressed violation fails the harness. Same
     # checks as `python -m repro.analysis`.
     "analysis": analysis_gate.run,
-}
-
-# Suites that publish a machine-readable artifact get it schema-checked
-# after the run: a malformed JSON fails the harness instead of silently
-# corrupting the cross-PR perf trajectory.
-ARTIFACT_VALIDATORS = {
-    "streams": lambda: streams_bench.validate_report_file(
-        streams_bench.DEFAULT_JSON),
 }
 
 
@@ -66,10 +54,6 @@ def main() -> None:
         t0 = time.time()
         try:
             SUITES[name]()
-            validator = ARTIFACT_VALIDATORS.get(name)
-            if validator is not None:
-                validator()
-                print(f"# {name}: artifact schema OK", file=sys.stderr)
         except Exception:
             traceback.print_exc()
             failed.append(name)

@@ -256,10 +256,17 @@ class StreamEngine:
                                          exact_smax=exact_smax)
         else:
             tick_body = self._vstep
+
+        def scoped_tick_body(states, deltas: GraphDelta):
+            # The tick's device operations carry this name in the
+            # profiler's trace, whatever the jitted function is called.
+            with jax.named_scope("finger.tick"):
+                return tick_body(states, deltas)
+
         # The one batched-tick computation every entry point executes:
         # `tick` jits it, `run` scans it, and the serving plans wrap it
         # in shard_map (each shard runs it on its resident streams).
-        self._tick_body = tick_body
+        self._tick_body = scoped_tick_body
         # Donate the stacked state: the engine owns it and a serving tick
         # should update the (B, n) strengths in place, not copy them.
         self._tick = jax.jit(self._tick_body, donate_argnums=(0,))

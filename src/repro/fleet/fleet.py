@@ -12,16 +12,26 @@ tenant manifest).
 Queries never gather full score vectors: per-tenant `scores` read one
 slot each through the jitted dynamic index, and `top_anomalies` merges
 per-shard top-k *candidate rows* only.
+
+The serving loop writes profiler spans (`jax.profiler.TraceAnnotation`,
+free when no profiler runs), all named ``finger.*``: ``finger.ingest``
+(counters ``step``, ``lanes``) split into ``finger.route`` and
+``finger.wal`` beside each shard's ``finger.shard_ingest``;
+``finger.poll`` (``step``, ``launches``) around each
+``finger.dispatch`` and the periodic ``finger.save``;
+``finger.scores`` and ``finger.top_anomalies`` (``step``) around every
+blocking device-to-host read, ``finger.d2h``. The fleet-level spans of
+one tick carry the same ``step``.
 """
 from __future__ import annotations
 
 import json
 import os
 import tempfile
-import time
 from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.sparse import SparseCapacityError, sparse_state_from_graph
 from repro.core.state import FingerState, host_finger_state
@@ -63,7 +73,6 @@ class FingerFleet:
         self._pool_scores_dev: Dict[int, list] = {}
         self._pool_scores_host: Dict[int, Dict[int, np.ndarray]] = {}
         self._last_poll_launches = 0
-        self._last_save_pause_s = 0.0
 
     # -- construction -----------------------------------------------------
     @staticmethod
@@ -248,6 +257,34 @@ class FingerFleet:
         WAL-only — they replay at `recover`."""
         self._check_open("ingest")
         self._require_unstaged("ingest")
+        step_next = self._step + 1
+        with TraceAnnotation(
+                "finger.ingest", step=step_next,
+                lanes=sum(d.lane_count() for d in deltas.values())):
+            with TraceAnnotation("finger.route"):
+                stages, sparse_slots, wal_pending = self._route(deltas)
+            with TraceAnnotation("finger.wal"):
+                self._append_wal(wal_pending, step_next)
+            for pool_i, shard_i in self.live_shard_ids():
+                pool = self._config.pools[pool_i]
+                svc = self.shard_service(pool_i, shard_i)
+                key = (pool_i, shard_i)
+                if pool.method == "sparse_tick":
+                    slots = sparse_slots.get(key, {})
+                    empty = self._router.empty_delta(pool, svc)
+                    svc.ingest([slots.get(s, empty)
+                                for s in range(pool.streams_per_shard)])
+                else:
+                    stage = stages.get(key)
+                    if stage is None:  # no tenant delta: all-zero rows
+                        stage = self._router.stage_for(key, pool)
+                    svc.ingest(stage.finish(svc))
+        self._staged = True
+
+    def _route(self, deltas: Dict[str, GraphDelta]):
+        """The capacity pre-pass (warm repad / promotion), then every
+        delta translated for its shard: ``(stages, sparse_slots,
+        wal_pending)``."""
         for name in deltas:
             self._directory.get(name)  # fail fast, by name
         for name, d in deltas.items():
@@ -255,7 +292,6 @@ class FingerFleet:
             if self._is_dead(entry.pool, entry.shard):
                 continue
             self._rebalancer.ensure_capacity(name, d)
-        step_next = self._step + 1
         # Translation: dense tenants stage numpy-vectorized rows
         # straight into their shard's preallocated (B, k_pad) buffers
         # (one stacked GraphDelta per shard, no per-tenant allocation);
@@ -281,9 +317,14 @@ class FingerFleet:
                     stage = self._router.stage_for(key, pool)
                     stages[key] = stage
                 self._router.stage_dense(entry, d, svc, pool, stage)
-        # WAL: one buffered commit per tick, after every translation
-        # succeeded — a rejected tick leaves no partial WAL — with the
-        # retention policy applied as part of the same pass.
+        return stages, sparse_slots, wal_pending
+
+    def _append_wal(self, wal_pending: List[Tuple[TenantEntry,
+                                                   GraphDelta]],
+                    step_next: int) -> None:
+        """WAL: one buffered commit per tick, after every translation
+        succeeded — a rejected tick leaves no partial WAL — with the
+        retention policy applied as part of the same pass."""
         retention = self._config.wal_retention_ticks
         for entry, d in wal_pending:
             entry.wal.append((step_next, d))
@@ -295,21 +336,6 @@ class FingerFleet:
                     entry.wal = [w for w in entry.wal
                                  if w[0] > cutoff]
                     entry.wal_floor = max(entry.wal_floor, pruned_to)
-        for pool_i, shard_i in self.live_shard_ids():
-            pool = self._config.pools[pool_i]
-            svc = self.shard_service(pool_i, shard_i)
-            key = (pool_i, shard_i)
-            if pool.method == "sparse_tick":
-                slots = sparse_slots.get(key, {})
-                empty = self._router.empty_delta(pool, svc)
-                svc.ingest([slots.get(s, empty)
-                            for s in range(pool.streams_per_shard)])
-            else:
-                stage = stages.get(key)
-                if stage is None:  # no tenant delta: all-zero rows
-                    stage = self._router.stage_for(key, pool)
-                svc.ingest(stage.finish(svc))
-        self._staged = True
 
     def poll(self) -> int:
         """Advance the whole fleet one tick (all live shards — shard
@@ -325,10 +351,16 @@ class FingerFleet:
         falls back to sequential per-shard `poll()` for that group
         only. A due periodic save runs AFTER every pool's tick has
         been dispatched — the checkpoint never serializes ahead of
-        device work — and its pause is recorded in
-        `last_save_pause_s` instead of silently inflating the tick.
+        device work — in its own ``finger.save`` span.
         """
         self._check_open("poll")
+        with TraceAnnotation("finger.poll", step=self._step + 1) as span:
+            launches = self._poll()
+            span.set_metadata(launches=launches)
+        return self._step
+
+    def _poll(self) -> int:
+        """`poll`'s body; returns the launches it dispatched."""
         if not self._staged:
             self.ingest({})
         self._pool_scores_dev = {}
@@ -372,13 +404,11 @@ class FingerFleet:
         self._step += 1
         self._staged = False
         self._last_poll_launches = launches
-        self._last_save_pause_s = 0.0
         every = self._config.save_every_ticks
         if every is not None and self._step % every == 0:
-            t0 = time.perf_counter()
-            self.save()
-            self._last_save_pause_s = time.perf_counter() - t0
-        return self._step
+            with TraceAnnotation("finger.save"):
+                self.save()
+        return launches
 
     @property
     def last_poll_launches(self) -> int:
@@ -386,12 +416,6 @@ class FingerFleet:
         pool layout-group when stacked, one per shard sequentially
         (the sentinel's dispatch-budget probe)."""
         return self._last_poll_launches
-
-    @property
-    def last_save_pause_s(self) -> float:
-        """Wall-clock seconds the latest `poll()` spent in its
-        periodic whole-fleet save (0.0 when none was due)."""
-        return self._last_save_pause_s
 
     # -- queries ----------------------------------------------------------
     def _host_score_row(self, pool_i: int,
@@ -409,7 +433,8 @@ class FingerFleet:
                 return None
             rows = {}
             for shard_ids, mat in planes:
-                host = np.asarray(mat)  # the pool's one transfer
+                with TraceAnnotation("finger.d2h"):
+                    host = np.asarray(mat)  # the pool's one transfer
                 for j, s in enumerate(shard_ids):
                     rows[s] = host[j]
             self._pool_scores_host[pool_i] = rows
@@ -424,26 +449,27 @@ class FingerFleet:
         Tenants stranded on a dead shard — or (re)installed since the
         shard last ticked — report their last known score."""
         self._check_open("scores")
-        out: Dict[str, float] = {}
-        for name in (self._directory.names() if names is None
-                     else names):
-            entry = self._directory.get(name)
-            if (self._is_dead(entry.pool, entry.shard)
-                    or entry.installed_step >= self._step):
-                # dead shard, or row (re)installed since the shard
-                # last ticked: the slot's device score is stale
+        with TraceAnnotation("finger.scores", step=self._step):
+            out: Dict[str, float] = {}
+            for name in (self._directory.names() if names is None
+                         else names):
+                entry = self._directory.get(name)
+                if (self._is_dead(entry.pool, entry.shard)
+                        or entry.installed_step >= self._step):
+                    # dead shard, or row (re)installed since the shard
+                    # last ticked: the slot's device score is stale
+                    out[name] = entry.last_score
+                    continue
+                row = self._host_score_row(entry.pool, entry.shard)
+                if row is not None:
+                    entry.last_score = float(row[entry.slot])
+                else:
+                    svc = self.shard_service(entry.pool, entry.shard)
+                    v = svc.score_at(entry.slot)
+                    if v is not None:
+                        entry.last_score = float(v)
                 out[name] = entry.last_score
-                continue
-            row = self._host_score_row(entry.pool, entry.shard)
-            if row is not None:
-                entry.last_score = float(row[entry.slot])
-            else:
-                svc = self.shard_service(entry.pool, entry.shard)
-                v = svc.score_at(entry.slot)
-                if v is not None:
-                    entry.last_score = float(v)
-            out[name] = entry.last_score
-        return out
+            return out
 
     def top_anomalies(self, k: int = 8) -> List[Tuple[str, float]]:
         """The k highest-scoring tenants of the latest tick: per-shard
@@ -453,29 +479,30 @@ class FingerFleet:
         (free); others run the device-side `top_anomalies` query —
         full score vectors never leave their shard either way."""
         self._check_open("top_anomalies")
-        cands: List[Tuple[float, str]] = []
-        for pool_i, shard_i in self.live_shard_ids():
-            pool = self._config.pools[pool_i]
-            kk = min(k, pool.streams_per_shard)
-            row = self._host_score_row(pool_i, shard_i)
-            if row is not None:
-                # Stable sort on the negated row matches lax.top_k's
-                # tie-breaking (lowest slot wins among equal scores).
-                slots = np.argsort(-row, kind="stable")[:kk]
-                vals = row[slots]
-            else:
-                svc = self.shard_service(pool_i, shard_i)
-                try:
-                    vals, slots = svc.top_anomalies(k=kk)
-                except ServiceLifecycleError:
-                    continue  # shard has not ticked yet
-            for v, s in zip(np.ravel(vals), np.ravel(slots)):
-                entry = self._directory.tenant_at(pool_i, shard_i,
-                                                  int(s))
-                if entry is not None:
-                    cands.append((float(v), entry.name))
-        cands.sort(key=lambda t: -t[0])
-        return [(name, v) for v, name in cands[:k]]
+        with TraceAnnotation("finger.top_anomalies", step=self._step):
+            cands: List[Tuple[float, str]] = []
+            for pool_i, shard_i in self.live_shard_ids():
+                pool = self._config.pools[pool_i]
+                kk = min(k, pool.streams_per_shard)
+                row = self._host_score_row(pool_i, shard_i)
+                if row is not None:
+                    # Stable sort on the negated row matches lax.top_k's
+                    # tie-breaking (lowest slot wins among equal scores).
+                    slots = np.argsort(-row, kind="stable")[:kk]
+                    vals = row[slots]
+                else:
+                    svc = self.shard_service(pool_i, shard_i)
+                    try:
+                        vals, slots = svc.top_anomalies(k=kk)
+                    except ServiceLifecycleError:
+                        continue  # shard has not ticked yet
+                for v, s in zip(np.ravel(vals), np.ravel(slots)):
+                    entry = self._directory.tenant_at(pool_i, shard_i,
+                                                      int(s))
+                    if entry is not None:
+                        cands.append((float(v), entry.name))
+            cands.sort(key=lambda t: -t[0])
+            return [(name, v) for v, name in cands[:k]]
 
     # -- rebalancing ------------------------------------------------------
     def promote(self, name: str,

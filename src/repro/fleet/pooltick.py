@@ -50,6 +50,7 @@ from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.engine.stream import StreamEngine
 from repro.fleet.errors import PoolGroupError
@@ -139,17 +140,20 @@ def pool_tick_fn(exact_smax: bool, method: str):
         body = jax.vmap(engine._tick_body)
 
     def run(states_seq, deltas_seq):
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *states_seq)
-        sdeltas = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *deltas_seq)
-        dists, new_states = body(stacked, sdeltas)
-        s = len(states_seq)
-        rows = tuple(dists[i] for i in range(s))
-        shard_states = tuple(
-            jax.tree_util.tree_map(lambda x, _i=i: x[_i], new_states)
-            for i in range(s))
-        return dists, rows, shard_states
+        # The device operations of the pool tick carry this name in the
+        # profiler's trace, whatever the jitted function is called.
+        with jax.named_scope("finger.tick"):
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *states_seq)
+            sdeltas = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *deltas_seq)
+            dists, new_states = body(stacked, sdeltas)
+            s = len(states_seq)
+            rows = tuple(dists[i] for i in range(s))
+            shard_states = tuple(
+                jax.tree_util.tree_map(lambda x, _i=i: x[_i], new_states)
+                for i in range(s))
+            return dists, rows, shard_states
 
     return jax.jit(run, donate_argnums=(0,))
 
@@ -170,7 +174,8 @@ def tick_pool(services: Sequence) -> jax.Array:
     fn = pool_tick_fn(first.exact_smax, first.method)
     states = tuple(svc.states() for svc in svcs)
     deltas = tuple(svc.begin_pool_tick() for svc in svcs)
-    dists, rows, shard_states = fn(states, deltas)
+    with TraceAnnotation("finger.dispatch"):
+        dists, rows, shard_states = fn(states, deltas)
     for svc, row, st in zip(svcs, rows, shard_states):
         svc.finish_pool_tick(row, st)
     return dists

@@ -391,6 +391,11 @@ class GraphDelta:
     def has_node_slots(self) -> bool:
         return self.node_ids is not None
 
+    def lane_count(self) -> int:
+        """Edge lanes the mask keeps, counted on the host (a device
+        mask is read back)."""
+        return int(np.count_nonzero(np.asarray(self.mask)))
+
     def scaled(self, factor: float) -> "GraphDelta":
         """ΔG/2 for Algorithm 2 (the averaged graph G ⊕ ΔG/2).
 

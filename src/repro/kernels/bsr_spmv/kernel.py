@@ -50,5 +50,6 @@ def bsr_matvec_pallas(values, col_ids, x, interpret: bool = False):
         out_specs=pl.BlockSpec((1, b, 1), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rb, b, 1), jnp.float32),
         interpret=interpret,
+        name="bsr_matvec",
     )(col_ids, x2, values)
     return y.reshape(n)

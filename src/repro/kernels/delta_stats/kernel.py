@@ -114,5 +114,6 @@ def delta_stats_sorted_pallas(
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, SCALAR_LANES), jnp.float32),
         interpret=interpret,
+        name="delta_stats",
     )(sorted_nodes, sorted_vals, sorted_strengths, endpoint_valid,
       dw, w_old, mask)

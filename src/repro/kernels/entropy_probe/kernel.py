@@ -97,6 +97,7 @@ def attention_graph_stats_pallas(
             jax.ShapeDtypeStruct((bh, s), jnp.float32),
         ],
         interpret=interpret,
+        name="entropy_probe_row_stats",
     )(logits)
 
     scal, colsum, diag = pl.pallas_call(
@@ -121,5 +122,6 @@ def attention_graph_stats_pallas(
             jax.ShapeDtypeStruct((bh, s), jnp.float32),
         ],
         interpret=interpret,
+        name="entropy_probe_graph_stats",
     )(logits, logits, rowmax, denom, rowmax, denom)
     return scal, colsum, diag

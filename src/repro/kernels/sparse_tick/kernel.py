@@ -244,6 +244,7 @@ def sparse_tick_pallas(
         out_shape=tuple(jax.ShapeDtypeStruct((b, 1, w), jnp.float32)
                         for w in widths_out),
         interpret=interpret,
+        name="sparse_tick",
     )(scalars, strengths, node_mask, edge_weights,
       ep_ids, ep_dw, ep_wold, ep_mask, eslot, nid, nflag)
 
@@ -297,5 +298,6 @@ def sparse_tick_pallas_stacked(
         out_shape=tuple(jax.ShapeDtypeStruct((s, b, 1, w), jnp.float32)
                         for w in widths_out),
         interpret=interpret,
+        name="sparse_tick_stacked",
     )(scalars, strengths, node_mask, edge_weights,
       ep_ids, ep_dw, ep_wold, ep_mask, eslot, nid, nflag)

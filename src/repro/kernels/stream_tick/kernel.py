@@ -248,6 +248,7 @@ def stream_tick_pallas(
         out_shape=tuple(jax.ShapeDtypeStruct((b, 1, w), jnp.float32)
                         for w in widths_out),
         interpret=interpret,
+        name="stream_tick",
     )(scalars, strengths, node_mask,
       ep_ids, ep_dw, ep_wold, ep_mask, nid, nflag)
 
@@ -296,5 +297,6 @@ def stream_tick_pallas_stacked(
         out_shape=tuple(jax.ShapeDtypeStruct((s, b, 1, w), jnp.float32)
                         for w in widths_out),
         interpret=interpret,
+        name="stream_tick_stacked",
     )(scalars, strengths, node_mask,
       ep_ids, ep_dw, ep_wold, ep_mask, nid, nflag)

@@ -74,4 +74,5 @@ def vnge_q_stats_pallas(
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="vnge_q_stats",
     )(w)

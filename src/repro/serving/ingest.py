@@ -46,12 +46,14 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence, Union
 
 import numpy as np
 
 import jax
+from jax.profiler import TraceAnnotation
 
+from repro.engine.stream import stack_deltas
 from repro.graphs.types import GraphDelta
 from repro.serving.config import ServiceConfig
 from repro.serving.plans import ExecutionPlan
@@ -218,13 +220,21 @@ class SyncIngestor:
         """What `put` enqueues — the host delta (transfer deferred)."""
         return deltas
 
-    def put(self, deltas: GraphDelta) -> None:
-        deltas = self._maybe_remap(deltas)
-        validate_stacked_delta(self.config, deltas)
-        if len(self._queue) >= self.config.max_queue:
-            raise IngestError(
-                f"ingestion queue full ({self.config.max_queue} "
-                f"pending tick(s)); poll() before ingesting more")
+    def put(self, deltas: Union[GraphDelta, Sequence[GraphDelta]]
+            ) -> None:
+        """Queue one tick: a stacked (B, k_pad) delta, or the B
+        per-stream deltas to stack. Stacking, remap, validation and the
+        queue check run in a ``finger.stack`` span; the transfer
+        (`ExecutionPlan.put_deltas`) is outside it."""
+        with TraceAnnotation("finger.stack"):
+            if not isinstance(deltas, GraphDelta):
+                deltas = stack_deltas(list(deltas))
+            deltas = self._maybe_remap(deltas)
+            validate_stacked_delta(self.config, deltas)
+            if len(self._queue) >= self.config.max_queue:
+                raise IngestError(
+                    f"ingestion queue full ({self.config.max_queue} "
+                    f"pending tick(s)); poll() before ingesting more")
         self._queue.append(self._prepare(deltas))
 
     def take_all(self) -> list:

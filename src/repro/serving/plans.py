@@ -41,6 +41,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -183,13 +184,17 @@ class ExecutionPlan:
 
         Returns immediately with the transfer in flight (jax transfers
         are asynchronous) — the double-buffered ingestor leans on this
-        to overlap tick T+1's transfer with tick T's compute.
+        to overlap tick T+1's transfer with tick T's compute. Every
+        tick's transfer passes here, in a ``finger.h2d`` span counting
+        the delta's ``bytes``.
         """
-        if self.mesh is None:
-            return jax.device_put(deltas)
-        sharding = NamedSharding(self.mesh, self._spec())
-        return jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, sharding), deltas)
+        nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(deltas))
+        with TraceAnnotation("finger.h2d", bytes=nbytes):
+            if self.mesh is None:
+                return jax.device_put(deltas)
+            sharding = NamedSharding(self.mesh, self._spec())
+            return jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, sharding), deltas)
 
     # -- the tick --------------------------------------------------------
     def tick(self, states: FingerState,

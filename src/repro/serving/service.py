@@ -47,21 +47,19 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-from typing import Callable, Dict, Optional, Sequence, Tuple, Union
+from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.experimental.compilation_cache import compilation_cache
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.core.state import FingerState
-from repro.engine.stream import (
-    StreamEngine,
-    restore_stacked_state,
-    stack_deltas,
-)
+from repro.engine.stream import StreamEngine, restore_stacked_state
 from repro.graphs.layout import (
     NodeLayout,
     compose_index_maps,
@@ -477,18 +475,18 @@ class FingerService:
         """Queue one tick's deltas (a stacked (B, k_pad) GraphDelta, or
         a list of B per-stream deltas to stack). Under double-buffered
         ingestion the host→device transfer starts here, overlapping the
-        in-flight tick's compute."""
+        in-flight tick's compute. Profiler span ``finger.shard_ingest``."""
         self._check_open("ingest")
-        if self._config.method == "sparse_tick":
-            self._ingestor.put(self._translate_sparse(deltas))
-            return
-        if not isinstance(deltas, GraphDelta):
-            deltas = stack_deltas(list(deltas))
-        self._ingestor.put(deltas)
+        with TraceAnnotation("finger.shard_ingest"):
+            if self._config.method == "sparse_tick":
+                deltas = self._translate_sparse(deltas)
+            self._ingestor.put(deltas)
 
-    def _translate_sparse(self, deltas) -> GraphDelta:
-        """One tick's B per-stream *virtual* deltas → the stacked
-        slot-space delta, through the per-stream `SlotMap`s.
+    def _translate_sparse(self, deltas) -> List[GraphDelta]:
+        """One tick's B per-stream *virtual* deltas → their slot-space
+        deltas, through the per-stream `SlotMap`s, in a ``finger.slotmap``
+        span counting the valid lanes in (``lanes``) and the lanes
+        translated (``kept``).
 
         Atomic over the batch: every stream is staged (pure) before any
         map commits, so a rejection — out-of-capacity
@@ -516,10 +514,14 @@ class FingerService:
             raise IngestError(
                 f"ingestion queue full ({self._config.max_queue} "
                 f"pending tick(s)); poll() before ingesting more")
-        staged = [sm.stage(d)
-                  for sm, d in zip(self._slot_maps, deltas)]
-        return stack_deltas([sm.commit(st)
-                             for sm, st in zip(self._slot_maps, staged)])
+        with TraceAnnotation("finger.slotmap") as span:
+            staged = [sm.stage(d)
+                      for sm, d in zip(self._slot_maps, deltas)]
+            out = [sm.commit(st)
+                   for sm, st in zip(self._slot_maps, staged)]
+            span.set_metadata(lanes=sum(d.lane_count() for d in deltas),
+                              kept=sum(d.lane_count() for d in out))
+        return out
 
     def poll(self) -> Optional[TickReport]:
         """Advance one tick if a delta is queued; None otherwise.
@@ -532,7 +534,8 @@ class FingerService:
         deltas = self._ingestor.get()
         if deltas is None:
             return None
-        dists, self._states = self._plan.tick(self._states, deltas)
+        with TraceAnnotation("finger.dispatch"):
+            dists, self._states = self._plan.tick(self._states, deltas)
         self._last_scores = dists
         self._step += 1
         every = self._config.checkpoint.every_ticks
@@ -584,7 +587,8 @@ class FingerService:
         self._check_open("scores")
         if self._last_scores is None:
             return None
-        return np.asarray(self._last_scores)
+        with TraceAnnotation("finger.d2h"):
+            return np.asarray(self._last_scores)
 
     def top_anomalies(self, k: Optional[int] = None,
                       per_pod: bool = False
@@ -609,7 +613,11 @@ class FingerService:
             vals, ids = self._plan.pod_topk(self._last_scores, k)
         else:
             vals, ids = self._plan.topk(self._last_scores, k)
-        return np.asarray(vals), np.asarray(ids)
+        with TraceAnnotation("finger.d2h"):
+            vals = np.asarray(vals)
+        with TraceAnnotation("finger.d2h"):
+            ids = np.asarray(ids)
+        return vals, ids
 
     def score_at(self, slot: int) -> Optional[float]:
         """The latest tick's score of one stream slot, read through a
@@ -619,8 +627,9 @@ class FingerService:
         self._require_slot(slot, "score_at")
         if self._last_scores is None:
             return None
-        return float(np.asarray(
-            _score_at_jit(self._last_scores, np.int32(slot))))
+        score = _score_at_jit(self._last_scores, np.int32(slot))
+        with TraceAnnotation("finger.d2h"):
+            return float(np.asarray(score))
 
     # -- stream-slot hooks (the fleet's shard-facing surface) ------------
     def _require_slot(self, slot: int, what: str) -> None:

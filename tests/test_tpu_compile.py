@@ -111,6 +111,15 @@ def _lower(name, sharding):
         interpret=False)
 
 
+KERNEL_NAMES = {
+    "stream_tick_pallas": "stream_tick",
+    "stream_tick_pallas_stacked": "stream_tick_stacked",
+    "sparse_tick_pallas": "sparse_tick",
+    "sparse_tick_pallas_stacked": "sparse_tick_stacked",
+    "delta_stats_sorted_pallas": "delta_stats",
+}
+
+
 @pytest.mark.parametrize("name", [
     "stream_tick_pallas",
     "stream_tick_pallas_stacked",
@@ -119,5 +128,8 @@ def _lower(name, sharding):
     "delta_stats_sorted_pallas",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name):
-    compiled = _lower(name, one_chip).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    text = _lower(name, one_chip).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # The kernel's instruction carries its pallas_call's stable name,
+    # which a reader of the device trace looks for.
+    assert f"%{KERNEL_NAMES[name]}." in text
