@@ -271,7 +271,9 @@ class FingerFleet:
                 key = (pool_i, shard_i)
                 if pool.method == "sparse_tick":
                     slots = sparse_slots.get(key, {})
-                    empty = self._router.empty_delta(pool, svc)
+                    empty = None
+                    if len(slots) < pool.streams_per_shard:
+                        empty = self._router.empty_delta(pool)
                     svc.ingest([slots.get(s, empty)
                                 for s in range(pool.streams_per_shard)])
                 else:
@@ -309,7 +311,7 @@ class FingerFleet:
             pool = self._config.pools[entry.pool]
             key = (entry.pool, entry.shard)
             if pool.method == "sparse_tick":
-                t = self._router.translate(entry, d, svc, pool)
+                t = self._router.translate(entry, d, pool)
                 sparse_slots.setdefault(key, {})[entry.slot] = t
             else:
                 stage = stages.get(key)

@@ -1,19 +1,22 @@
 """Tenant routing: bucket admission and per-tenant delta translation.
 
-The router owns two pure-host jobs:
+The router owns three pure-host jobs:
 
 - `place`: best-fit admission — the smallest bucket (pool) whose
   ``n_pad`` covers the tenant's node space and still has a free stream
   slot on a live shard, spilling upward through the bucket ladder;
   `AdmissionError` by name when nothing fits.
-- `translate`: one tenant's *tenant-space* `GraphDelta` (node ids in
-  the tenant's private zero-based space) → the *shard-space* delta its
-  stream row ticks with — virtual ids mapped through the tenant's
+- `stage_dense`: one dense-pool tenant's *tenant-space* `GraphDelta`
+  (node ids in the tenant's private zero-based space) → its row of the
+  shard's stacked delta — virtual ids mapped through the tenant's
   ``slot_of_node`` position map (joins allocate fresh positions), lanes
   re-padded to the pool's static ``k_pad``/``j_pad``, and the result
   stamped with the shard's live `NodeLayout` generation so a migration
   racing an in-flight tick is remapped by the serving grace machinery
   instead of scattering into stale slots.
+- `translate`: one sparse-pool tenant's delta re-padded to the pool's
+  static sizes, on the host; the shard's own `SlotMap`s map its
+  virtual ids to slots.
 
 Positions are per-stream: each stream row has its own (n_pad,) state,
 so two tenants on one shard both use low positions — only the shared
@@ -87,8 +90,8 @@ class ShardStage:
 
     def finish(self, svc) -> GraphDelta:
         """The tick's stacked (B, k_pad) shard-space GraphDelta, stamped
-        with the shard's live layout generation (same grace-machinery
-        contract as the per-tenant `translate` path)."""
+        with the shard's live layout generation (the serving grace
+        machinery remaps it if the shard migrates before the tick)."""
         return GraphDelta(
             senders=self.senders.copy(),
             receivers=self.receivers.copy(),
@@ -181,10 +184,13 @@ class FleetRouter:
         return placed + new
 
     def translate(self, entry: TenantEntry, delta: GraphDelta,
-                  svc, pool: PoolSpec) -> GraphDelta:
-        """Tenant-space delta → shard-space delta for ``entry``'s
-        stream (see module docstring). Mutates the entry's
-        ``slot_of_node`` (join placement) — call once per delta."""
+                  pool: PoolSpec) -> GraphDelta:
+        """A sparse-pool tenant's virtual-id delta re-padded to the
+        pool's static sizes, with host (numpy) leaves: sparse shards
+        translate virtual ids themselves (per-stream `SlotMap`s inside
+        the service, host code), and the stacked tick goes to the
+        device once after them. Dense pools stage through
+        `stage_dense`."""
         join, leave = self._split_node_slots(delta)
         if (join.size or leave.size) and pool.j_pad is None:
             raise FleetIngestError(
@@ -192,17 +198,6 @@ class FleetRouter:
                 f"join/leave slots but pool {pool.name!r} has "
                 "j_pad=None (no node lanes); use a pool with join "
                 "slots")
-        if pool.method == "sparse_tick":
-            return self._translate_sparse(entry, delta, join, leave,
-                                          pool)
-        return self._translate_dense(entry, delta, join, leave, svc,
-                                     pool)
-
-    def _translate_sparse(self, entry, delta, join, leave,
-                          pool: PoolSpec) -> GraphDelta:
-        """Sparse shards translate virtual ids themselves (per-stream
-        `SlotMap`s inside the service); the fleet only re-pads the
-        lanes to the pool's static sizes."""
         m = np.asarray(delta.mask) > 0
         if delta.n_nodes > pool.n_pad:
             raise FleetIngestError(
@@ -210,71 +205,13 @@ class FleetRouter:
                 f"{delta.n_nodes} virtual node(s), beyond pool "
                 f"{pool.name!r}'s virtual bound n_pad={pool.n_pad}")
         try:
-            return GraphDelta.from_arrays(
+            return GraphDelta.host_from_arrays(
                 np.asarray(delta.senders)[m],
                 np.asarray(delta.receivers)[m],
                 np.asarray(delta.dw)[m], np.asarray(delta.w_old)[m],
                 n_nodes=delta.n_nodes, n_pad=pool.n_pad,
                 k_pad=pool.k_pad, j_pad=pool.j_pad,
                 join=join, leave=leave)
-        except ValueError as e:
-            raise FleetIngestError(
-                f"tenant {entry.name!r}: {e}") from e
-
-    def _translate_dense(self, entry, delta, join, leave, svc,
-                         pool: PoolSpec) -> GraphDelta:
-        som = entry.slot_of_node
-        if delta.n_nodes > som.shape[0]:
-            som = np.concatenate([
-                som, np.full((delta.n_nodes - som.shape[0],), -1,
-                             np.int32)])
-            entry.slot_of_node = som
-            entry.n_nodes = int(delta.n_nodes)
-        n_pad = svc.layout.n_pad
-        # First-time joins take the smallest positions this tenant
-        # does not already hold (per-stream free set).
-        new = [v for v in join.tolist() if som[v] < 0]
-        if new:
-            used = set(som[som >= 0].tolist())
-            pos = 0
-            for v in new:
-                while pos in used:
-                    pos += 1
-                if pos >= n_pad:
-                    # ensure_capacity should have repadded/promoted
-                    # first; reaching here means the caller skipped it.
-                    raise FleetIngestError(
-                        f"tenant {entry.name!r}: join of node {v} "
-                        f"overflows the shard layout n_pad={n_pad}; "
-                        "the rebalancer must repad or promote first")
-                som[v] = pos
-                used.add(pos)
-        m = np.asarray(delta.mask) > 0
-        snd = som[np.asarray(delta.senders, np.int64)[m]]
-        rcv = som[np.asarray(delta.receivers, np.int64)[m]]
-        if (snd < 0).any() or (rcv < 0).any():
-            bad = sorted(set(
-                np.asarray(delta.senders)[m][snd < 0].tolist()
-                + np.asarray(delta.receivers)[m][rcv < 0].tolist()))
-            raise FleetIngestError(
-                f"tenant {entry.name!r}: delta edge(s) touch node(s) "
-                f"{bad} the tenant never joined")
-        leave_pos = som[leave.astype(np.int64)] if leave.size \
-            else np.zeros((0,), np.int32)
-        if leave.size and (leave_pos < 0).any():
-            bad = sorted(leave[leave_pos < 0].tolist())
-            raise FleetIngestError(
-                f"tenant {entry.name!r}: leave of never-joined "
-                f"node(s) {bad}")
-        try:
-            return GraphDelta.from_arrays(
-                snd, rcv, np.asarray(delta.dw)[m],
-                np.asarray(delta.w_old)[m],
-                n_nodes=n_pad, k_pad=pool.k_pad, j_pad=pool.j_pad,
-                join=som[join.astype(np.int64)] if join.size
-                else np.zeros((0,), np.int32),
-                leave=leave_pos,
-                layout=svc.layout)
         except ValueError as e:
             raise FleetIngestError(
                 f"tenant {entry.name!r}: {e}") from e
@@ -297,11 +234,10 @@ class FleetRouter:
 
     def stage_dense(self, entry: TenantEntry, delta: GraphDelta,
                     svc, pool: PoolSpec, stage: ShardStage) -> None:
-        """`_translate_dense`, vectorized into the staging buffers: the
-        same tenant→slot position math and the same named rejections,
-        but the result lands directly in ``stage``'s row
-        ``entry.slot`` instead of allocating a per-tenant `GraphDelta`.
-        Mutates ``entry.slot_of_node`` (join placement) — call once per
+        """One dense-pool tenant's delta into ``stage``'s row
+        ``entry.slot`` (see module docstring), numpy-vectorized with
+        named rejections (`FleetIngestError`). Mutates
+        ``entry.slot_of_node`` (join placement) — call once per
         (tenant, tick)."""
         join, leave = self._split_node_slots(delta)
         if (join.size or leave.size) and pool.j_pad is None:
@@ -370,15 +306,12 @@ class FleetRouter:
             else np.zeros((0,), np.int32),
             leave_pos.astype(np.int32))
 
-    def empty_delta(self, pool: PoolSpec, svc) -> GraphDelta:
-        """The free-slot no-op delta of one shard tick (stamped with
-        the shard's live layout for dense pools, so it stacks with
-        translated tenant deltas)."""
+    @staticmethod
+    def empty_delta(pool: PoolSpec) -> GraphDelta:
+        """The free-slot no-op delta of one sparse shard tick, with host
+        leaves like `translate`'s (dense shards' free slots are the
+        all-zero rows of their `ShardStage`)."""
         z = np.zeros((0,), np.float32)
-        if pool.method == "sparse_tick":
-            return GraphDelta.from_arrays(
-                z, z, z, z, n_nodes=0, n_pad=pool.n_pad,
-                k_pad=pool.k_pad, j_pad=pool.j_pad)
-        return GraphDelta.from_arrays(
-            z, z, z, z, n_nodes=0, k_pad=pool.k_pad,
-            j_pad=pool.j_pad, layout=svc.layout)
+        return GraphDelta.host_from_arrays(
+            z, z, z, z, n_nodes=0, n_pad=pool.n_pad, k_pad=pool.k_pad,
+            j_pad=pool.j_pad)

@@ -436,6 +436,27 @@ class GraphDelta:
                     join=(), leave=(),
                     j_pad: Optional[int] = None,
                     layout: Optional[NodeLayout] = None) -> "GraphDelta":
+        """A padded delta with device leaves: `host_from_arrays`, then
+        one `jnp.asarray` per leaf."""
+        host = GraphDelta.host_from_arrays(
+            senders, receivers, dw, w_old, n_nodes, k_pad=k_pad,
+            n_pad=n_pad, join=join, leave=leave, j_pad=j_pad,
+            layout=layout)
+        return jax.tree_util.tree_map(jnp.asarray, host)
+
+    @staticmethod
+    def host_from_arrays(senders, receivers, dw, w_old, n_nodes: int,
+                         k_pad: Optional[int] = None,
+                         n_pad: Optional[int] = None,
+                         join=(), leave=(),
+                         j_pad: Optional[int] = None,
+                         layout: Optional[NodeLayout] = None
+                         ) -> "GraphDelta":
+        """The padded delta with host (numpy) leaves, nothing put on the
+        device: self-loops dropped, lanes ordered (lo, hi) and padded to
+        ``k_pad``, joins then leaves padded to ``j_pad``, each bound
+        checked (`ValueError`). For a delta whose next reader is host
+        code, such as a sparse shard's `SlotMap`."""
         senders = np.asarray(senders, np.int32)
         receivers = np.asarray(receivers, np.int32)
         dw = np.asarray(dw, np.float32)
@@ -483,18 +504,18 @@ class GraphDelta:
                 raise ValueError(
                     f"{j} node join/leave slots exceed j_pad={j_pad}")
             jpad = j_pad - j
-            node_ids = jnp.asarray(np.concatenate(
-                [join, leave, np.zeros(jpad, np.int32)]))
-            node_flag = jnp.asarray(np.concatenate(
+            node_ids = np.concatenate(
+                [join, leave, np.zeros(jpad, np.int32)])
+            node_flag = np.concatenate(
                 [np.ones(join.size, np.float32),
                  -np.ones(leave.size, np.float32),
-                 np.zeros(jpad, np.float32)]))
+                 np.zeros(jpad, np.float32)])
         return GraphDelta(
-            senders=jnp.asarray(np.concatenate([lo, np.zeros(pad, np.int32)])),
-            receivers=jnp.asarray(np.concatenate([hi, np.zeros(pad, np.int32)])),
-            dw=jnp.asarray(np.concatenate([dw, z])),
-            w_old=jnp.asarray(np.concatenate([w_old, z])),
-            mask=jnp.asarray(np.concatenate([np.ones(k, np.float32), z])),
+            senders=np.concatenate([lo, np.zeros(pad, np.int32)]),
+            receivers=np.concatenate([hi, np.zeros(pad, np.int32)]),
+            dw=np.concatenate([dw, z]),
+            w_old=np.concatenate([w_old, z]),
+            mask=np.concatenate([np.ones(k, np.float32), z]),
             n_nodes=n_layout,
             node_ids=node_ids,
             node_flag=node_flag,

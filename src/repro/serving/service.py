@@ -82,6 +82,10 @@ from repro.train.checkpoint import save_checkpoint
 # restores into a bare StreamEngine and vice versa (the migration path).
 _CKPT_KIND = "stream_engine_state"
 
+# The leaves of a virtual delta that `SlotMap.stage` reads on the host.
+_SLOTMAP_READS = ("senders", "receivers", "dw", "w_old", "mask",
+                  "node_ids", "node_flag")
+
 
 class ServiceLifecycleError(RuntimeError):
     """An operation was called in a state that cannot honor it (closed
@@ -485,8 +489,10 @@ class FingerService:
     def _translate_sparse(self, deltas) -> List[GraphDelta]:
         """One tick's B per-stream *virtual* deltas → their slot-space
         deltas, through the per-stream `SlotMap`s, in a ``finger.slotmap``
-        span counting the valid lanes in (``lanes``) and the lanes
-        translated (``kept``).
+        span counting the valid lanes in (``lanes``), the lanes
+        translated (``kept``) and the incoming leaves that were device
+        arrays, which `SlotMap.stage` reads back (``device_reads``; the
+        fleet router hands host leaves).
 
         Atomic over the batch: every stream is staged (pure) before any
         map commits, so a rejection — out-of-capacity
@@ -519,8 +525,11 @@ class FingerService:
                       for sm, d in zip(self._slot_maps, deltas)]
             out = [sm.commit(st)
                    for sm, st in zip(self._slot_maps, staged)]
-            span.set_metadata(lanes=sum(d.lane_count() for d in deltas),
-                              kept=sum(d.lane_count() for d in out))
+            span.set_metadata(
+                lanes=sum(d.lane_count() for d in deltas),
+                kept=sum(d.lane_count() for d in out),
+                device_reads=sum(isinstance(getattr(d, f), jax.Array)
+                                 for d in deltas for f in _SLOTMAP_READS))
         return out
 
     def poll(self) -> Optional[TickReport]:

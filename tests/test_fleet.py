@@ -15,6 +15,7 @@ Acceptance anchors:
 import pathlib
 import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -32,6 +33,8 @@ from repro.fleet import (
     ShardUnavailableError,
     UnknownTenantError,
 )
+from repro.core.sparse import SlotMap
+from repro.fleet.router import FleetRouter
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.types import GraphDelta
 from repro.serving import FingerService, ServiceConfig, TopKSpec
@@ -509,6 +512,135 @@ class TestSparsePool:
             assert e.slot_of_node is not None
             for t in range(2):
                 tick(10 + t)
+        finally:
+            fleet.close()
+            oracle.close()
+
+
+class TestSparseRouterHostLeaves:
+    """A sparse pool's deltas stay on the host from the router to the
+    shard's `SlotMap`s: `translate` and `empty_delta` give numpy leaves,
+    the slot-space deltas equal those of the device-leaf re-pad the
+    router used to make, and the fleet keeps the oracle's scores
+    through adds, deletes, joins and leaves."""
+
+    N_VIRT, N_NODES, K, J = 64, 8, 4, 2
+
+    @staticmethod
+    def _assert_host(delta):
+        for leaf in jax.tree_util.tree_leaves(delta):
+            assert isinstance(leaf, np.ndarray)
+            assert not isinstance(leaf, jax.Array)
+
+    @staticmethod
+    def _device_repad(delta, pool):
+        """The router's former re-pad: device leaves."""
+        m = np.asarray(delta.mask) > 0
+        join, leave = FleetRouter._split_node_slots(delta)
+        return GraphDelta.from_arrays(
+            np.asarray(delta.senders)[m], np.asarray(delta.receivers)[m],
+            np.asarray(delta.dw)[m], np.asarray(delta.w_old)[m],
+            n_nodes=delta.n_nodes, n_pad=pool.n_pad, k_pad=pool.k_pad,
+            j_pad=pool.j_pad, join=join, leave=leave)
+
+    def _tick_delta(self, w, t, rng):
+        """Tick ``t`` of one tenant whose edge weights are ``w``
+        (updated in place): one add and one delete among the base
+        nodes, and node 8 joining with an edge (t=1), losing it (t=2)
+        and leaving as node 9 joins (t=3), which then gains one."""
+        lanes, join, leave = [], [], []
+
+        def lane(i, j, dw):
+            old = w.get((i, j), 0.0)
+            lanes.append((i, j, dw, old))
+            if old + dw > 0:
+                w[(i, j)] = old + dw
+            else:
+                del w[(i, j)]
+
+        base = [(i, j) for i in range(self.N_NODES)
+                for j in range(i + 1, self.N_NODES)]
+        absent = [p for p in base if p not in w]
+        present = [p for p in base if p in w]
+        lane(*absent[rng.integers(len(absent))],
+             float(rng.uniform(0.5, 2.0)))
+        gone = present[rng.integers(len(present))]
+        lane(*gone, -w[gone])
+        if t == 1:
+            join.append(8)
+            lane(0, 8, 1.5)
+        elif t == 2:
+            lane(0, 8, -w[(0, 8)])
+        elif t == 3:
+            leave.append(8)
+            join.append(9)
+        elif t == 4:
+            lane(1, 9, 0.75)
+        lo, hi, dw, w_old = zip(*lanes)
+        return GraphDelta.from_arrays(
+            lo, hi, dw, w_old, n_nodes=self.N_VIRT, k_pad=self.K,
+            j_pad=self.J, join=join, leave=leave)
+
+    def test_host_leaves_translate_as_device_leaves_did(self):
+        cfg = FleetConfig(pools=(
+            PoolSpec(name="slots", n_pad=self.N_VIRT, shards=1,
+                     streams_per_shard=3, k_pad=self.K, j_pad=self.J,
+                     method="sparse_tick", n_slots=12, m_pad=32),))
+        pool = cfg.pools[0]
+        names = ["u", "v"]
+        graphs = {n: _graph(self.N_NODES, i + 71)
+                  for i, n in enumerate(names)}
+        fleet = FingerFleet.open(cfg)
+        oracle = FingerService.open(
+            ServiceConfig(batch_size=2, n_pad=self.N_VIRT, k_pad=self.K,
+                          j_pad=self.J, topk=TopKSpec(k=2)),
+            [graphs[n] for n in names])
+        try:
+            for n in names:
+                fleet.admit(n, graphs[n])
+            svc = fleet.shard_service(0, 0)
+            self._assert_host(fleet.router.empty_delta(pool))
+            slots = {n: fleet.directory.get(n).slot for n in names}
+            # shadow maps: one fed the router's output, one the old
+            # device-leaf re-pad, both from the shard's admitted maps
+            host_maps, dev_maps = (
+                {n: SlotMap.from_json(svc.slot_maps[slots[n]].to_json())
+                 for n in names} for _ in range(2))
+            weights = {}
+            for n in names:
+                w = np.asarray(graphs[n].weights)
+                weights[n] = {(i, j): float(w[i, j])
+                              for i in range(self.N_NODES)
+                              for j in range(i + 1, self.N_NODES)
+                              if w[i, j] > 0}
+            rng = np.random.default_rng(11)
+            for t in range(6):
+                ds = {n: self._tick_delta(weights[n], t, rng)
+                      for n in names}
+                for n in names:
+                    entry = fleet.directory.get(n)
+                    got = fleet.router.translate(entry, ds[n], pool)
+                    self._assert_host(got)
+                    new = host_maps[n].translate(got)
+                    old = dev_maps[n].translate(
+                        self._device_repad(ds[n], pool))
+                    new_leaves, new_def = jax.tree_util.tree_flatten(new)
+                    old_leaves, old_def = jax.tree_util.tree_flatten(old)
+                    assert new_def == old_def, (t, n)
+                    for a, b in zip(new_leaves, old_leaves):
+                        assert a.dtype == b.dtype, (t, n)
+                        np.testing.assert_array_equal(a, b)
+                fleet.ingest(ds)
+                fleet.poll()
+                oracle.ingest([ds[n] for n in names])
+                oracle.poll()
+                got = fleet.scores()
+                ref = np.asarray(oracle.scores()).ravel()
+                for i, n in enumerate(names):
+                    assert abs(got[n] - float(ref[i])) < 1e-5, \
+                        (t, n, got[n], float(ref[i]))
+                    assert svc.slot_maps[slots[n]].to_json() \
+                        == host_maps[n].to_json(), (t, n)
         finally:
             fleet.close()
             oracle.close()

@@ -20,6 +20,7 @@ from jax.profiler import ProfileData
 from repro.fleet import FingerFleet, FleetConfig, PoolSpec
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.types import GraphDelta
+from repro.serving import FingerService, ServiceConfig, TopKSpec
 from repro.serving.plans import dummy_tick_args
 
 N_VIRT, N_NODES, B, K_PAD, TICKS, SAVE_EVERY = 32, 10, 3, 4, 3, 2
@@ -133,6 +134,46 @@ def test_lane_counters_equal_the_lanes_sent(traced):
     assert [s[3]["lanes"] for s in slotmaps] == sent
     for s in slotmaps:
         assert 0 < s[3]["kept"] <= s[3]["lanes"]
+
+
+def test_slotmap_reads_nothing_back_from_the_fleet(traced):
+    """The fleet router hands a sparse shard host leaves, so `SlotMap`
+    reads no device array back."""
+    _, spans, _, _, _ = traced
+    slotmaps = _named(spans, "finger.slotmap")
+    assert len(slotmaps) == TICKS
+    assert [s[3]["device_reads"] for s in slotmaps] == [0] * TICKS
+
+
+def test_slotmap_counts_device_leaves_read_back(tmp_path):
+    """A direct sparse `FingerService` caller that passes `from_arrays`
+    (device-leaf) deltas is counted: seven leaves a stream (five edge
+    and two node leaves), none for a stream given host leaves."""
+    graphs = [erdos_renyi(N_NODES, 0.4, seed=i, weighted=True)
+              for i in range(B)]
+    svc = FingerService.open(ServiceConfig(
+        batch_size=B, n_pad=N_VIRT, k_pad=K_PAD, j_pad=2,
+        method="sparse_tick", n_slots=16, m_pad=64,
+        topk=TopKSpec(k=2)), graphs)
+    try:
+        deltas = [GraphDelta.from_arrays([0], [1 + i], [0.5], [0.0],
+                                         n_nodes=N_VIRT, k_pad=K_PAD,
+                                         j_pad=2)
+                  for i in range(B - 1)]
+        deltas.append(GraphDelta.host_from_arrays(
+            [0], [B], [0.5], [0.0], n_nodes=N_VIRT, k_pad=K_PAD,
+            j_pad=2))
+        trace_dir = str(tmp_path)
+        jax.profiler.start_trace(trace_dir)
+        try:
+            svc.ingest(deltas)
+        finally:
+            jax.profiler.stop_trace()
+        svc.poll()
+    finally:
+        svc.close()
+    slotmap, = _named(_host_spans(trace_dir), "finger.slotmap")
+    assert slotmap[3]["device_reads"] == 7 * (B - 1)
 
 
 def test_h2d_bytes_are_the_staged_deltas_leaves(traced):

@@ -16,6 +16,7 @@ from repro.core import (
 )
 from repro.graphs import DenseGraph, GraphDelta, apply_delta_dense
 from repro.graphs.generators import erdos_renyi
+from repro.graphs.layout import NodeLayout
 from repro.graphs.streams import churn_stream
 
 
@@ -230,6 +231,75 @@ class TestRegressions:
         seq3, _ = dos_attack_sequence(n=100, n_graphs=4, seed=1,
                                       k_pad=64)
         assert {d.dw.shape for d in seq3.deltas} == {(64,)}
+
+
+# (kwargs of from_arrays, ValueError match or None). Lanes arrive
+# unordered and padded short of k_pad; joins precede leaves.
+_HOST_DELTA_CASES = {
+    "lanes": (dict(senders=[3, 0, 5], receivers=[1, 2, 4],
+                   dw=[0.5, -1.0, 2.0], w_old=[0.0, 1.0, 0.5],
+                   n_nodes=6, k_pad=5), None),
+    "self_loop_dropped": (dict(senders=[3, 2], receivers=[3, 1],
+                               dw=[1.0, 0.5], w_old=[0.0, 0.0],
+                               n_nodes=4, k_pad=3), None),
+    "joins_and_leaves": (dict(senders=[0], receivers=[7], dw=[1.0],
+                              w_old=[0.0], n_nodes=6, n_pad=8, k_pad=2,
+                              join=[6, 7], leave=[2], j_pad=4), None),
+    "layout_stamp": (dict(senders=[1], receivers=[0], dw=[1.0],
+                          w_old=[0.0], n_nodes=4,
+                          layout=NodeLayout(8, generation=3)), None),
+    "k_over_k_pad": (dict(senders=[0, 1, 2], receivers=[1, 2, 3],
+                          dw=[1.0] * 3, w_old=[0.0] * 3, n_nodes=4,
+                          k_pad=2), "exceed k_pad=2"),
+    "join_outside_n_pad": (dict(senders=[0], receivers=[1], dw=[1.0],
+                                w_old=[0.0], n_nodes=4, n_pad=4,
+                                join=[4]), "outside the n_pad=4"),
+    "leave_outside_n_pad": (dict(senders=[0], receivers=[1], dw=[1.0],
+                                 w_old=[0.0], n_nodes=4, leave=[-1]),
+                            "outside the n_pad=4"),
+    "j_over_j_pad": (dict(senders=[0], receivers=[1], dw=[1.0],
+                          w_old=[0.0], n_nodes=8, join=[2, 3],
+                          leave=[4], j_pad=2), "exceed j_pad=2"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HOST_DELTA_CASES))
+def test_host_from_arrays_is_from_arrays_on_the_host(case):
+    """`GraphDelta.host_from_arrays` pads and validates exactly as
+    `from_arrays` does, and differs only in where the leaves live:
+    numpy on the host against device arrays."""
+    import warnings
+
+    kwargs, error = _HOST_DELTA_CASES[case]
+    if error is not None:
+        for build in (GraphDelta.host_from_arrays, GraphDelta.from_arrays):
+            with pytest.raises(ValueError, match=error):
+                build(**kwargs)
+        return
+    with warnings.catch_warnings(record=True) as rec:
+        warnings.simplefilter("always")
+        host = GraphDelta.host_from_arrays(**kwargs)
+        dev = GraphDelta.from_arrays(**kwargs)
+    host_leaves, host_def = jax.tree_util.tree_flatten(host)
+    dev_leaves, dev_def = jax.tree_util.tree_flatten(dev)
+    assert host_def == dev_def  # same n_nodes, generation, None leaves
+    for h, d in zip(host_leaves, dev_leaves):
+        assert isinstance(h, np.ndarray) and not isinstance(h, jax.Array)
+        assert isinstance(d, jax.Array)
+        assert h.dtype == d.dtype and h.dtype in (np.int32, np.float32)
+        np.testing.assert_array_equal(h, np.asarray(d))
+    assert np.all(host.senders <= host.receivers)
+    if "k_pad" in kwargs:
+        assert host.senders.shape == (kwargs["k_pad"],)
+    if case == "self_loop_dropped":
+        assert sum("self-loop" in str(w.message) for w in rec) == 2
+        assert host.mask.tolist() == [1.0, 0.0, 0.0]
+        assert (host.senders[0], host.receivers[0]) == (1, 2)
+    if case == "joins_and_leaves":
+        assert host.node_ids.tolist() == [6, 7, 2, 0]
+        assert host.node_flag.tolist() == [1.0, 1.0, -1.0, 0.0]
+    if case == "layout_stamp":
+        assert (host.n_nodes, host.layout_generation) == (8, 3)
 
 
 @settings(max_examples=20, deadline=None)
