@@ -63,6 +63,7 @@ from repro.graphs.types import (
 )
 
 __all__ = [
+    "delta_moments",
     "delta_stats",
     "delta_stats_compact",
     "delta_stats_from_sorted",
@@ -135,6 +136,19 @@ def sorted_delta_endpoints(strengths: jax.Array, delta: GraphDelta):
         in_graph.astype(jnp.float32)
 
 
+def _segment_ds(sorted_nodes, sorted_vals, endpoint_valid):
+    """(head, Δs): per sorted endpoint, whether it opens its node's
+    segment, and the Δs of that node (its segment's sum)."""
+    two_k = sorted_nodes.shape[0]
+    head = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_nodes[1:] != sorted_nodes[:-1]])
+    head = jnp.logical_and(head, endpoint_valid > 0)
+    seg_id = jnp.cumsum(head) - 1
+    seg_ds = jax.ops.segment_sum(sorted_vals, seg_id, num_segments=two_k)
+    # Δs of the segment each endpoint belongs to, broadcast back per slot.
+    return head, seg_ds[seg_id]
+
+
 def delta_stats_from_sorted(
     sorted_nodes: jax.Array,      # (2k,) int32, ascending, sentinel last
     sorted_vals: jax.Array,       # (2k,) f32 masked Δw per endpoint
@@ -150,15 +164,7 @@ def delta_stats_from_sorted(
     `kernels.delta_stats` must match it up to float accumulation order.
     The max is -inf for an all-masked delta (dense-path convention).
     """
-    two_k = sorted_nodes.shape[0]
-    head = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_nodes[1:] != sorted_nodes[:-1]])
-    head = jnp.logical_and(head, endpoint_valid > 0)
-    seg_id = jnp.cumsum(head) - 1
-    seg_ds = jax.ops.segment_sum(sorted_vals, seg_id, num_segments=two_k)
-    # Δs of the segment each endpoint belongs to, broadcast back per slot.
-    ds_here = seg_ds[seg_id]
-
+    head, ds_here = _segment_ds(sorted_nodes, sorted_vals, endpoint_valid)
     node_term = jnp.sum(jnp.where(
         head,
         2.0 * sorted_strengths * ds_here + ds_here * ds_here,
@@ -182,6 +188,40 @@ def delta_stats_compact(state: FingerState, delta: GraphDelta):
     stats = delta_stats_from_sorted(*prep, delta.dw, delta.w_old,
                                     delta.mask)
     return stats[0], stats[1], stats[2]
+
+
+def delta_moments(state: FingerState, delta: GraphDelta,
+                  method: str = "dense"):
+    """(ΔS, A₁, A₂): the moments that carry G along G ⊕ tΔG.
+
+    With A = Σ s_i² + 2 Σ_E w² (Lemma 1: Q = 1 - A/S²), the graph
+    G ⊕ tΔG has S_t = S + tΔS and A_t = A + tA₁ + t²A₂, where
+
+      A₁ = 2 Σ_{ΔV} s_i Δs_i + 4 Σ_{ΔE} w_ij Δw_ij
+      A₂ = Σ_{ΔV} Δs_i² + 2 Σ_{ΔE} Δw_ij²
+
+    (Theorem 2's ΔQ term is A₁ + A₂). The delta is gated as
+    `update_state` gates it; ``method`` picks the dense scatter or the
+    compact sorted-endpoint reduction (``fused_tick`` takes the latter).
+    """
+    delta, _ = gate_delta_for_update(state.node_mask, delta)
+    m = delta.mask
+    dw = delta.dw * m
+    if method == "dense":
+        ds = jnp.zeros_like(state.strengths)
+        ds = ds.at[delta.senders].add(dw, mode="drop")
+        ds = ds.at[delta.receivers].add(dw, mode="drop")
+        lin = jnp.sum(2.0 * state.strengths * ds)
+        quad = jnp.sum(ds * ds)
+    else:
+        nodes, vals, strengths, valid = sorted_delta_endpoints(
+            state.strengths, delta)
+        head, ds = _segment_ds(nodes, vals, valid)
+        lin = jnp.sum(jnp.where(head, 2.0 * strengths * ds, 0.0))
+        quad = jnp.sum(jnp.where(head, ds * ds, 0.0))
+    a1 = lin + jnp.sum(4.0 * delta.w_old * dw)
+    a2 = quad + jnp.sum(2.0 * dw * dw)
+    return 2.0 * jnp.sum(dw), a1, a2
 
 
 def _apply_delta_strengths(strengths: jax.Array,

@@ -39,15 +39,15 @@ _tick` below is its single-stream oracle.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.jsdist import _js_from_entropies
-from repro.core.incremental import update_state
-from repro.core.state import FingerState, host_finger_state
+from repro.core.jsdist import js_from_increments
+from repro.core.incremental import delta_moments, update_state
+from repro.core.state import FingerState
 from repro.graphs.types import (
     DenseGraph,
     EdgeList,
@@ -75,6 +75,11 @@ EDGE_SLOT_SENTINEL = np.int32(2**31 - 1)
 # A post-delta edge weight at/below this fraction of the moved mass is
 # a deletion: the edge's slot is returned to the free list.
 _DELETED_EDGE_TOL = 1e-9
+
+# An undirected edge (lo, hi) of the virtual space is keyed
+# ``lo << 32 | hi`` (node ids are int32).
+_KEY_SHIFT = 32
+_KEY_MASK = (1 << _KEY_SHIFT) - 1
 
 
 class SparseCapacityError(RuntimeError):
@@ -226,8 +231,10 @@ def sparse_jsdist_tick(
 
     Two Theorem-2 updates (ΔG/2 and ΔG) through the dense math on the
     slot-universe view — O(Δm) statistics under ``method="compact"``
-    plus the O(n_slots) strength carry — then the edge-store scatter.
-    The single-stream oracle of `repro.kernels.sparse_tick`.
+    plus the O(n_slots) strength carry, scored from the tick's
+    increments (`core.jsdist.js_divergence_from_increments`) — then the
+    edge-store scatter. The single-stream oracle of
+    `repro.kernels.sparse_tick`.
     """
     _require_slot_delta(state, delta, "sparse_jsdist_tick")
     view = state.dense_view()
@@ -235,8 +242,8 @@ def sparse_jsdist_tick(
                         method=method)
     full = update_state(view, delta, exact_smax=exact_smax,
                         method=method)
-    dist = _js_from_entropies(half.h_tilde(), view.h_tilde(),
-                              full.h_tilde())
+    dist = js_from_increments(view, half, full,
+                              *delta_moments(view, delta, method))
     ew = _advance_edge_store(state, delta, full.s_total)
     return dist, SparseStreamState(
         q=full.q, s_total=full.s_total, s_max=full.s_max,
@@ -249,17 +256,164 @@ def sparse_jsdist_tick(
 # ---------------------------------------------------------------------------
 
 
+class _EdgeIndex:
+    """Open-addressed index from edge key to edge slot (linear probing).
+
+    The buckets hold edge slots (int32); a bucket's key is read from the
+    map's slot-to-key array, so the index costs 4 bytes a bucket. It is
+    sized once for the edge capacity (``m_pad`` at most 70% full) and
+    rebuilt only when released buckets (tombstones) fill it past 85% or
+    the capacity grows. Every operation works on a whole batch of keys at
+    once, one probe round per numpy pass; a lookup also notes where each
+    absent key would go, so its insertion need not probe again.
+    """
+
+    _EMPTY = -1
+    _TOMB = -2
+    _MAX_LOAD = 0.7
+    _CROWDED = 0.85
+    _MULT = np.uint64(0x9E3779B97F4A7C15)
+
+    def __init__(self, capacity: int):
+        bits = max(4, int(np.ceil(np.log2(max(capacity, 1)
+                                          / self._MAX_LOAD))))
+        self.bits = bits
+        self.table = np.full(1 << bits, self._EMPTY, np.int32)
+        self.live = 0
+        self.tombs = 0
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.table.nbytes)
+
+    def _home(self, keys: np.ndarray) -> np.ndarray:
+        h = keys.astype(np.uint64) * self._MULT
+        return (h >> np.uint64(64 - self.bits)).astype(np.int64)
+
+    def find(self, keys: np.ndarray, slot_key: np.ndarray):
+        """(slots, buckets, free) of ``keys``: a present key's slot and
+        bucket, -1 for an absent one; an absent key's first free bucket
+        on its probe path, where `insert` may put it, -1 for a present
+        one."""
+        n = keys.shape[0]
+        slots = np.full(n, -1, np.int32)
+        where = np.full(n, -1, np.int64)
+        free = np.full(n, -1, np.int64)
+        todo = np.arange(n)
+        pos = self._home(keys)
+        want = keys
+        mask = (1 << self.bits) - 1
+        while todo.size:
+            b = self.table[pos]
+            live = b >= 0
+            hit = live & (slot_key[np.where(live, b, 0)] == want)
+            slots[todo[hit]] = b[hit]
+            where[todo[hit]] = pos[hit]
+            opened = ~live
+            if self.tombs:  # the first tombstone, else the closing empty
+                opened &= free[todo] < 0
+            free[todo[opened]] = pos[opened]
+            go = ~hit & (b != self._EMPTY)
+            todo, pos, want = todo[go], (pos[go] + 1) & mask, want[go]
+        free[slots >= 0] = -1
+        return slots, where, free
+
+    def insert(self, keys: np.ndarray, slots: np.ndarray,
+               at: Optional[np.ndarray] = None) -> None:
+        """Add distinct ``keys`` (absent from the index) at ``slots``:
+        each at the first free bucket from its home on, or from its
+        bucket in ``at`` on (`find`'s free buckets, with nothing
+        inserted since; keys of one batch that meet there go in turn)."""
+        mask = (1 << self.bits) - 1
+        pos = self._home(keys) if at is None else at
+        slots = slots.astype(np.int32)
+        while pos.size:
+            b = self.table[pos]
+            free = b < 0
+            cand = pos[free]
+            self.table[cand] = slots[free]
+            won = np.zeros(pos.size, bool)
+            won[free] = self.table[cand] == slots[free]
+            self.tombs -= int(np.count_nonzero(b[won] == self._TOMB))
+            go = ~won
+            pos, slots = (pos[go] + 1) & mask, slots[go]
+        self.live += int(keys.shape[0])
+
+    def release(self, buckets: np.ndarray) -> None:
+        self.table[buckets] = self._TOMB
+        self.live -= int(buckets.shape[0])
+        self.tombs += int(buckets.shape[0])
+
+    def crowded(self, adding: int) -> bool:
+        return (self.live + self.tombs + adding) \
+            > self._CROWDED * self.table.shape[0]
+
+
+class _SlotStack:
+    """A free list as an int32 stack: allocation takes from the top,
+    frees push on top, a capacity grow slides the new slots in at the
+    bottom (they are used last)."""
+
+    def __init__(self, capacity: int):
+        self.items = np.arange(capacity - 1, -1, -1, dtype=np.int32)
+        self.top = capacity
+
+    @classmethod
+    def of(cls, capacity: int, items) -> "_SlotStack":
+        st = cls.__new__(cls)
+        st.items = np.zeros(capacity, np.int32)
+        items = np.asarray(items, np.int32)
+        st.items[:items.size] = items
+        st.top = int(items.size)
+        return st
+
+    def peek(self, count: int) -> np.ndarray:
+        """The next ``count`` slots, in the order they are taken."""
+        return self.items[self.top - count:self.top][::-1].copy()
+
+    def take(self, count: int) -> None:
+        self.top -= count
+
+    def push(self, slots: np.ndarray) -> None:
+        self.items[self.top:self.top + slots.size] = slots
+        self.top += int(slots.size)
+
+    def grow(self, old: int, new: int) -> None:
+        items = np.zeros(new, np.int32)
+        added = new - old
+        items[:added] = np.arange(new - 1, old - 1, -1, dtype=np.int32)
+        items[added:added + self.top] = self.items[:self.top]
+        self.items, self.top = items, self.top + added
+
+    def as_array(self) -> np.ndarray:
+        return self.items[:self.top].copy()
+
+
+def _edge_keys(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """One int64 key per undirected edge, ordered as (lo, hi) is."""
+    return (lo.astype(np.int64) << _KEY_SHIFT) | hi.astype(np.int64)
+
+
 class SlotMap:
     """Per-stream host translator from virtual node ids to device slots.
 
     Owns the allocation discipline of one stream's slot space: node
     slots are allocated on join and freed on leave, edge slots are
     allocated the first time an edge appears and freed when a delta
-    deletes it (post-delta weight ≈ 0) or its endpoint leaves. All
+    deletes it (post-delta weight ≈ 0) or its endpoint leaves (a
+    leaving node's remaining edges go in ascending (lo, hi) order). All
     frees/allocations commit only after the whole delta validates, so a
     rejected delta never corrupts the map — and freed slots are not
     reused within the same delta (a single tick's scatter must never
     write one slot twice).
+
+    Array-backed, so a delta's lanes resolve in a few numpy passes and
+    a graph of tens of millions of edges fits: node id → slot is an
+    int32 array over ``n_virtual`` (beside its inverse and each node
+    slot's live-edge count), edge slot → key ``lo << 32 | hi`` an int64
+    array over ``m_pad``, key → edge slot a `_EdgeIndex`, and the
+    two free lists int32 stacks. About 39 bytes of host memory an edge
+    when half the edge capacity is live.
 
     ``translate`` is stateful: call it exactly once per applied delta,
     in tick order (serving ingestion does; the queue holds translated
@@ -277,14 +431,13 @@ class SlotMap:
         self.layout = layout
         self.n_virtual = int(n_virtual)
         self.stream = stream
-        self.node_slot: Dict[int, int] = {}
-        self.edge_slot: Dict[Tuple[int, int], int] = {}
-        # stacks: allocation pops from the end, frees push back
-        self._free_nodes: List[int] = list(range(layout.n_slots - 1,
-                                                 -1, -1))
-        self._free_edges: List[int] = list(range(layout.m_pad - 1,
-                                                 -1, -1))
-        self._node_edges: Dict[int, Set[Tuple[int, int]]] = {}
+        self._slot_of_vid = np.full(self.n_virtual, -1, np.int32)
+        self._vid_of_slot = np.full(layout.n_slots, -1, np.int32)
+        self._degree = np.zeros(layout.n_slots, np.int32)
+        self._key_of_slot = np.full(layout.m_pad, -1, np.int64)
+        self._free_nodes = _SlotStack(layout.n_slots)
+        self._free_edges = _SlotStack(layout.m_pad)
+        self._index = _EdgeIndex(layout.m_pad)
 
     def _where(self) -> str:
         tag = "" if self.stream is None else f"[stream {self.stream}] "
@@ -292,28 +445,44 @@ class SlotMap:
 
     @property
     def n_free_nodes(self) -> int:
-        return len(self._free_nodes)
+        return self._free_nodes.top
 
     @property
     def n_free_edges(self) -> int:
-        return len(self._free_edges)
+        return self._free_edges.top
+
+    @property
+    def n_live_edges(self) -> int:
+        return self.layout.m_pad - self._free_edges.top
+
+    @property
+    def nbytes(self) -> int:
+        """Host bytes the map's arrays hold."""
+        return int(self._slot_of_vid.nbytes + self._vid_of_slot.nbytes
+                   + self._degree.nbytes + self._key_of_slot.nbytes
+                   + self._free_nodes.items.nbytes
+                   + self._free_edges.items.nbytes + self._index.nbytes)
 
     def grow(self, new_layout: SparseLayout) -> None:
-        """Adopt a grown layout: append the new slots to the free lists
-        (existing assignments keep their ids)."""
-        if new_layout.n_slots < self.layout.n_slots \
-                or new_layout.m_pad < self.layout.m_pad:
+        """Adopt a grown layout: the new slots go to the bottom of the
+        free lists (existing assignments keep their ids)."""
+        old = self.layout
+        if new_layout.n_slots < old.n_slots \
+                or new_layout.m_pad < old.m_pad:
             raise ValueError(
                 f"SlotMap.grow: ({new_layout.n_slots}, "
                 f"{new_layout.m_pad}) shrinks the current capacity "
-                f"({self.layout.n_slots}, {self.layout.m_pad})")
-        self._free_nodes = list(
-            range(new_layout.n_slots - 1, self.layout.n_slots - 1, -1)
-        ) + self._free_nodes
-        self._free_edges = list(
-            range(new_layout.m_pad - 1, self.layout.m_pad - 1, -1)
-        ) + self._free_edges
+                f"({old.n_slots}, {old.m_pad})")
+        self._free_nodes.grow(old.n_slots, new_layout.n_slots)
+        self._free_edges.grow(old.m_pad, new_layout.m_pad)
+        self._vid_of_slot = _extend(self._vid_of_slot, new_layout.n_slots,
+                                    -1)
+        self._degree = _extend(self._degree, new_layout.n_slots, 0)
+        self._key_of_slot = _extend(self._key_of_slot, new_layout.m_pad,
+                                    -1)
         self.layout = new_layout
+        if new_layout.m_pad != old.m_pad:
+            self._rebuild_index()
 
     def grow_virtual(self, n_virtual: int) -> None:
         """Raise the virtual addressing bound (a host-only 'repad')."""
@@ -322,51 +491,124 @@ class SlotMap:
                 f"SlotMap.grow_virtual: n_virtual={n_virtual} shrinks "
                 f"the current bound {self.n_virtual}")
         self.n_virtual = int(n_virtual)
+        self._slot_of_vid = _extend(self._slot_of_vid, self.n_virtual, -1)
+
+    def _rebuild_index(self) -> None:
+        slots = np.nonzero(self._key_of_slot >= 0)[0]
+        self._index = _EdgeIndex(self.layout.m_pad)
+        self._index.insert(self._key_of_slot[slots], slots)
+
+    def admit(self, vids: np.ndarray, lo: np.ndarray,
+              hi: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """Allocate slots for an admitted graph, in the order given:
+        its active nodes ``vids`` (inactive so far), then its distinct
+        edges (``lo`` < ``hi``, both ends among ``vids``). Returns
+        (node slots, edge slots); the caller checks capacity."""
+        nodes = self._free_nodes.peek(vids.size)
+        self._free_nodes.take(vids.size)
+        self._slot_of_vid[vids] = nodes
+        self._vid_of_slot[nodes] = vids
+        edges = self._free_edges.peek(lo.size)
+        self._free_edges.take(lo.size)
+        keys = _edge_keys(lo, hi)
+        self._key_of_slot[edges] = keys
+        for ends in (lo, hi):
+            self._degree += np.bincount(
+                self._slot_of_vid[ends],
+                minlength=self.layout.n_slots).astype(np.int32)
+        self._index.insert(keys, edges)
+        return nodes, edges
 
     # -- persistence -----------------------------------------------------
-    def to_json(self) -> dict:
-        """The map as a JSON-serializable dict: capacities, the two
-        assignment tables, and the free lists *in stack order* —
-        allocation order is part of the translation contract (the
-        next join must take the same slot after a round trip), so the
-        free lists persist verbatim rather than being re-derived."""
-        return {
-            "n_slots": int(self.layout.n_slots),
-            "m_pad": int(self.layout.m_pad),
-            "generation": int(self.layout.generation),
-            "n_virtual": int(self.n_virtual),
-            "stream": self.stream,
-            "node_slot": [[int(v), int(s)]
-                          for v, s in sorted(self.node_slot.items())],
-            "edge_slot": [[int(lo), int(hi), int(s)]
-                          for (lo, hi), s
-                          in sorted(self.edge_slot.items())],
-            "free_nodes": [int(s) for s in self._free_nodes],
-            "free_edges": [int(s) for s in self._free_edges],
-        }
+    def header(self) -> dict:
+        """The map's sizes, JSON-serializable; `arrays` holds the rest."""
+        return {"n_slots": int(self.layout.n_slots),
+                "m_pad": int(self.layout.m_pad),
+                "generation": int(self.layout.generation),
+                "n_virtual": int(self.n_virtual),
+                "stream": self.stream}
+
+    def arrays(self) -> Dict[str, np.ndarray]:
+        """The map's state as arrays: each node slot's virtual id and
+        each edge slot's key (-1 where free), and the free lists in
+        stack order — allocation order is part of the translation
+        contract (the next join must take the same slot after a round
+        trip), so the free lists persist verbatim."""
+        return {"vid_of_slot": self._vid_of_slot.copy(),
+                "key_of_slot": self._key_of_slot.copy(),
+                "free_nodes": self._free_nodes.as_array(),
+                "free_edges": self._free_edges.as_array()}
+
+    @classmethod
+    def from_arrays(cls, header: dict,
+                    arrays: Mapping[str, np.ndarray]) -> "SlotMap":
+        """Rebuild a map saved as `header` and `arrays`."""
+        layout = SparseLayout(n_slots=int(header["n_slots"]),
+                              m_pad=int(header["m_pad"]),
+                              generation=int(header["generation"]))
+        sm = cls.__new__(cls)
+        sm.layout = layout
+        sm.n_virtual = int(header["n_virtual"])
+        sm.stream = header.get("stream")
+        sm._vid_of_slot = np.asarray(arrays["vid_of_slot"], np.int32).copy()
+        sm._key_of_slot = np.asarray(arrays["key_of_slot"], np.int64).copy()
+        sm._free_nodes = _SlotStack.of(layout.n_slots,
+                                       arrays["free_nodes"])
+        sm._free_edges = _SlotStack.of(layout.m_pad, arrays["free_edges"])
+        sm._slot_of_vid = np.full(sm.n_virtual, -1, np.int32)
+        slots = np.nonzero(sm._vid_of_slot >= 0)[0]
+        sm._slot_of_vid[sm._vid_of_slot[slots]] = slots
+        sm._degree = np.zeros(layout.n_slots, np.int32)
+        keys = sm._key_of_slot[sm._key_of_slot >= 0]
+        for ends in (keys >> _KEY_SHIFT, keys & _KEY_MASK):
+            sm._degree += np.bincount(
+                sm._slot_of_vid[ends],
+                minlength=layout.n_slots).astype(np.int32)
+        sm._rebuild_index()
+        return sm
 
     @classmethod
     def from_json(cls, payload: dict) -> "SlotMap":
-        """Rebuild a map serialized by `to_json` — assignments, free
-        lists (exact order), and the per-node edge index (re-derived
-        from the edge table)."""
-        layout = SparseLayout(n_slots=int(payload["n_slots"]),
-                              m_pad=int(payload["m_pad"]),
-                              generation=int(payload["generation"]))
-        sm = cls(layout, int(payload["n_virtual"]),
-                 stream=payload.get("stream"))
-        sm.node_slot = {int(v): int(s)
-                        for v, s in payload["node_slot"]}
-        sm.edge_slot = {(int(lo), int(hi)): int(s)
-                        for lo, hi, s in payload["edge_slot"]}
-        sm._free_nodes = [int(s) for s in payload["free_nodes"]]
-        sm._free_edges = [int(s) for s in payload["free_edges"]]
-        sm._node_edges = {int(v): set() for v in sm.node_slot}
-        for key in sm.edge_slot:
-            sm._node_edges.setdefault(key[0], set()).add(key)
-            sm._node_edges.setdefault(key[1], set()).add(key)
-        return sm
+        """Rebuild a map from the single JSON payload older checkpoints
+        hold: its sizes, ``node_slot`` as [vid, slot] and ``edge_slot``
+        as [lo, hi, slot] entries, and the free lists in stack order."""
+        n_slots, m_pad = int(payload["n_slots"]), int(payload["m_pad"])
+        vid_of_slot = np.full(n_slots, -1, np.int32)
+        nodes = np.asarray(payload["node_slot"], np.int64).reshape(-1, 2)
+        vid_of_slot[nodes[:, 1]] = nodes[:, 0]
+        key_of_slot = np.full(m_pad, -1, np.int64)
+        edges = np.asarray(payload["edge_slot"], np.int64).reshape(-1, 3)
+        key_of_slot[edges[:, 2]] = _edge_keys(edges[:, 0], edges[:, 1])
+        return cls.from_arrays(payload, {
+            "vid_of_slot": vid_of_slot, "key_of_slot": key_of_slot,
+            "free_nodes": payload["free_nodes"],
+            "free_edges": payload["free_edges"]})
 
+    def to_virtual(self, row: np.ndarray, n_nodes: int) -> np.ndarray:
+        """A per-slot ``row`` gathered into virtual ids ``0 .. n_nodes
+        - 1``: each allocated node's value at its id, 0 elsewhere."""
+        out = np.zeros((n_nodes,), np.asarray(row).dtype)
+        slots = np.nonzero((self._vid_of_slot >= 0)
+                           & (self._vid_of_slot < n_nodes))[0]
+        out[self._vid_of_slot[slots]] = np.asarray(row)[slots]
+        return out
+
+    ARRAYS = ("vid_of_slot", "key_of_slot", "free_nodes", "free_edges")
+
+    @classmethod
+    def restore(cls, payload: dict,
+                arrays: Optional[Mapping[str, np.ndarray]] = None
+                ) -> "SlotMap":
+        """A map from a checkpoint: its `header` and `arrays`, or the
+        single JSON payload older checkpoints hold."""
+        if "node_slot" in payload:
+            return cls.from_json(payload)
+        if arrays is None:
+            raise ValueError("SlotMap.restore: the payload holds no "
+                             "assignments and no arrays were given")
+        return cls.from_arrays(payload, arrays)
+
+    # -- translation -----------------------------------------------------
     def translate(self, delta: GraphDelta) -> GraphDelta:
         """Virtual-space `GraphDelta` → slot-space delta with edge slots.
 
@@ -379,6 +621,20 @@ class SlotMap:
         duplicate edge lanes. Equivalent to ``commit(stage(delta))``.
         """
         return self.commit(self.stage(delta))
+
+    def _slots_of(self, vids: np.ndarray, new_vids: np.ndarray,
+                  new_slots: np.ndarray) -> np.ndarray:
+        """Node slots of in-range ``vids``, counting staged joins;
+        -1 for an inactive node."""
+        slots = self._slot_of_vid[vids]
+        if new_vids.size:
+            miss = np.nonzero(slots < 0)[0]
+            order = np.argsort(new_vids)
+            idx = np.minimum(np.searchsorted(new_vids[order], vids[miss]),
+                             new_vids.size - 1)
+            hit = new_vids[order][idx] == vids[miss]
+            slots[miss[hit]] = new_slots[order][idx[hit]]
+        return slots
 
     def stage(self, delta: GraphDelta) -> "_StagedTranslation":
         """The pure half of `translate`: validate + resolve slots
@@ -415,8 +671,7 @@ class SlotMap:
                 f"n_pad={self.n_virtual} virtual space; re-pad the "
                 "stream to a larger n_pad to grow past it")
 
-        joins: List[int] = []
-        leaves: List[int] = []
+        new_vids = np.zeros(0, np.int64)
         if delta.node_ids is not None:
             nid = np.asarray(delta.node_ids, np.int64)
             nflag = np.asarray(delta.node_flag, np.float32)
@@ -426,27 +681,59 @@ class SlotMap:
                     where + f"join/leave node id(s) "
                     f"{sorted(set(int(i) for i in nid[oob]))} outside "
                     f"the n_pad={self.n_virtual} virtual space")
-            joins = [int(i) for i in nid[nflag > 0]]
-            leaves = [int(i) for i in nid[nflag < 0]]
-
-        # -- stage (no mutation until everything validates) --------------
-        staged_nodes: Dict[int, int] = {}
-        for vid in joins:
-            if vid in self.node_slot or vid in staged_nodes:
-                continue  # re-join of an active node: mask no-op
-            idx = len(staged_nodes)
-            if idx >= len(self._free_nodes):
+            joins = nid[nflag > 0]
+            # a re-join of an active node is a mask no-op
+            first = np.zeros(joins.size, bool)
+            first[np.unique(joins, return_index=True)[1]] = True
+            new_vids = joins[first & (self._slot_of_vid[joins] < 0)]
+            if new_vids.size > self.n_free_nodes:
                 raise SparseCapacityError(
                     where + f"node slots exhausted (n_slots="
                     f"{self.layout.n_slots}, all allocated) while "
-                    f"joining node {vid}; grow the capacity "
-                    "(FingerService.grow_capacity)")
-            staged_nodes[vid] = self._free_nodes[-(1 + idx)]
+                    f"joining node {int(new_vids[self.n_free_nodes])}; "
+                    "grow the capacity (FingerService.grow_capacity)")
+        new_nodes = self._free_nodes.peek(new_vids.size)
 
-        def slot_of(vid: int) -> Optional[int]:
-            if vid in self.node_slot:
-                return self.node_slot[vid]
-            return staged_nodes.get(vid)
+        # -- edge lanes: drop padding, self-loops and lanes touching an
+        # inactive node (the dense node mask gates them to exactly zero)
+        lanes = np.nonzero(valid)[0]
+        lo = np.minimum(senders[lanes], receivers[lanes])
+        hi = np.maximum(senders[lanes], receivers[lanes])
+        s_lo = self._slots_of(lo, new_vids, new_nodes)
+        s_hi = self._slots_of(hi, new_vids, new_nodes)
+        keep = (lo != hi) & (s_lo >= 0) & (s_hi >= 0)
+        lanes, lo, hi, s_lo, s_hi = (x[keep] for x in
+                                     (lanes, lo, hi, s_lo, s_hi))
+        keys = _edge_keys(lo, hi)
+        order = np.argsort(keys, kind="stable")
+        later = np.zeros(keys.size, bool)
+        later[order[1:][keys[order[1:]] == keys[order[:-1]]]] = True
+        slots, buckets, free = self._index.find(keys, self._key_of_slot)
+        fresh = (slots < 0) & ~later
+        over = np.nonzero(fresh & (np.cumsum(fresh)
+                                   > self.n_free_edges))[0]
+        first_over = int(over[0]) if over.size else keys.size
+        first_dup = int(np.argmax(later)) if later.any() else keys.size
+        if first_dup < first_over:
+            raise ValueError(
+                where + f"duplicate edge lane for ({int(lo[first_dup])}, "
+                f"{int(hi[first_dup])}) in one delta; the slot-addressed "
+                "edge store cannot scatter one slot twice per tick — "
+                "merge the lanes' dw host-side")
+        if first_over < keys.size:
+            raise SparseCapacityError(
+                where + f"edge slots exhausted (m_pad="
+                f"{self.layout.m_pad}, "
+                f"{self.n_live_edges + self.n_free_edges} live) while "
+                f"adding edge ({int(lo[first_over])}, "
+                f"{int(hi[first_over])}); grow the capacity "
+                "(FingerService.grow_capacity)")
+        new_edges = self._free_edges.peek(int(np.count_nonzero(fresh)))
+        slots[fresh] = new_edges
+        w0 = w_old[lanes].astype(np.float64)
+        d = dw[lanes].astype(np.float64)
+        gone = (~fresh) & (w0 + d <= _DELETED_EDGE_TOL
+                           * (np.abs(w0) + np.abs(d)))
 
         out_snd = np.zeros(k_pad, np.int32)
         out_rcv = np.zeros(k_pad, np.int32)
@@ -454,75 +741,28 @@ class SlotMap:
         out_wold = np.zeros(k_pad, np.float32)
         out_mask = np.zeros(k_pad, np.float32)
         out_slot = np.full(k_pad, EDGE_SLOT_SENTINEL, np.int32)
-
-        staged_edges: Dict[Tuple[int, int], int] = {}
-        deleted: List[Tuple[int, int]] = []
-        seen: Set[Tuple[int, int]] = set()
-        for lane in range(k_pad):
-            if not valid[lane]:
-                continue
-            lo = int(min(senders[lane], receivers[lane]))
-            hi = int(max(senders[lane], receivers[lane]))
-            if lo == hi:
-                continue  # self-loop: from_arrays drops these already
-            s_lo, s_hi = slot_of(lo), slot_of(hi)
-            if s_lo is None or s_hi is None:
-                # dense semantics: an edge touching an inactive node is
-                # gated to exactly zero — drop the lane host-side
-                continue
-            key = (lo, hi)
-            if key in seen:
-                raise ValueError(
-                    where + f"duplicate edge lane for ({lo}, {hi}) in "
-                    "one delta; the slot-addressed edge store cannot "
-                    "scatter one slot twice per tick — merge the "
-                    "lanes' dw host-side")
-            seen.add(key)
-            if key in self.edge_slot:
-                slot = self.edge_slot[key]
-            else:
-                idx = len(staged_edges)
-                if idx >= len(self._free_edges):
-                    raise SparseCapacityError(
-                        where + f"edge slots exhausted (m_pad="
-                        f"{self.layout.m_pad}, "
-                        f"{len(self.edge_slot) + idx} live) while "
-                        f"adding edge ({lo}, {hi}); grow the capacity "
-                        "(FingerService.grow_capacity)")
-                slot = self._free_edges[-(1 + idx)]
-                staged_edges[key] = slot
-            new_w = float(w_old[lane]) + float(dw[lane])
-            if key in self.edge_slot and new_w <= _DELETED_EDGE_TOL * (
-                    abs(float(w_old[lane])) + abs(float(dw[lane]))):
-                deleted.append(key)
-            out_snd[lane] = min(s_lo, s_hi)
-            out_rcv[lane] = max(s_lo, s_hi)
-            out_dw[lane] = dw[lane]
-            out_wold[lane] = w_old[lane]
-            out_mask[lane] = 1.0
-            out_slot[lane] = slot
+        out_snd[lanes] = np.minimum(s_lo, s_hi)
+        out_rcv[lanes] = np.maximum(s_lo, s_hi)
+        out_dw[lanes] = dw[lanes]
+        out_wold[lanes] = w_old[lanes]
+        out_mask[lanes] = 1.0
+        out_slot[lanes] = slots
 
         out_nid = out_nflag = None
+        freed = np.zeros(0, np.int64)
         if delta.node_ids is not None:
-            j_pad = nid.shape[0]
-            out_nid = np.zeros(j_pad, np.int32)
-            out_nflag = np.zeros(j_pad, np.float32)
-            freed_nodes: List[int] = []
-            for lane in range(j_pad):
-                if nflag[lane] > 0:
-                    slot = slot_of(int(nid[lane]))
-                    out_nid[lane] = slot
-                    out_nflag[lane] = 1.0
-                elif nflag[lane] < 0:
-                    vid = int(nid[lane])
-                    slot = slot_of(vid)
-                    if slot is None:
-                        continue  # leave of an inactive node: no-op
-                    out_nid[lane] = slot
-                    out_nflag[lane] = -1.0
-                    freed_nodes.append(vid)
-        else:
-            freed_nodes = []
+            out_nid = np.zeros(nid.shape[0], np.int32)
+            out_nflag = np.zeros(nid.shape[0], np.float32)
+            at = np.nonzero(nflag > 0)[0]
+            out_nid[at] = self._slots_of(nid[at], new_vids, new_nodes)
+            out_nflag[at] = 1.0
+            at = np.nonzero(nflag < 0)[0]
+            left = self._slots_of(nid[at], new_vids, new_nodes)
+            at, left = at[left >= 0], left[left >= 0]  # inactive: no-op
+            out_nid[at] = left
+            out_nflag[at] = -1.0
+            freed = nid[at]
+            freed = freed[np.sort(np.unique(freed, return_index=True)[1])]
 
         # Host (numpy) leaves: the service stacks the B per-stream
         # deltas on the host and moves the tick to the device once.
@@ -535,58 +775,101 @@ class SlotMap:
             edge_slots=out_slot,
         )
         return _StagedTranslation(
-            delta=slot_delta, staged_nodes=staged_nodes,
-            staged_edges=staged_edges, deleted=deleted,
-            freed_nodes=freed_nodes)
+            delta=slot_delta, node_vids=new_vids, node_slots=new_nodes,
+            edge_keys=keys[fresh], edge_slots=new_edges,
+            edge_buckets=free[fresh],
+            edge_ends=np.concatenate([s_lo[fresh], s_hi[fresh]]),
+            gone_slots=slots[gone], gone_buckets=buckets[gone],
+            gone_ends=np.concatenate([s_lo[gone], s_hi[gone]]),
+            freed_vids=freed)
 
     def commit(self, staged: "_StagedTranslation") -> GraphDelta:
         """Apply a staged translation to the map and return its
         slot-space delta. The staged slot assignments index this map's
         free lists, so nothing may stage or commit on this map in
         between."""
-        staged_nodes = staged.staged_nodes
-        staged_edges = staged.staged_edges
-        if staged_nodes:
-            del self._free_nodes[-len(staged_nodes):]
-            for vid, slot in staged_nodes.items():
-                self.node_slot[vid] = slot
-                self._node_edges.setdefault(vid, set())
-        if staged_edges:
-            del self._free_edges[-len(staged_edges):]
-            for key, slot in staged_edges.items():
-                self.edge_slot[key] = slot
-                self._node_edges.setdefault(key[0], set()).add(key)
-                self._node_edges.setdefault(key[1], set()).add(key)
-        for key in staged.deleted:
-            self._release_edge(key)
-        for vid in staged.freed_nodes:
-            for key in list(self._node_edges.get(vid, ())):
-                # isolated-leave contract: normally already deleted
-                self._release_edge(key)
-            self._node_edges.pop(vid, None)
-            self._free_nodes.append(self.node_slot.pop(vid))
+        if staged.node_vids.size:
+            self._free_nodes.take(staged.node_vids.size)
+            self._slot_of_vid[staged.node_vids] = staged.node_slots
+            self._vid_of_slot[staged.node_slots] = staged.node_vids
+        if staged.edge_slots.size:
+            self._free_edges.take(staged.edge_slots.size)
+            self._key_of_slot[staged.edge_slots] = staged.edge_keys
+            np.add.at(self._degree, staged.edge_ends, np.int32(1))
+        if staged.gone_slots.size:
+            self._index.release(staged.gone_buckets)
+            self._drop_edges(staged.gone_slots, staged.gone_ends)
+        if staged.edge_slots.size:
+            if self._index.crowded(staged.edge_slots.size):
+                self._rebuild_index()
+            else:
+                self._index.insert(staged.edge_keys, staged.edge_slots,
+                                   at=staged.edge_buckets)
+        freed = staged.freed_vids
+        if freed.size:
+            slots = self._slot_of_vid[freed]
+            if self._degree[slots].any():
+                self._release_edges_of(freed)
+            self._slot_of_vid[freed] = -1
+            self._vid_of_slot[slots] = -1
+            self._free_nodes.push(slots)
         return staged.delta
 
-    def _release_edge(self, key: Tuple[int, int]) -> None:
-        slot = self.edge_slot.pop(key, None)
-        if slot is None:
-            return
-        self._free_edges.append(slot)
-        for vid in key:
-            edges = self._node_edges.get(vid)
-            if edges is not None:
-                edges.discard(key)
+    def _drop_edges(self, slots: np.ndarray, ends: np.ndarray) -> None:
+        """Free edge ``slots`` (pushed in the order given); ``ends``
+        are their endpoints' node slots."""
+        self._key_of_slot[slots] = -1
+        np.subtract.at(self._degree, ends, np.int32(1))
+        self._free_edges.push(slots.astype(np.int32))
+
+    def _release_edges_of(self, vids: np.ndarray) -> None:
+        """Free every live edge of the distinct leaving nodes ``vids``
+        (isolated-leave contract: normally already deleted): node by
+        node in the order given, each node's edges in ascending key
+        order, an edge between two of them with the first. One pass
+        over the edge slots for the whole delta."""
+        slots = np.nonzero(self._key_of_slot >= 0)[0]
+        keys = self._key_of_slot[slots]
+        lo, hi = keys >> _KEY_SHIFT, keys & _KEY_MASK
+        rank = np.full(self.n_virtual, vids.size, np.int32)
+        rank[vids] = np.arange(vids.size, dtype=np.int32)
+        owner = np.minimum(rank[lo], rank[hi])
+        mine = np.nonzero(owner < vids.size)[0]
+        mine = mine[np.lexsort((keys[mine], owner[mine]))]
+        slots, keys, lo, hi = slots[mine], keys[mine], lo[mine], hi[mine]
+        _, buckets, _ = self._index.find(keys, self._key_of_slot)
+        self._index.release(buckets)
+        self._drop_edges(slots, np.concatenate(
+            [self._slot_of_vid[lo], self._slot_of_vid[hi]]))
+
+
+def _extend(a: np.ndarray, size: int, fill) -> np.ndarray:
+    if size == a.shape[0]:
+        return a
+    out = np.full(size, fill, a.dtype)
+    out[:a.shape[0]] = a
+    return out
 
 
 @dataclasses.dataclass
 class _StagedTranslation:
-    """One `SlotMap.stage` result awaiting `commit` (see SlotMap)."""
+    """One `SlotMap.stage` result awaiting `commit` (see SlotMap): the
+    joining nodes and new edges with the slots they take (and the index
+    buckets the edges go to), the deleted edges' slots and index
+    buckets, the leaving nodes; ``*_ends`` are
+    node slots of the edges' two endpoints (lo ends, then hi ends)."""
 
     delta: GraphDelta
-    staged_nodes: Dict[int, int]
-    staged_edges: Dict[Tuple[int, int], int]
-    deleted: List[Tuple[int, int]]
-    freed_nodes: List[int]
+    node_vids: np.ndarray
+    node_slots: np.ndarray
+    edge_keys: np.ndarray
+    edge_slots: np.ndarray
+    edge_buckets: np.ndarray
+    edge_ends: np.ndarray
+    gone_slots: np.ndarray
+    gone_buckets: np.ndarray
+    gone_ends: np.ndarray
+    freed_vids: np.ndarray
 
 
 # ---------------------------------------------------------------------------
@@ -605,9 +888,10 @@ def sparse_state_from_graph(
     """Host graph → (slot-space state, its `SlotMap`), one O(n + m) pass.
 
     Active nodes get slots in ascending virtual-id order, edges in
-    (i, j) lexicographic order; the FINGER statistics are computed on
-    the slot-space graph directly (relabeling invariance makes them
-    exactly the virtual graph's).
+    (i, j) lexicographic order, each set in a few numpy passes; the
+    FINGER statistics are computed on the slot-space graph directly
+    (relabeling invariance makes them exactly the virtual graph's), in
+    float64 and rounded to the state's float32.
     """
     n_virtual = g.n_nodes if n_virtual is None else int(n_virtual)
     if g.n_nodes > n_virtual:
@@ -630,8 +914,11 @@ def sparse_state_from_graph(
         nz = vals != 0.0
         iu = np.asarray(g.senders, np.int64)[nz]
         ju = np.asarray(g.receivers, np.int64)[nz]
-        order = np.lexsort((ju, iu))
-        iu, ju, vals = iu[order], ju[order], vals[nz][order]
+        vals = vals[nz]
+        keys = _edge_keys(iu, ju)
+        if np.any(keys[1:] <= keys[:-1]):  # already in order: no sort
+            order = np.argsort(keys, kind="stable")
+            iu, ju, vals = iu[order], ju[order], vals[order]
     else:
         w = np.asarray(g.masked_weights(), np.float32)
         iu, ju = np.triu_indices(g.n_nodes, k=1)
@@ -644,33 +931,25 @@ def sparse_state_from_graph(
             f"m_pad={layout.m_pad}; use a larger capacity")
 
     slot_map = SlotMap(layout, n_virtual, stream=stream)
-    for vid in active:
-        slot_map.node_slot[int(vid)] = slot_map._free_nodes.pop()
-        slot_map._node_edges.setdefault(int(vid), set())
-    snd = np.zeros(iu.size, np.int32)
-    rcv = np.zeros(iu.size, np.int32)
+    node_slots, edge_slots = slot_map.admit(active, iu, ju)
+    a = slot_map._slot_of_vid[iu]
+    b = slot_map._slot_of_vid[ju]
     ew = np.zeros(layout.m_pad, np.float32)
-    for lane in range(iu.size):
-        key = (int(iu[lane]), int(ju[lane]))
-        slot = slot_map._free_edges.pop()
-        slot_map.edge_slot[key] = slot
-        slot_map._node_edges[key[0]].add(key)
-        slot_map._node_edges[key[1]].add(key)
-        a, b = slot_map.node_slot[key[0]], slot_map.node_slot[key[1]]
-        snd[lane], rcv[lane] = min(a, b), max(a, b)
-        ew[slot] = vals[lane]
-
+    ew[edge_slots] = vals
     slot_mask = np.zeros(layout.n_slots, np.float32)
-    for vid in active:
-        slot_mask[slot_map.node_slot[int(vid)]] = 1.0
-    el = EdgeList.from_arrays(
-        snd, rcv, vals, n_nodes=layout.n_slots,
-        m_pad=max(int(iu.size), 1), n_pad=layout.n_slots,
-        node_mask=slot_mask)
-    fs = host_finger_state(el)
+    slot_mask[node_slots] = 1.0
+    # Lemma 1 in float64 numpy, one pass over the edges, then rounded
+    w = vals.astype(np.float64)
+    strengths = np.bincount(a, w, layout.n_slots) \
+        + np.bincount(b, w, layout.n_slots)
+    s_total = strengths.sum()
+    c = 1.0 / s_total if s_total > 0 else 0.0
+    q = 1.0 - c * c * (strengths @ strengths + 2.0 * (w @ w))
     state = SparseStreamState(
-        q=jnp.asarray(fs.q), s_total=jnp.asarray(fs.s_total),
-        s_max=jnp.asarray(fs.s_max), strengths=jnp.asarray(fs.strengths),
+        q=jnp.asarray(q, jnp.float32),
+        s_total=jnp.asarray(s_total, jnp.float32),
+        s_max=jnp.asarray(strengths.max(), jnp.float32),
+        strengths=jnp.asarray(strengths.astype(np.float32)),
         node_mask=jnp.asarray(slot_mask), edge_weights=jnp.asarray(ew),
         layout=layout)
     return state, slot_map

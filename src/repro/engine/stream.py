@@ -57,6 +57,7 @@ from repro.graphs.layout import NodeLayout
 from repro.graphs.types import GraphDelta
 from repro.train.checkpoint import (
     latest_checkpoint,
+    load_arrays,
     load_manifest,
     restore_checkpoint,
     save_checkpoint,
@@ -80,6 +81,28 @@ def _check_consistent(label: str, kind: str, values) -> None:
             f"streams but {[values[i] for i in bad]!r} for stream(s) "
             f"{bad}; pad every stream to one shared layout "
             f"(thread n_pad/k_pad through the constructors)")
+
+
+def slot_map_checkpoint(slot_maps) -> Tuple[List[dict], dict]:
+    """Per-stream `SlotMap`s as checkpoint metadata (one header each)
+    and the arrays to store beside the state (`_restore_slot_maps`)."""
+    arrays = {f"slot_maps/{i}/{k}": v
+              for i, sm in enumerate(slot_maps)
+              for k, v in sm.arrays().items()}
+    return [sm.header() for sm in slot_maps], arrays
+
+
+def _restore_slot_maps(path: str, payloads: list) -> list:
+    """The checkpoint's `SlotMap`s: headers with their stored arrays,
+    or the single JSON payloads older checkpoints hold."""
+    from repro.core.sparse import SlotMap
+
+    names = [f"slot_maps/{i}/{k}" for i, p in enumerate(payloads)
+             if "node_slot" not in p for k in SlotMap.ARRAYS]
+    stored = load_arrays(path, names)
+    return [SlotMap.restore(p, {k: stored.get(f"slot_maps/{i}/{k}")
+                                for k in SlotMap.ARRAYS})
+            for i, p in enumerate(payloads)]
 
 
 def restore_stacked_state(ckpt_dir: str, *, exact_smax: bool,
@@ -115,8 +138,9 @@ def restore_stacked_state(ckpt_dir: str, *, exact_smax: bool,
     sp = meta.get("sparse")
     if sp is not None:
         # Slot-space checkpoint: rebuild the SparseStreamState pytree
-        # from the recorded capacities (the host SlotMaps ride in the
-        # metadata and are the caller's concern).
+        # from the recorded capacities; the host SlotMaps (headers in
+        # the metadata, arrays beside the state) come back as
+        # ``meta["slot_maps"]``.
         from repro.core.sparse import SparseLayout, SparseStreamState
 
         slayout = SparseLayout(int(sp["n_slots"]), int(sp["m_pad"]),
@@ -129,6 +153,9 @@ def restore_stacked_state(ckpt_dir: str, *, exact_smax: bool,
         states, manifest = restore_checkpoint(path, template,
                                               manifest=manifest)
         states = jax.tree_util.tree_map(jnp.asarray, states)
+        if meta.get("slot_maps") is not None:
+            meta = dict(meta, slot_maps=_restore_slot_maps(
+                path, meta["slot_maps"]))
         return states, int(manifest["step"]), meta
     zbn = jnp.zeros((b, n_pad), jnp.float32)
     has_mask = bool(meta.get("has_node_mask"))

@@ -9,9 +9,12 @@ promoted across buckets when they outgrow one, survive shard death
 (`save`/`restore` — per-shard serving checkpoints + one ``fleet.json``
 tenant manifest).
 
-Queries never gather full score vectors: per-tenant `scores` read one
-slot each through the jitted dynamic index, and `top_anomalies` merges
-per-shard top-k *candidate rows* only.
+Queries read a tick's scores once: a stacked launch leaves its pool an
+(S, B) score plane and a shard that ticked on its own its (B,) row, each
+pulled to the host with one transfer when `scores` or `top_anomalies`
+first needs it; `top_anomalies` merges per-shard top-k candidates. A
+shard without a row (before its first tick) is read through the jitted
+one-slot index and the device top-k.
 
 The serving loop writes profiler spans (`jax.profiler.TraceAnnotation`,
 free when no profiler runs), all named ``finger.*``: ``finger.ingest``
@@ -166,7 +169,9 @@ class FingerFleet:
     def admit(self, name: str, graph) -> TenantEntry:
         """Admit a tenant with its current graph (tenant node space =
         the graph's). Best-fit bucket, least-loaded shard; the stream
-        row is installed live (`install_stream`)."""
+        row is installed live (`install_stream`). A sparse tenant's
+        admission runs in a ``finger.admit`` span counting the nodes
+        and edges it allocated slots for (``nodes``, ``edges``)."""
         self._check_open("admit")
         self._require_unstaged("admit")
         if name in self._directory:
@@ -176,30 +181,43 @@ class FingerFleet:
             n_t, self.live_shards())
         pool = self._config.pools[pool_i]
         svc = self.shard_service(pool_i, shard_i)
-        # Same O(n + m) init pass `StreamEngine.init_states` runs on
-        # the unpadded graph (zero-padding into the shard layout
-        # commutes with every FINGER statistic), computed on the host:
-        # the values are host bookkeeping.
-        st = host_finger_state(graph)
-        base = {
-            "q": float(st.q), "s_total": float(st.s_total),
-            "s_max": float(st.s_max),
-            "strengths": np.asarray(st.strengths, np.float32).copy(),
-            "node_mask":
-                np.ones((n_t,), np.float32) if st.node_mask is None
-                else np.asarray(st.node_mask, np.float32).copy(),
-        }
         if pool.method == "sparse_tick":
-            try:
-                row, slot_map = sparse_state_from_graph(
-                    graph, svc.capacity, n_virtual=svc.config.n_pad,
-                    stream=slot)
-            except SparseCapacityError as e:
-                raise AdmissionError(
-                    f"tenant {name!r}: {e}") from e
-            svc.install_stream(slot, row, slot_map=slot_map)
+            with TraceAnnotation("finger.admit") as span:
+                try:
+                    row, slot_map = sparse_state_from_graph(
+                        graph, svc.capacity, n_virtual=svc.config.n_pad,
+                        stream=slot)
+                except SparseCapacityError as e:
+                    raise AdmissionError(
+                        f"tenant {name!r}: {e}") from e
+                svc.install_stream(slot, row, slot_map=slot_map)
+                span.set_metadata(
+                    nodes=slot_map.layout.n_slots - slot_map.n_free_nodes,
+                    edges=slot_map.n_live_edges)
+            # the tenant-space base is the slot row read through the map
+            base = {
+                "q": float(row.q), "s_total": float(row.s_total),
+                "s_max": float(row.s_max),
+                "strengths": slot_map.to_virtual(
+                    np.asarray(row.strengths, np.float32), n_t),
+                "node_mask": slot_map.to_virtual(
+                    np.asarray(row.node_mask, np.float32), n_t),
+            }
             slot_of_node = None
         else:
+            # Same O(n + m) init pass `StreamEngine.init_states` runs on
+            # the unpadded graph (zero-padding into the shard layout
+            # commutes with every FINGER statistic), computed on the
+            # host: the values are host bookkeeping.
+            st = host_finger_state(graph)
+            base = {
+                "q": float(st.q), "s_total": float(st.s_total),
+                "s_max": float(st.s_max),
+                "strengths": np.asarray(st.strengths, np.float32).copy(),
+                "node_mask":
+                    np.ones((n_t,), np.float32) if st.node_mask is None
+                    else np.asarray(st.node_mask, np.float32).copy(),
+            }
             self._install_row(svc, pool_i, slot, base)
             slot_of_node = np.arange(n_t, dtype=np.int32)
         entry = TenantEntry(
@@ -371,10 +389,14 @@ class FingerFleet:
         live = self.live_shards()
         for pool_i in sorted(live):
             pool = self._config.pools[pool_i]
+            # (shard ids, scores): an (S, B) plane of a stacked launch,
+            # or one shard's (B,) row where it ticked on its own
+            planes = []
+            self._pool_scores_dev[pool_i] = planes
             if not (self._config.stacked_ticks
                     and pooltick.stackable(pool.method)):
                 for shard_i in live[pool_i]:
-                    self.shard_service(pool_i, shard_i).poll()
+                    self._poll_alone(pool_i, shard_i, planes)
                     launches += 1
                 continue
             # Group live shards by live layout: shards of one pool
@@ -388,21 +410,19 @@ class FingerFleet:
                 gkey = (svc.layout.n_pad, svc.layout.generation,
                         svc.capacity)
                 groups.setdefault(gkey, []).append((shard_i, svc))
-            planes = []
             for members in groups.values():
                 group = [svc for _, svc in members]
                 if not pooltick.group_fits(
                         [svc.config for svc in group]):
                     # S-stacked operands would blow the residency
                     # budget: this group ticks sequentially.
-                    for svc in group:
-                        svc.poll()
+                    for shard_i, _ in members:
+                        self._poll_alone(pool_i, shard_i, planes)
                         launches += 1
                     continue
                 dists = pooltick.tick_pool(group)
                 launches += 1
                 planes.append(([s for s, _ in members], dists))
-            self._pool_scores_dev[pool_i] = planes
         self._step += 1
         self._staged = False
         self._last_poll_launches = launches
@@ -411,6 +431,13 @@ class FingerFleet:
             with TraceAnnotation("finger.save"):
                 self.save()
         return launches
+
+    def _poll_alone(self, pool_i: int, shard_i: int, planes: list) -> None:
+        """Tick one shard on its own; its (B,) scores join the pool's
+        planes, so one read serves `scores` and `top_anomalies`."""
+        report = self.shard_service(pool_i, shard_i).poll()
+        if report is not None:
+            planes.append(([shard_i], report.scores))
 
     @property
     def last_poll_launches(self) -> int:
@@ -422,12 +449,11 @@ class FingerFleet:
     # -- queries ----------------------------------------------------------
     def _host_score_row(self, pool_i: int,
                         shard_i: int) -> Optional[np.ndarray]:
-        """One shard's (B,) host score row out of the tick's score
-        plane — materialized lazily with ONE device→host transfer per
-        pool layout-group per tick, then indexed for free by every
-        per-tenant read and top-k merge. None when the shard ticked
-        outside the plane (sequential mode, residency fallback,
-        pre-first-tick)."""
+        """One shard's (B,) host score row of the latest tick —
+        materialized lazily with ONE device→host transfer per stacked
+        layout-group, or per shard that ticked on its own, per tick,
+        then indexed for free by every per-tenant read and top-k merge.
+        None when the shard did not tick in the latest poll."""
         rows = self._pool_scores_host.get(pool_i)
         if rows is None:
             planes = self._pool_scores_dev.get(pool_i)
@@ -436,7 +462,9 @@ class FingerFleet:
             rows = {}
             for shard_ids, mat in planes:
                 with TraceAnnotation("finger.d2h"):
-                    host = np.asarray(mat)  # the pool's one transfer
+                    host = np.asarray(mat)  # the plane's one transfer
+                if host.ndim == 1:  # a shard that ticked on its own
+                    host = host[None]
                 for j, s in enumerate(shard_ids):
                     rows[s] = host[j]
             self._pool_scores_host[pool_i] = rows
@@ -444,10 +472,10 @@ class FingerFleet:
 
     def scores(self, names: Optional[List[str]] = None
                ) -> Dict[str, float]:
-        """Latest per-tenant JSdist scores. Stacked-tick pools read the
-        cached host score plane (at most one device→host transfer per
-        pool per tick, amortized over every tenant); other shards keep
-        the jitted one-slot read. Never a full per-tenant (B,) gather.
+        """Latest per-tenant JSdist scores, read from the cached host
+        score rows (one device→host transfer per stacked group or
+        per shard that ticked alone, per tick, amortized over every
+        tenant); a shard without a row keeps the jitted one-slot read.
         Tenants stranded on a dead shard — or (re)installed since the
         shard last ticked — report their last known score."""
         self._check_open("scores")
@@ -476,10 +504,10 @@ class FingerFleet:
     def top_anomalies(self, k: int = 8) -> List[Tuple[str, float]]:
         """The k highest-scoring tenants of the latest tick: per-shard
         candidate rows (k capped at each shard's stream count), mapped
-        slot→tenant, merged and cut to k. Shards on the score plane
-        take their candidates from the already-materialized host row
-        (free); others run the device-side `top_anomalies` query —
-        full score vectors never leave their shard either way."""
+        slot→tenant, merged and cut to k. Shards with a host score row
+        take their candidates from it (free once `scores` pulled it);
+        a shard without one runs the device-side `top_anomalies`
+        query."""
         self._check_open("top_anomalies")
         with TraceAnnotation("finger.top_anomalies", step=self._step):
             cands: List[Tuple[float, str]] = []

@@ -135,15 +135,10 @@ class Rebalancer:
         stream's host `SlotMap` (virtual id → node slot) is the
         gather. Only slots the map owns are read — free slots hold
         exact zeros either way."""
-        n_t = entry.n_nodes
-        strengths = np.zeros((n_t,), np.float32)
-        mask = np.zeros((n_t,), np.float32)
-        row_s = np.asarray(row.strengths, np.float32)
-        row_m = np.asarray(row.node_mask, np.float32)
-        for vid, slot in slot_map.node_slot.items():
-            if vid < n_t:
-                strengths[vid] = row_s[slot]
-                mask[vid] = row_m[slot]
+        strengths = slot_map.to_virtual(
+            np.asarray(row.strengths, np.float32), entry.n_nodes)
+        mask = slot_map.to_virtual(
+            np.asarray(row.node_mask, np.float32), entry.n_nodes)
         return {"q": float(row.q), "s_total": float(row.s_total),
                 "s_max": float(row.s_max), "strengths": strengths,
                 "node_mask": mask}

@@ -22,8 +22,8 @@ bucket first, spilling up) and installed at identity positions —
 sparse slot-space tenants also land on dense pools, since their edge
 store cannot be reconstructed from FINGER statistics. A dead sparse
 shard's disk base is gathered to tenant space through the per-stream
-`SlotMap` payloads its checkpoint manifest serializes (virtual id →
-slot), in place of a dense position map.
+`SlotMap`s its checkpoint stores (virtual id → slot), in place of a
+dense position map.
 """
 from __future__ import annotations
 
@@ -112,8 +112,8 @@ def _load_dead_checkpoint(dead: DeadShard, exact_smax: bool):
     (so directory position maps index it): per-stream scalars plus the
     (B, n_pad_death) strengths/mask. Sparse checkpoints skip the
     layout walk — slot ids survive capacity growth unchanged — and
-    surface the serialized per-stream `SlotMap` payloads instead (the
-    gather table sparse tenants are read through)."""
+    surface the checkpoint's per-stream `SlotMap`s instead (the gather
+    table sparse tenants are read through)."""
     states, step_saved, meta = restore_stacked_state(
         dead.ckpt_dir, exact_smax=exact_smax, method=dead.method)
     strengths = np.asarray(states.strengths, np.float32)
@@ -179,7 +179,7 @@ def recover_shard(fleet, dead: DeadShard) -> List[dict]:
             mask = np.zeros((entry.n_nodes,), np.float32)
             if pool.method == "sparse_tick":
                 # Sparse tenants carry no dense position map; gather
-                # through the checkpoint's serialized SlotMap.
+                # through the checkpoint's SlotMap.
                 if not disk["slot_maps"]:
                     raise RecoveryError(
                         f"tenant {entry.name!r}: sparse shard "
@@ -187,11 +187,9 @@ def recover_shard(fleet, dead: DeadShard) -> List[dict]:
                         "carries no SlotMap payloads (it predates "
                         "sparse persistence) — its slot assignments "
                         "are unrecoverable")
-                for vid, slot in disk["slot_maps"][entry.slot][
-                        "node_slot"]:
-                    if vid < entry.n_nodes:
-                        strengths[vid] = row_s[slot]
-                        mask[vid] = row_m[slot]
+                slot_map = disk["slot_maps"][entry.slot]
+                strengths = slot_map.to_virtual(row_s, entry.n_nodes)
+                mask = slot_map.to_virtual(row_m, entry.n_nodes)
             else:
                 som = entry.slot_of_node
                 valid = np.nonzero(som >= 0)[0]

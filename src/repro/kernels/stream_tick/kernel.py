@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.jsdist import divergence_from_increments
 from repro.kernels.dispatch import (
     SCALAR_LANES,
     pack_lanes,
@@ -204,7 +205,15 @@ def _kernel(sc_ref, str_ref, mask_ref,
     h_pre = _h_tilde(q0, s0, smax0)
     h_half = _h_tilde(q_half, s_half, smax_half)
     h_full = _h_tilde(q_full, s_full, smax_full)
-    div = h_half - 0.5 * (h_pre + h_full)
+    # Lemma-1 moments of the tick (`core.incremental.delta_moments`);
+    # edge sums count each edge twice, as above.
+    a1 = jnp.sum(jnp.where(head, 2.0 * s_ep * ds_here, 0.0)) \
+        + 0.5 * jnp.sum(4.0 * ep_wold * vals)
+    a2 = jnp.sum(jnp.where(head, ds_here * ds_here, 0.0)) \
+        + 0.5 * jnp.sum(2.0 * vals * vals)
+    div = divergence_from_increments(
+        q0, s0, smax0, s_half, smax_half, s_full, smax_full,
+        delta_s_full, a1, a2, h_half - 0.5 * (h_pre + h_full))
 
     sco_ref[...] = pack_lanes(sco_ref.shape, jnp.sqrt(jnp.maximum(div, 0.0)),
                               q_full, s_full, smax_full)

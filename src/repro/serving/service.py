@@ -59,7 +59,8 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.core.state import FingerState
-from repro.engine.stream import StreamEngine, restore_stacked_state
+from repro.engine.stream import (StreamEngine, restore_stacked_state,
+                                 slot_map_checkpoint)
 from repro.graphs.layout import (
     NodeLayout,
     compose_index_maps,
@@ -386,12 +387,10 @@ class FingerService:
     def _restore_sparse(cls, config: ServiceConfig, plan, states, step,
                         meta) -> "FingerService":
         """Sparse tail of `restore`: rebuild the per-stream host
-        `SlotMap`s from the manifest payload and re-validate the slot
+        `SlotMap`s from the checkpoint and re-validate the slot
         capacities against the config. No layout-log walk — slot
         capacities only grow in place (slot ids are preserved), so the
         saved state IS the current layout's."""
-        from repro.core.sparse import SlotMap
-
         b = int(states.q.shape[0])
         if b != config.batch_size:
             raise ServiceConfigError(
@@ -413,7 +412,7 @@ class FingerService:
                 f"payload(s) for {b} stream(s); it predates sparse "
                 "persistence — rebuild these streams from their "
                 "source graphs with FingerService.open")
-        slot_maps = [SlotMap.from_json(p) for p in payloads]
+        slot_maps = list(payloads)  # rebuilt by restore_stacked_state
         for slot, sm in enumerate(slot_maps):
             if sm.n_virtual > config.n_pad:
                 raise ServiceConfigError(
@@ -490,7 +489,8 @@ class FingerService:
         """One tick's B per-stream *virtual* deltas → their slot-space
         deltas, through the per-stream `SlotMap`s, in a ``finger.slotmap``
         span counting the valid lanes in (``lanes``), the lanes
-        translated (``kept``) and the incoming leaves that were device
+        translated (``kept``), the edge slots allocated (``new_edges``)
+        and the incoming leaves that were device
         arrays, which `SlotMap.stage` reads back (``device_reads``; the
         fleet router hands host leaves).
 
@@ -528,6 +528,7 @@ class FingerService:
             span.set_metadata(
                 lanes=sum(d.lane_count() for d in deltas),
                 kept=sum(d.lane_count() for d in out),
+                new_edges=sum(st.edge_slots.size for st in staged),
                 device_reads=sum(isinstance(getattr(d, f), jax.Array)
                                  for d in deltas for f in _SLOTMAP_READS))
         return out
@@ -747,16 +748,19 @@ class FingerService:
                         "ingestion": self._config.ingestion,
                         "k_pad": self._config.k_pad},
         }
+        extra = None
         if self._config.method == "sparse_tick":
             meta["sparse"] = {
                 "n_slots": int(self._capacity.n_slots),
                 "m_pad": int(self._capacity.m_pad),
                 "generation": int(self._capacity.generation),
             }
-            meta["slot_maps"] = [sm.to_json() for sm in self._slot_maps]
+            meta["slot_maps"], extra = slot_map_checkpoint(
+                self._slot_maps)
         return save_checkpoint(ckpt_dir, self._step, states,
                                metadata=meta,
-                               prune_policy=self._config.checkpoint.prune)
+                               prune_policy=self._config.checkpoint.prune,
+                               extra_arrays=extra)
 
     # -- live migration --------------------------------------------------
     def _journal(self, record: dict) -> None:

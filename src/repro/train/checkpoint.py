@@ -94,12 +94,15 @@ def resolve_prune_policy(policy: PrunePolicy) -> Callable[[List[int]], set]:
 def save_checkpoint(ckpt_dir: str, step: int, tree,
                     metadata: Optional[dict] = None,
                     keep_last: Optional[int] = None,
-                    prune_policy: Optional[PrunePolicy] = None) -> str:
+                    prune_policy: Optional[PrunePolicy] = None,
+                    extra_arrays: Optional[Dict[str, Any]] = None) -> str:
     """Atomically write checkpoint `step`; prune old ones by policy.
 
     ``keep_last`` is the legacy spelling of ``prune_policy=k`` and is
     kept for existing callers; passing both is an error. With neither,
-    the default is keep-last-3.
+    the default is keep-last-3. ``extra_arrays`` are host arrays stored
+    beside the tree's under their own names (read back with
+    `load_arrays`), which no name of the tree's may take.
     """
     if keep_last is not None and prune_policy is not None:
         raise ValueError("save_checkpoint: pass either keep_last "
@@ -113,6 +116,11 @@ def save_checkpoint(ckpt_dir: str, step: int, tree,
     os.makedirs(tmp, exist_ok=True)
     named = _flatten_with_names(tree)
     arrays = {k: np.asarray(jax.device_get(v)) for k, v in named.items()}
+    for k, v in (extra_arrays or {}).items():
+        if k in arrays:
+            raise ValueError(f"save_checkpoint: extra array {k!r} "
+                             "takes the name of a tree leaf")
+        arrays[k] = np.asarray(v)
     np.savez_compressed(os.path.join(tmp, "arrays.npz"), **arrays)
     manifest = {"step": step, "time": time.time(),
                 "n_arrays": len(arrays),
@@ -155,6 +163,13 @@ def load_manifest(path: str) -> dict:
     """The checkpoint's manifest (step, time, metadata)."""
     with open(os.path.join(path, "manifest.json")) as f:
         return json.load(f)
+
+
+def load_arrays(path: str, names) -> Dict[str, np.ndarray]:
+    """The named arrays of checkpoint ``path`` (tree leaves or extra
+    arrays), read into memory."""
+    with np.load(os.path.join(path, "arrays.npz")) as data:
+        return {k: data[k] for k in names}
 
 
 def restore_checkpoint(path: str, template,

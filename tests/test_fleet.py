@@ -40,6 +40,8 @@ from repro.graphs.types import GraphDelta
 from repro.serving import FingerService, ServiceConfig, TopKSpec
 from repro.serving.migrate import embed_delta
 
+from slotmap_reference import as_json
+
 K_PAD, J_PAD = 3, 2
 
 
@@ -604,7 +606,8 @@ class TestSparseRouterHostLeaves:
             # shadow maps: one fed the router's output, one the old
             # device-leaf re-pad, both from the shard's admitted maps
             host_maps, dev_maps = (
-                {n: SlotMap.from_json(svc.slot_maps[slots[n]].to_json())
+                {n: SlotMap.restore(svc.slot_maps[slots[n]].header(),
+                                    svc.slot_maps[slots[n]].arrays())
                  for n in names} for _ in range(2))
             weights = {}
             for n in names:
@@ -639,8 +642,8 @@ class TestSparseRouterHostLeaves:
                 for i, n in enumerate(names):
                     assert abs(got[n] - float(ref[i])) < 1e-5, \
                         (t, n, got[n], float(ref[i]))
-                    assert svc.slot_maps[slots[n]].to_json() \
-                        == host_maps[n].to_json(), (t, n)
+                    assert as_json(svc.slot_maps[slots[n]]) \
+                        == as_json(host_maps[n]), (t, n)
         finally:
             fleet.close()
             oracle.close()
@@ -794,6 +797,41 @@ class TestStackedSequentialParity:
         self._assert_traces_match(
             self._sparse_lifecycle(True, tmp_path / "on"),
             self._sparse_lifecycle(False, tmp_path / "off"))
+
+
+class TestPerShardReadout:
+    """A shard that ticks on its own serves `scores` and `top_anomalies`
+    from one host read of its (B,) scores: the same numbers as its
+    one-slot device read and its device top-k."""
+
+    def test_host_row_matches_the_device_reads(self, tmp_path):
+        names = ["a", "b", "c"]
+        cfg = _two_bucket_cfg(stacked_ticks=False, directory=str(tmp_path))
+        fleet = FingerFleet.open(cfg)
+        try:
+            for i, n in enumerate(names):
+                fleet.admit(n, _graph(6, i + 7))
+            for t in range(3):
+                fleet.ingest({n: _delta(6, 30 + 10 * t + k)
+                              for k, n in enumerate(names)})
+                fleet.poll()
+                got, top = fleet.scores(), fleet.top_anomalies(k=2)
+                dev = []
+                for pool_i, shard_i in fleet.live_shard_ids():
+                    svc = fleet.shard_service(pool_i, shard_i)
+                    vals, slots = svc.top_anomalies(k=2)
+                    for v, slot in zip(vals, slots):
+                        entry = fleet.directory.tenant_at(
+                            pool_i, shard_i, int(slot))
+                        if entry is not None:
+                            assert got[entry.name] == svc.score_at(
+                                entry.slot)
+                            dev.append((float(v), entry.name))
+                dev.sort(key=lambda c: -c[0])
+                assert len(dev) == len(names)
+                assert top == [(n, v) for v, n in dev[:2]]
+        finally:
+            fleet.close()
 
 
 class TestPoolTickGrouping:

@@ -185,17 +185,16 @@ def test_h2d_bytes_are_the_staged_deltas_leaves(traced):
 
 
 def test_d2h_reads_per_tick_follow_the_read_path(traced):
-    mode, spans, _, _, _ = traced
+    _, spans, _, _, _ = traced
     reads = Counter()
     for name in ("finger.scores", "finger.top_anomalies"):
         for outer in _named(spans, name):
             reads[outer[3]["step"]] += len(_within(spans, "finger.d2h",
                                                    outer))
-    # stacked: one pull of the pool's score plane; per shard: one
-    # `score_at` per stream, then values and ids of the device top-k.
-    want = 1 if mode == "stacked" else B + 2
-    assert list(reads.values()) == [want] * TICKS
-    assert len(_named(spans, "finger.d2h")) == want * TICKS
+    # one pull of the tick's scores, the pool's stacked plane or the
+    # shard's own row, serves every tenant's score and the top-k
+    assert list(reads.values()) == [1] * TICKS
+    assert len(_named(spans, "finger.d2h")) == TICKS
 
 
 def test_fleet_spans_carry_the_fleet_step(traced):
@@ -217,3 +216,43 @@ def test_poll_counts_its_launches_and_saves(traced):
     assert len(_named(spans, "finger.save")) \
         == sum(s % SAVE_EVERY == 0 for s in range(fleet.step - TICKS + 1,
                                                   fleet.step + 1))
+
+
+def test_admit_and_new_edge_counters(tmp_path):
+    """A sparse admission runs in a ``finger.admit`` span counting the
+    nodes and edges it gave slots; ``finger.slotmap`` counts the edge
+    slots a tick allocates (``new_edges``): its lanes less those whose
+    edge is already live."""
+    fleet = FingerFleet.open(FleetConfig(pools=(PoolSpec(
+        name="slots", n_pad=N_VIRT, shards=1, streams_per_shard=B,
+        k_pad=K_PAD, method="sparse_tick", n_slots=16, m_pad=64),)))
+    try:
+        graphs = [erdos_renyi(N_NODES, 0.4, seed=i, weighted=True)
+                  for i in range(B)]
+        rng = np.random.default_rng(3)
+        deltas = _deltas(rng, [f"t{i}" for i in range(B)])
+        fresh = 0
+        for i, (name, d) in enumerate(deltas.items()):
+            w = np.asarray(graphs[i].weights)
+            fresh += sum(w[s, r] == 0 for s, r, m in zip(
+                np.asarray(d.senders), np.asarray(d.receivers),
+                np.asarray(d.mask)) if m > 0)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for i, g in enumerate(graphs):
+                fleet.admit(f"t{i}", g)
+            fleet.ingest(deltas)
+        finally:
+            jax.profiler.stop_trace()
+        fleet.poll()
+    finally:
+        fleet.close()
+    spans = _host_spans(str(tmp_path))
+    admits = _named(spans, "finger.admit")
+    assert [s[3]["nodes"] for s in admits] == [N_NODES] * B
+    assert [s[3]["edges"] for s in admits] == [
+        int(np.count_nonzero(np.triu(np.asarray(g.weights), 1)))
+        for g in graphs]
+    slotmap, = _named(spans, "finger.slotmap")
+    assert 0 < fresh < slotmap[3]["lanes"]
+    assert slotmap[3]["new_edges"] == fresh
