@@ -261,26 +261,44 @@ class _EdgeIndex:
 
     The buckets hold edge slots (int32); a bucket's key is read from the
     map's slot-to-key array, so the index costs 4 bytes a bucket. It is
-    sized once for the edge capacity (``m_pad`` at most 70% full) and
-    rebuilt only when released buckets (tombstones) fill it past 85% or
-    the capacity grows. Every operation works on a whole batch of keys at
-    once, one probe round per numpy pass; a lookup also notes where each
-    absent key would go, so its insertion need not probe again.
+    sized for the edge capacity (``m_pad`` at most 70% full) and rebuilt
+    in place when the capacity grows, when live and released buckets
+    (tombstones) fill it past 85%, or when tombstones outnumber a quarter
+    of the live keys: a probe walks past every tombstone on its path, so
+    under steady deletes and adds they would lengthen every lookup.
+    Every operation works on a whole batch of keys at once, one probe
+    round per numpy pass; a lookup also notes where each absent key
+    would go, so its insertion need not probe again. ``rebuilds`` and
+    ``probe_rounds`` count the rebuilds and `find`'s probe rounds so far.
     """
 
     _EMPTY = -1
     _TOMB = -2
     _MAX_LOAD = 0.7
     _CROWDED = 0.85
+    _LIVE_PER_TOMB = 4
     _MULT = np.uint64(0x9E3779B97F4A7C15)
 
     def __init__(self, capacity: int):
+        self.rebuilds = 0
+        self.probe_rounds = 0
+        self._clear(capacity)
+
+    def _clear(self, capacity: int) -> None:
         bits = max(4, int(np.ceil(np.log2(max(capacity, 1)
                                           / self._MAX_LOAD))))
         self.bits = bits
         self.table = np.full(1 << bits, self._EMPTY, np.int32)
         self.live = 0
         self.tombs = 0
+
+    def rebuild(self, capacity: int, keys: np.ndarray,
+                slots: np.ndarray) -> None:
+        """Empty the index, sized for ``capacity``, and insert the live
+        ``keys`` at ``slots``: no tombstones are left."""
+        self._clear(capacity)
+        self.insert(keys, slots)
+        self.rebuilds += 1
 
     @property
     def nbytes(self) -> int:
@@ -304,6 +322,7 @@ class _EdgeIndex:
         want = keys
         mask = (1 << self.bits) - 1
         while todo.size:
+            self.probe_rounds += 1
             b = self.table[pos]
             live = b >= 0
             hit = live & (slot_key[np.where(live, b, 0)] == want)
@@ -347,6 +366,9 @@ class _EdgeIndex:
     def crowded(self, adding: int) -> bool:
         return (self.live + self.tombs + adding) \
             > self._CROWDED * self.table.shape[0]
+
+    def tombstoned(self) -> bool:
+        return self.tombs > self.live // self._LIVE_PER_TOMB
 
 
 class _SlotStack:
@@ -493,10 +515,16 @@ class SlotMap:
         self.n_virtual = int(n_virtual)
         self._slot_of_vid = _extend(self._slot_of_vid, self.n_virtual, -1)
 
+    @property
+    def index_counters(self) -> Tuple[int, int]:
+        """The edge index's (rebuilds, probe rounds) since the map was
+        made or restored."""
+        return self._index.rebuilds, self._index.probe_rounds
+
     def _rebuild_index(self) -> None:
         slots = np.nonzero(self._key_of_slot >= 0)[0]
-        self._index = _EdgeIndex(self.layout.m_pad)
-        self._index.insert(self._key_of_slot[slots], slots)
+        self._index.rebuild(self.layout.m_pad, self._key_of_slot[slots],
+                            slots)
 
     def admit(self, vids: np.ndarray, lo: np.ndarray,
               hi: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -559,12 +587,14 @@ class SlotMap:
         slots = np.nonzero(sm._vid_of_slot >= 0)[0]
         sm._slot_of_vid[sm._vid_of_slot[slots]] = slots
         sm._degree = np.zeros(layout.n_slots, np.int32)
-        keys = sm._key_of_slot[sm._key_of_slot >= 0]
+        edges = np.nonzero(sm._key_of_slot >= 0)[0]
+        keys = sm._key_of_slot[edges]
         for ends in (keys >> _KEY_SHIFT, keys & _KEY_MASK):
             sm._degree += np.bincount(
                 sm._slot_of_vid[ends],
                 minlength=layout.n_slots).astype(np.int32)
-        sm._rebuild_index()
+        sm._index = _EdgeIndex(layout.m_pad)
+        sm._index.insert(keys, edges)
         return sm
 
     @classmethod
@@ -813,6 +843,8 @@ class SlotMap:
             self._slot_of_vid[freed] = -1
             self._vid_of_slot[slots] = -1
             self._free_nodes.push(slots)
+        if self._index.tombstoned():
+            self._rebuild_index()
         return staged.delta
 
     def _drop_edges(self, slots: np.ndarray, ends: np.ndarray) -> None:

@@ -489,10 +489,11 @@ class FingerService:
         """One tick's B per-stream *virtual* deltas → their slot-space
         deltas, through the per-stream `SlotMap`s, in a ``finger.slotmap``
         span counting the valid lanes in (``lanes``), the lanes
-        translated (``kept``), the edge slots allocated (``new_edges``)
-        and the incoming leaves that were device
-        arrays, which `SlotMap.stage` reads back (``device_reads``; the
-        fleet router hands host leaves).
+        translated (``kept``), the edge slots allocated (``new_edges``),
+        the edge-index rebuilds and probe rounds the maps ran
+        (``index_rebuilds``, ``probe_rounds``) and the incoming leaves
+        that were device arrays, which `SlotMap.stage` reads back
+        (``device_reads``; the fleet router hands host leaves).
 
         Atomic over the batch: every stream is staged (pure) before any
         map commits, so a rejection — out-of-capacity
@@ -521,14 +522,20 @@ class FingerService:
                 f"ingestion queue full ({self._config.max_queue} "
                 f"pending tick(s)); poll() before ingesting more")
         with TraceAnnotation("finger.slotmap") as span:
+            before = np.sum([sm.index_counters for sm in self._slot_maps],
+                            axis=0)
             staged = [sm.stage(d)
                       for sm, d in zip(self._slot_maps, deltas)]
             out = [sm.commit(st)
                    for sm, st in zip(self._slot_maps, staged)]
+            rebuilds, rounds = np.sum(
+                [sm.index_counters for sm in self._slot_maps],
+                axis=0) - before
             span.set_metadata(
                 lanes=sum(d.lane_count() for d in deltas),
                 kept=sum(d.lane_count() for d in out),
                 new_edges=sum(st.edge_slots.size for st in staged),
+                index_rebuilds=int(rebuilds), probe_rounds=int(rounds),
                 device_reads=sum(isinstance(getattr(d, f), jax.Array)
                                  for d in deltas for f in _SLOTMAP_READS))
         return out

@@ -256,3 +256,69 @@ def test_admit_and_new_edge_counters(tmp_path):
     slotmap, = _named(spans, "finger.slotmap")
     assert 0 < fresh < slotmap[3]["lanes"]
     assert slotmap[3]["new_edges"] == fresh
+
+
+def _index_counters(spans):
+    slotmaps = _named(spans, "finger.slotmap")
+    counts = [(s[3]["index_rebuilds"], s[3]["probe_rounds"])
+              for s in slotmaps]
+    for count in counts:
+        assert all(isinstance(c, int) and c >= 0 for c in count), count
+    return counts
+
+
+def test_an_add_only_fleet_never_rebuilds_its_edge_index(traced):
+    """The fixture's deltas only add or re-weight edges, so no
+    tombstone is left and the index is never rebuilt; every tick's
+    lookups probe at least one round."""
+    _, spans, _, _, _ = traced
+    counts = _index_counters(spans)
+    assert len(counts) == TICKS
+    assert [r for r, _ in counts] == [0] * TICKS
+    assert all(p > 0 for _, p in counts)
+
+
+def test_a_churn_fleet_rebuilds_its_edge_index(tmp_path):
+    """Each tick every tenant deletes five live edges and adds back the
+    five it deleted the tick before: the tombstones pass a quarter of
+    the live keys, so ``index_rebuilds`` counts rebuilds."""
+    k_pad, cut = 12, 5
+    fleet = FingerFleet.open(FleetConfig(pools=(PoolSpec(
+        name="slots", n_pad=N_VIRT, shards=1, streams_per_shard=B,
+        k_pad=k_pad, method="sparse_tick", n_slots=16, m_pad=64),)))
+    try:
+        live, gone = {}, {}
+        for i in range(B):
+            g = erdos_renyi(N_NODES, 0.4, seed=i, weighted=True)
+            fleet.admit(f"t{i}", g)
+            w = np.triu(np.asarray(g.weights), 1)
+            live[f"t{i}"] = {(int(a), int(b)): float(w[a, b])
+                             for a, b in zip(*np.nonzero(w))}
+            gone[f"t{i}"] = {}
+        rng = np.random.default_rng(5)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for _ in range(TICKS):
+                deltas = {}
+                for name, edges in live.items():
+                    back = gone[name]
+                    pick = rng.choice(len(edges), cut, replace=False)
+                    cut_now = {k: edges[k] for k in
+                               (sorted(edges)[p] for p in pick)}
+                    lanes = [(a, b, -w, w) for (a, b), w in cut_now.items()]
+                    lanes += [(a, b, w, 0.0) for (a, b), w in back.items()]
+                    edges.update(back)
+                    for k in cut_now:
+                        del edges[k]
+                    gone[name] = cut_now
+                    deltas[name] = GraphDelta.host_from_arrays(
+                        *zip(*lanes), n_nodes=N_VIRT, k_pad=k_pad)
+                fleet.ingest(deltas)
+                fleet.poll()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        fleet.close()
+    counts = _index_counters(_host_spans(str(tmp_path)))
+    assert len(counts) == TICKS
+    assert any(r > 0 for r, _ in counts)

@@ -261,3 +261,90 @@ def test_edge_index_inserts_where_find_saw_room(seed):
     assert (free[20:] == -1).all()
     assert index.live == int((index.table >= 0).sum()) == 100
     assert index.tombs == int((index.table == _EdgeIndex._TOMB).sum())
+
+
+def _churn_delta(rng, live, n, count, k_pad):
+    """Delete ``count`` random live unit-weight edges and add ``count``
+    absent pairs in one host delta; ``live`` is updated in place."""
+    gone = [live.pop(int(rng.integers(len(live)))) for _ in range(count)]
+    new = []
+    while len(new) < count:
+        lo, hi = sorted(int(x) for x in rng.choice(n, 2, replace=False))
+        if (lo, hi) not in live and (lo, hi) not in new \
+                and (lo, hi) not in gone:
+            new.append((lo, hi))
+    live.extend(new)
+    pairs = gone + new
+    return gone, GraphDelta.host_from_arrays(
+        [a for a, _ in pairs], [b for _, b in pairs],
+        [-1.0] * count + [1.0] * count, [1.0] * count + [0.0] * count,
+        n_nodes=n, k_pad=k_pad)
+
+
+def _lookup(sm: SlotMap, pairs):
+    keys = np.array([(lo << 32) | hi for lo, hi in pairs], np.int64)
+    slots, _, _ = sm._index.find(keys, sm._key_of_slot)
+    return slots
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_churn_keeps_tombstones_under_a_quarter_of_live_keys(seed):
+    """300 ticks of as many deletes as adds: after every commit the
+    index holds at most a quarter as many tombstones as live keys, the
+    edge slots and free lists are the dict map's, every live key is
+    found at its slot and no deleted key is found."""
+    rng = np.random.default_rng(300 + seed)
+    n, m = 160, 240
+    pairs = set()
+    while len(pairs) < m:
+        lo, hi = sorted(int(x) for x in rng.choice(n, 2, replace=False))
+        pairs.add((lo, hi))
+    live = sorted(pairs)
+    g = EdgeList.from_arrays([a for a, _ in live], [b for _, b in live],
+                             np.ones(m), n_nodes=n)
+    layout = SparseLayout(n_slots=n, m_pad=512)
+    _, got = sparse_state_from_graph(g, layout)
+    want = dict_admit(g, layout)
+    deleted = set()
+    for t in range(300):
+        label = f"seed {seed} tick {t}"
+        gone, d = _churn_delta(rng, live, n, int(rng.integers(4, 13)),
+                               k_pad=32)
+        _assert_same_delta(got.translate(d), want.translate(d), label)
+        _assert_same(got, want, label)
+        index = got._index
+        assert index.tombs <= index.live // 4, label
+        assert index.live == int((index.table >= 0).sum()), label
+        assert index.tombs == int((index.table == _EdgeIndex._TOMB).sum())
+        deleted = (deleted | set(gone)) - set(live)
+        assert list(_lookup(got, want.edge_slot)) \
+            == list(want.edge_slot.values()), label
+        assert (_lookup(got, sorted(deleted)) == -1).all(), label
+    assert got.index_counters[0] > 0  # the purge engaged
+
+
+def test_a_delete_only_delta_purges_tombstones():
+    """A delta that only deletes, past a quarter of the keys left,
+    rebuilds the index: no tombstone is left, the remaining keys keep
+    their slots and the deleted ones are gone."""
+    rng = np.random.default_rng(11)
+    n, m, cut = 40, 60, 16
+    keys = rng.choice(n * n, 4 * m, replace=False)
+    keys = keys[keys // n < keys % n][:m]
+    pairs = [(int(k // n), int(k % n)) for k in keys]
+    g = EdgeList.from_arrays([a for a, _ in pairs], [b for _, b in pairs],
+                             np.ones(m), n_nodes=n)
+    _, sm = sparse_state_from_graph(g, SparseLayout(n_slots=n, m_pad=128))
+    slots = _lookup(sm, pairs)
+    gone, kept = pairs[:cut], pairs[cut:]
+    d = GraphDelta.host_from_arrays(
+        [a for a, _ in gone], [b for _, b in gone], -np.ones(cut),
+        np.ones(cut), n_nodes=n, k_pad=cut)
+    staged = sm.stage(d)
+    assert staged.edge_slots.size == 0 and staged.gone_slots.size == cut
+    sm.commit(staged)
+    assert sm.index_counters[0] == 1
+    assert sm._index.tombs == 0
+    assert sm._index.live == int((sm._index.table >= 0).sum()) == m - cut
+    assert (_lookup(sm, kept) == slots[cut:]).all()
+    assert (_lookup(sm, gone) == -1).all()
