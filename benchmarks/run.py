@@ -1,6 +1,6 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
-"""Benchmark harness: one module per paper table/figure plus the kernel
-microbenches and the roofline report. Usage:
+"""Benchmark harness: one module per paper table/figure plus the
+static-analysis gate. Usage:
 
     PYTHONPATH=src python -m benchmarks.run [--only fig1,table3]
 """
@@ -16,9 +16,6 @@ from benchmarks import (
     fig1_degree,
     fig2_size,
     fig4_bifurcation,
-    kernels_bench,
-    kernels_interpret,
-    roofline,
     table2_wiki,
     table3_dos,
 )
@@ -29,12 +26,6 @@ SUITES = {
     "table2": table2_wiki.run,
     "table3": table3_dos.run,
     "fig4": fig4_bifurcation.run,
-    "kernels": kernels_bench.run,
-    # Quick interpret-mode parity pass over EVERY Pallas kernel
-    # (incl. the stream_tick megakernel) so CPU CI catches kernel/ref
-    # drift without a TPU; a mismatch fails the harness.
-    "kernels-interpret": kernels_interpret.run,
-    "roofline": roofline.run,
     # Static-analysis gate (lint / HLO audit / VMEM / compile-budget
     # sentinel); any unsuppressed violation fails the harness. Same
     # checks as `python -m repro.analysis`.

@@ -36,11 +36,11 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import hlo_analysis
 from repro.core.state import FingerState
 from repro.distributed.sharding import auto_mesh
 from repro.graphs.layout import NodeLayout
 from repro.graphs.types import GraphDelta
-from repro.launch import hlo_analysis
 from repro.serving.config import ServiceConfig, TopKSpec
 
 PLACEMENTS = ("local", "sharded", "multipod")

@@ -249,7 +249,7 @@ def collect_footprints(budget_bytes: Optional[int] = None) -> VmemReport:
     with capture_pallas_launches() as captured:
         for name, check in checks.items():
             before = len(captured)
-            check(None)
+            check()
             seen[name] = captured[before:]
         launches = list(captured)
 

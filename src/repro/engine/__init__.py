@@ -16,7 +16,7 @@ Streams need not share a true node count: the engine pads every tenant
 graph to one static `n_pad` layout with a per-stream dynamic node mask
 (inactive slots contribute exactly zero to every statistic), supports
 node join/leave deltas mid-stream, and persists/restores the stacked
-state through `train.checkpoint` so serving restarts resume instead of
+state through `serving.checkpoint` so serving restarts resume instead of
 replaying.
 """
 from repro.engine.stream import (

@@ -33,7 +33,7 @@ joins/leaves are per-stream `GraphDelta` node slots, so tenants can grow
 and shrink mid-stream without recompilation.
 
 Restartable serving: `save`/`restore` persist the stacked state through
-`train.checkpoint` (atomic tmp-dir + rename writes; restore gathers to
+`serving.checkpoint` (atomic tmp-dir + rename writes; restore gathers to
 host and re-shards onto whatever mesh the new job runs), so a serving
 restart resumes scores exactly instead of replaying every stream.
 
@@ -55,13 +55,9 @@ from repro.core.jsdist import jsdist_incremental
 from repro.core.state import FingerState, finger_state
 from repro.graphs.layout import NodeLayout
 from repro.graphs.types import GraphDelta
-from repro.train.checkpoint import (
-    latest_checkpoint,
-    load_arrays,
-    load_manifest,
-    restore_checkpoint,
-    save_checkpoint,
-)
+
+# `repro.serving` imports this module, so `repro.serving.checkpoint` is
+# imported where it is used, after both packages have loaded.
 
 
 def _check_consistent(label: str, kind: str, values) -> None:
@@ -96,6 +92,7 @@ def _restore_slot_maps(path: str, payloads: list) -> list:
     """The checkpoint's `SlotMap`s: headers with their stored arrays,
     or the single JSON payloads older checkpoints hold."""
     from repro.core.sparse import SlotMap
+    from repro.serving.checkpoint import load_arrays
 
     names = [f"slot_maps/{i}/{k}" for i, p in enumerate(payloads)
              if "node_slot" not in p for k in SlotMap.ARRAYS]
@@ -115,6 +112,12 @@ def restore_stacked_state(ckpt_dir: str, *, exact_smax: bool,
     `StreamEngine.restore` and `serving.FingerService.restore` — one
     on-disk format, so checkpoints migrate freely between the two APIs.
     """
+    from repro.serving.checkpoint import (
+        latest_checkpoint,
+        load_manifest,
+        restore_checkpoint,
+    )
+
     path = latest_checkpoint(ckpt_dir)
     if path is None:
         raise FileNotFoundError(
@@ -371,15 +374,16 @@ class StreamEngine:
              prune_policy=None) -> str:
         """Persist the stacked serving state (atomic write).
 
-        Goes through `train.checkpoint`: arrays are gathered to host and
+        Goes through `serving.checkpoint`: arrays are gathered to host and
         published with a tmp-dir + rename, so a crash mid-save can never
         corrupt the latest checkpoint. The manifest records the stacked
         layout so `restore` can rebuild the pytree without a template.
-        ``prune_policy`` takes any `train.checkpoint` policy form
+        ``prune_policy`` takes any `serving.checkpoint` policy form
         (int / ``("keep_every_n", n, k)`` / callable); ``keep_last`` is
         the legacy int spelling.
         """
         from repro.core.sparse import SparseStreamState
+        from repro.serving.checkpoint import save_checkpoint
 
         # Reserved keys win over caller metadata: restore() depends on
         # them to rebuild the pytree and validate the engine config.

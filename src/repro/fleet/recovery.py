@@ -1,12 +1,11 @@
 """Shard-failure recovery: rebuild a dead shard's tenants on survivors.
 
-Promotes the repo's train-side fault-tolerance pattern (checkpoint +
-resume, `train.fault_tolerance`) into serving: each tenant is rebuilt
+Checkpoint plus resume, applied to serving: each tenant is rebuilt
 as ``base ⊕ replay(wal)`` where
 
 - ``base`` is the tenant-space snapshot in its directory entry, or —
   after a fleet save truncated it — the dead shard's *on-disk serving
-  checkpoint* (the shared `train.checkpoint` format), walked forward
+  checkpoint* (the shared `serving.checkpoint` format), walked forward
   through the shard's journaled layout migrations
   (`migrate.migrate_host_arrays`) to the layout at death so the
   directory's position maps index it correctly, then gathered to

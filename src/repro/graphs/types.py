@@ -4,7 +4,7 @@ Three interchangeable representations, all registered as JAX pytrees so
 they can flow through jit / scan / shard_map:
 
 - ``DenseGraph``  : (n, n) symmetric weight matrix. The natural format for
-  attention graphs, Hi-C contact maps, and the exact-VNGE oracle.
+  Hi-C contact maps and the exact-VNGE oracle.
 - ``EdgeList``    : padded COO with an explicit validity mask. The natural
   format for streaming graphs and O(n + m) FINGER computation.
 - ``GraphDelta``  : a padded set of undirected edge-weight changes

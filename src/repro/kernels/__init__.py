@@ -2,7 +2,6 @@
 
 - ``vnge_q``        : fused one-HBM-pass Lemma-1 statistics over dense W
 - ``bsr_spmv``      : block-sparse Laplacian matvec (λ_max power iteration)
-- ``entropy_probe`` : attention-graph VNGE stats from logits, A never in HBM
 - ``delta_stats``   : fused Theorem-2 ΔS/ΔQ/Δs_max over sorted endpoints
 - ``stream_tick``   : the single-pass batched serving tick — mask
   gating, node join/leave, delta statistics, state update and JSdist
@@ -10,8 +9,8 @@
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper with CPU interpret fallback), ref.py (pure-jnp oracle) and
-parity.py (interpret-vs-oracle check, auto-discovered by the
-kernels-interpret CI suite). Shared dispatch policy — backend
+parity.py (interpret-vs-oracle check, auto-discovered by
+`repro.kernels.parity` and run by the `repro.analysis.vmem` checker). Shared dispatch policy — backend
 detection, interpret fallback, the configurable VMEM budget — lives in
 `repro.kernels.dispatch`; the layout is enforced by the
 ``kernel-package-triple`` lint rule in `repro.analysis.lint`.
@@ -21,10 +20,6 @@ from repro.kernels.bsr_spmv.ops import (
     bsr_matvec,
     dense_to_bsr,
     power_iteration_lmax_bsr,
-)
-from repro.kernels.entropy_probe.ops import (
-    attention_graph_entropy,
-    attention_graph_stats,
 )
 from repro.kernels.delta_stats.ops import (
     delta_stats_fused,

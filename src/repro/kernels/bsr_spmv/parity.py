@@ -11,12 +11,10 @@ from repro.kernels.bsr_spmv.ref import bsr_matvec_ref
 from repro.kernels.parity import assert_close
 
 
-def check_parity(record=None) -> None:
+def check_parity() -> None:
     rng = np.random.default_rng(1)
     g = random_geometric_community(256, 4, 0.3, 0.01, seed=2)
     m = dense_to_bsr(np.asarray(g.weights), b=128)
     x = jnp.asarray(rng.random(m.n).astype(np.float32))
     assert_close("bsr_spmv", bsr_matvec(m, x, use_pallas=True),
                  bsr_matvec_ref(m, x), atol=1e-4)
-    if record is not None:
-        record("bsr_spmv_n256", lambda: bsr_matvec(m, x, use_pallas=True))

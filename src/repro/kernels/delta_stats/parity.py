@@ -12,7 +12,7 @@ from repro.kernels.delta_stats.ops import delta_stats_fused
 from repro.kernels.parity import assert_close
 
 
-def check_parity(record=None) -> None:
+def check_parity() -> None:
     rng = np.random.default_rng(3)
     g = erdos_renyi(48, 0.2, seed=3, weighted=True).pad_to(64)
     state = finger_state(g)
@@ -26,6 +26,3 @@ def check_parity(record=None) -> None:
     got = jnp.stack(delta_stats_fused(state, delta, use_pallas=True))
     want = jnp.stack(delta_stats_fused(state, delta, use_pallas=False))
     assert_close("delta_stats", got, want, atol=1e-5)
-    if record is not None:
-        record("delta_stats_k16", lambda: jnp.stack(
-            delta_stats_fused(state, delta, use_pallas=True)))

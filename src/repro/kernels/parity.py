@@ -1,11 +1,9 @@
 """Kernel parity registry: discovery + the shared comparison helper.
 
 Every kernel package under ``repro.kernels`` ships a ``parity.py``
-exposing ``check_parity(record=None)`` — a small-input check of the
-interpret path against the package's pure-jnp oracle that raises on
-mismatch.  ``record(metric, thunk)``, when given, lets the caller time
-and log the interpret-path latency (``benchmarks/kernels_interpret.py``
-passes an emit-to-CSV recorder; tests pass nothing).
+exposing ``check_parity()`` — a small-input check of the interpret
+path against the package's pure-jnp oracle that raises on mismatch
+(`repro.analysis.vmem` runs them all).
 
 `discover_parity_checks` walks the package with pkgutil, so a new
 kernel package can never silently skip CPU-CI parity coverage: a
@@ -17,12 +15,11 @@ from __future__ import annotations
 
 import importlib
 from pathlib import Path
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict
 
 import numpy as np
 
-RecordFn = Callable[[str, Callable[[], object]], None]
-ParityFn = Callable[[Optional[RecordFn]], None]
+ParityFn = Callable[[], None]
 
 
 class ParityRegistrationError(RuntimeError):
@@ -73,6 +70,6 @@ def discover_parity_checks() -> Dict[str, ParityFn]:
         fn = getattr(mod, "check_parity", None)
         if not callable(fn):
             raise ParityRegistrationError(
-                f"{modname} does not define check_parity(record=None)")
+                f"{modname} does not define check_parity()")
         checks[name] = fn
     return dict(sorted(checks.items()))

@@ -2,7 +2,7 @@
 
 Pads W up to the block grid, dispatches to the kernel on TPU and to
 interpret mode elsewhere (CPU CI), and exposes a drop-in `quadratic_q`
-replacement for `DenseGraph` hot paths (attention graphs, Hi-C maps).
+replacement for `DenseGraph` hot paths (Hi-C maps).
 """
 from __future__ import annotations
 

@@ -3,8 +3,7 @@
 Arrays are gathered to host, written as one compressed npz keyed by
 pytree path, plus a small JSON manifest (step, metadata). Writes are
 atomic (tmp dir + rename) so a crash mid-write can never corrupt the
-latest checkpoint. Restore re-shards onto whatever mesh the new job runs
-— the elastic-scaling path (fault_tolerance.elastic_restore).
+latest checkpoint. Restore re-shards onto whatever mesh the new job runs.
 
 Pruning is a pluggable policy (``prune_policy`` on `save_checkpoint`):
 

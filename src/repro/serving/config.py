@@ -16,9 +16,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-# The accepted policy forms are documented (and enforced) in
-# `train.checkpoint` — this module only re-exports the alias.
-from repro.train.checkpoint import PrunePolicy
+# The accepted prune-policy forms are documented (and enforced) in
+# `serving.checkpoint`.
+from repro.serving.checkpoint import PrunePolicy, resolve_prune_policy
 
 PLACEMENTS = ("local", "sharded", "multipod")
 INGESTIONS = ("sync", "double_buffered")
@@ -60,11 +60,9 @@ class CheckpointPolicy:
 
 
 def _validate_prune_policy(policy: PrunePolicy) -> None:
-    """Delegate to `train.checkpoint.resolve_prune_policy` — the single
+    """Delegate to `serving.checkpoint.resolve_prune_policy` — the single
     source of truth for accepted policy forms — re-raising its
     ValueError as the config-level named error."""
-    from repro.train.checkpoint import resolve_prune_policy
-
     try:
         resolve_prune_policy(policy)
     except ValueError as e:

@@ -77,7 +77,7 @@ from repro.serving.plans import (
     PlanCache,
     build_plan,
 )
-from repro.train.checkpoint import save_checkpoint
+from repro.serving.checkpoint import save_checkpoint
 
 # One on-disk format with StreamEngine.save: a FingerService checkpoint
 # restores into a bare StreamEngine and vice versa (the migration path).

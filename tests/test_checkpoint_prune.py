@@ -18,7 +18,7 @@ from repro.graphs.generators import erdos_renyi
 from repro.graphs.types import GraphDelta
 from repro.serving import CheckpointPolicy, FingerService, ServiceConfig
 from repro.serving.config import ServiceConfigError, TopKSpec
-from repro.train.checkpoint import (
+from repro.serving.checkpoint import (
     latest_checkpoint,
     load_manifest,
     resolve_prune_policy,

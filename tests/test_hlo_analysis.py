@@ -5,7 +5,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.launch.hlo_analysis import analyze, parse_hlo
+from repro.analysis.hlo_analysis import analyze, parse_hlo
 
 
 def _flops_of(f, *specs):
@@ -83,7 +83,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.hlo_analysis import analyze
+from repro.analysis.hlo_analysis import analyze
 from repro.distributed.sharding import auto_mesh
 mesh = auto_mesh((4,), ("d",))
 sh = NamedSharding(mesh, P("d", None))

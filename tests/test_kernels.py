@@ -14,11 +14,6 @@ from repro.kernels.bsr_spmv.ops import (
     power_iteration_lmax_bsr,
 )
 from repro.kernels.bsr_spmv.ref import bsr_density, bsr_matvec_ref
-from repro.kernels.entropy_probe.ops import (
-    attention_graph_entropy,
-    attention_graph_stats,
-)
-from repro.kernels.entropy_probe.ref import attention_graph_stats_ref
 from repro.kernels.vnge_q.ops import quadratic_q_dense, vnge_q_stats
 from repro.kernels.vnge_q.ref import vnge_q_stats_ref
 from repro.core.vnge import quadratic_q
@@ -92,41 +87,6 @@ class TestBsrSpmv:
         g = random_geometric_community(512, 4, 0.4, 0.0, seed=1)
         m = dense_to_bsr(np.asarray(g.weights), b=128)
         assert bsr_density(m) < 1.0  # off-community blocks dropped
-
-
-class TestEntropyProbe:
-    @pytest.mark.parametrize("bh,s", [(1, 128), (2, 256), (4, 128), (1, 384)])
-    def test_stats_match_ref(self, bh, s, rng):
-        logits = jnp.asarray(
-            rng.normal(0, 2.0, (bh, s, s)).astype(np.float32))
-        got = np.asarray(attention_graph_stats(logits))
-        ref = np.asarray(attention_graph_stats_ref(logits))
-        np.testing.assert_allclose(got, ref, rtol=5e-4, atol=1e-5)
-
-    def test_masked_causal_logits(self, rng):
-        s = 128
-        logits = rng.normal(0, 1.0, (2, s, s)).astype(np.float32)
-        mask = np.tril(np.ones((s, s), bool))
-        logits = np.where(mask, logits, -1e30)
-        got = np.asarray(attention_graph_stats(jnp.asarray(logits)))
-        ref = np.asarray(attention_graph_stats_ref(jnp.asarray(logits)))
-        np.testing.assert_allclose(got, ref, rtol=5e-4, atol=1e-5)
-
-    def test_entropy_bounded(self, rng):
-        s = 128
-        logits = jnp.asarray(rng.normal(0, 1, (3, s, s)).astype(np.float32))
-        h = np.asarray(attention_graph_entropy(logits))
-        assert np.all(h >= 0.0) and np.all(h <= np.log(s - 1) + 1e-3)
-
-    def test_uniform_attention_max_entropy(self):
-        """Uniform attention = complete graph → H̃ near its maximum."""
-        s = 128
-        logits = jnp.zeros((1, s, s), jnp.float32)
-        h_uniform = float(attention_graph_entropy(logits)[0])
-        peaked = jnp.asarray(
-            np.eye(s, k=-1, dtype=np.float32) * 50.0 - 25.0)
-        h_peaked = float(attention_graph_entropy(peaked[None])[0])
-        assert h_uniform > h_peaked
 
 
 class TestParityDiscovery:
